@@ -12,8 +12,7 @@ Single model:
   python -m tensor2robot_tpu.bin.run_serving \
       --export_dir /models/m/export/latest_exporter_numpy \
       --port 8000 --max-batch 64 --batch-deadline-ms 5 \
-      --metricsz-port 8001 --compilation-cache-dir /var/cache/t2r-xla \
-      --quantize int8
+      --metricsz-port 8001 --quantize int8
 
 Multi-model (ModelRouter: N export roots, one device, LRU paging under
 an HBM byte budget, priority-class admission control — best-effort
@@ -88,10 +87,6 @@ def main(argv=None):
   parser.add_argument('--metricsz-port', type=int, default=None,
                       help='Also serve the metrics registry (incl. the '
                            'serving report section) at /metricsz.')
-  parser.add_argument('--compilation-cache-dir', default=None,
-                      help='Persistent XLA cache: restarted servers '
-                           'deserialize bucket executables instead of '
-                           'recompiling (T2R_COMPILATION_CACHE_DIR).')
   parser.add_argument('--quantize', choices=('off', 'int8', 'fp8'),
                       default='off',
                       help='Weight-only quantized serving: int8 (or fp8 '
@@ -174,8 +169,7 @@ def main(argv=None):
   server_kwargs = dict(
       port=args.port,
       host=args.host,
-      request_timeout_secs=args.request_timeout_secs,
-      compilation_cache_dir=args.compilation_cache_dir)
+      request_timeout_secs=args.request_timeout_secs)
 
   if args.model:
     predictors = {}

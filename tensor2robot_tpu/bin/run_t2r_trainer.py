@@ -8,6 +8,12 @@ Usage:
   python -m tensor2robot_tpu.bin.run_t2r_trainer \
       --gin_configs path/to/experiment.gin \
       --gin_bindings 'train_eval_model.max_train_steps = 100'
+
+A run that completes leaves ``run_report.json`` in the model dir: the
+metrics registry's end-of-run report, whose ``device`` section says what
+the run actually ran on, ``programs`` what it compiled (hand-kernel
+count included) and ``metrics`` its ``compile/*`` and ``trainer/*``
+counters. chip_smoke.py reads it.
 """
 
 from __future__ import annotations
@@ -17,6 +23,8 @@ import logging
 import sys
 
 from tensor2robot_tpu import config as t2r_config
+
+RUN_REPORT_FILENAME = 'run_report.json'
 
 
 def main(argv=None):
@@ -72,11 +80,13 @@ def _run(args, resilience):
   if not isinstance(model_dir, str):
     model_dir = None
 
+  local_model_dir = model_dir if model_dir and '://' not in model_dir else None
+
   def save_config(text, filename):
-    if not model_dir or '://' in model_dir:
+    if local_model_dir is None:
       return
-    os.makedirs(model_dir, exist_ok=True)
-    with open(os.path.join(model_dir, filename), 'w') as f:
+    os.makedirs(local_model_dir, exist_ok=True)
+    with open(os.path.join(local_model_dir, filename), 'w') as f:
       f.write(text)
 
   # The startup snapshot is the FULL parsed config (the run may crash
@@ -105,6 +115,13 @@ def _run(args, resilience):
   operative = t2r_config.operative_config_str()
   logging.info('Operative config:\n%s', operative)
   save_config(operative, 'operative_config-0.gin')
+  if local_model_dir is not None:
+    from tensor2robot_tpu.observability import metrics as metrics_lib
+
+    final = {k: float(v) for k, v in (result or {}).items()}
+    metrics_lib.register_report_provider('result', lambda: final)
+    metrics_lib.dump_report(
+        os.path.join(local_model_dir, RUN_REPORT_FILENAME))
   return result
 
 

@@ -122,7 +122,9 @@ def serialize_serving_fn(model, serving_variables,
     exported = jax_export.export(
         jax.jit(serving_fn), platforms=platforms)(var_args, feature_args)
   except Exception as e:
-    # Some lowering rules are platform-gated; fall back to the current one.
+    # Some lowering rules are platform-gated; fall back to the current
+    # one — counted, so a check can tell the portable artifact from this.
+    metrics_lib.counter('export/serving_fn_single_platform').inc()
     logging.warning(
         'Multi-platform serving export (platforms=%s) failed; retrying for '
         'the current backend only — the artifact will NOT be portable '

@@ -8,7 +8,11 @@ toolchain and cached.
 
 ``load_record_io()`` returns the loaded ``ctypes.CDLL`` or ``None`` when
 no toolchain is available (callers fall back to the TF path). Set
-``T2R_NATIVE_DISABLE=1`` to force the fallback.
+``T2R_NATIVE_DISABLE=1`` to force the fallback. A build or load that
+FAILED is not the same as one that was switched off: the compiler's
+output is kept in :func:`build_errors`, which chip_smoke.py prints and
+fails on, so a machine that cannot build the reader is found out before
+a record-fed run quietly takes the slow path.
 """
 
 from __future__ import annotations
@@ -20,7 +24,7 @@ import os
 import subprocess
 import tempfile
 import threading
-from typing import Optional
+from typing import Dict, Optional
 
 _SRC = os.path.join(os.path.dirname(__file__), 'record_io.cpp')
 _JPEG_SRC = os.path.join(os.path.dirname(__file__), 'jpeg_decode.cpp')
@@ -29,6 +33,15 @@ _LIB: Optional[ctypes.CDLL] = None  # GUARDED_BY(_LOCK)
 _TRIED = False  # GUARDED_BY(_LOCK)
 _JPEG_LIB: Optional[ctypes.CDLL] = None  # GUARDED_BY(_LOCK)
 _JPEG_TRIED = False  # GUARDED_BY(_LOCK)
+_BUILD_ERRORS: Dict[str, str] = {}  # GUARDED_BY(_LOCK)
+
+
+def build_errors() -> Dict[str, str]:
+  """``{library: compiler or loader output}`` for every library whose
+  build or load failed in this process (empty when all that were asked
+  for loaded)."""
+  with _LOCK:
+    return dict(_BUILD_ERRORS)
 
 
 def _build_dir() -> str:
@@ -49,12 +62,19 @@ def _compile_src(src: str, stem: str, what: str,
   cmd = ['g++', '-O3', '-std=c++17', '-shared', '-fPIC', '-pthread',
          src, '-o', tmp, *extra_flags]
   try:
-    subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+    subprocess.run(cmd, check=True, capture_output=True, text=True,
+                   timeout=120)
   except (OSError, subprocess.SubprocessError) as e:
-    logging.warning('native %s build failed (%s); using fallback', what, e)
+    _note_failure(what, f'{e}\n{getattr(e, "stderr", None) or ""}'.strip())
     return None
   os.replace(tmp, out)  # atomic: racing builders converge on one file
   return out
+
+
+def _note_failure(what: str, output: str) -> None:  # HOLDS(_LOCK)
+  _BUILD_ERRORS[what] = output
+  logging.warning('native %s unavailable; using the fallback path:\n%s',
+                  what, output)
 
 
 def _compile() -> Optional[str]:
@@ -130,7 +150,7 @@ def load_record_io() -> Optional[ctypes.CDLL]:
       try:
         _LIB = _bind(ctypes.CDLL(path))
       except OSError as e:
-        logging.warning('native record_io load failed (%s)', e)
+        _note_failure('record_io', f'load failed: {e}')
         _LIB = None
     return _LIB
 
@@ -165,6 +185,6 @@ def load_jpeg_decode() -> Optional[ctypes.CDLL]:
       try:
         _JPEG_LIB = _bind_jpeg(ctypes.CDLL(path))
       except OSError as e:
-        logging.warning('native jpeg_decode load failed (%s)', e)
+        _note_failure('jpeg_decode', f'load failed: {e}')
         _JPEG_LIB = None
     return _JPEG_LIB

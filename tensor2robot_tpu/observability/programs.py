@@ -53,6 +53,7 @@ headline.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import hashlib
 import json
@@ -141,6 +142,14 @@ class ProgramRecord:
   source: str = ''  # which compile point recorded it
   recorded_unix: float = 0.0
   recompiles: int = 0  # re-records under this name with a NEW fingerprint
+  # Hand-written (Pallas/Mosaic) kernels in the compiled text: how a run
+  # on the chip shows that a kernel it asked for is really in the
+  # program, and that a default program has none (chip_smoke.py).
+  custom_calls: int = 0
+  # Collective ops XLA put in the compiled text, by kind (async starts
+  # count once): how a multi-chip run shows that the mesh's gradient
+  # all-reduce / fsdp all-gather are really in the program.
+  collectives: Dict[str, int] = dataclasses.field(default_factory=dict)
   # Train steps folded into ONE execution of this program (the trainer's
   # steps_per_dispatch scan). cost_analysis counts the WHOLE K-step
   # executable; utilization() divides by this so train/mfu and
@@ -168,9 +177,6 @@ def _cost_analysis(compiled) -> Dict[str, float]:
     cost = compiled.cost_analysis()
   except Exception:  # pylint: disable=broad-except
     return {}
-  # jax 0.4.x returns a one-element list of dicts; newer versions a dict.
-  if isinstance(cost, (list, tuple)):
-    cost = cost[0] if cost else {}
   return cost if isinstance(cost, dict) else {}
 
 
@@ -181,7 +187,23 @@ def _memory_analysis(compiled):
     return None
 
 
-def _aliased_param_numbers(compiled) -> Optional[Tuple[int, ...]]:
+def _compiled_text(compiled) -> str:
+  try:
+    return compiled.as_text() or ''
+  except Exception:  # pylint: disable=broad-except
+    return ''
+
+
+_COLLECTIVE_RE = re.compile(
+    r'\b(all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all)'
+    r'(?:-start)?\(')
+
+
+def _collective_counts(text: str) -> Dict[str, int]:
+  return dict(collections.Counter(_COLLECTIVE_RE.findall(text)))
+
+
+def _aliased_param_numbers(text: str) -> Optional[Tuple[int, ...]]:
   """Parameter numbers XLA aliased to outputs, from the HLO header.
 
   The optimized module's first line carries the truth about donation:
@@ -190,10 +212,6 @@ def _aliased_param_numbers(compiled) -> Optional[Tuple[int, ...]]:
   missing here was silently elided (the buffer is copied, not reused).
   None when the executable text is unavailable.
   """
-  try:
-    text = compiled.as_text()
-  except Exception:  # pylint: disable=broad-except
-    return None
   if not text:
     return None
   header = text[:text.find('\n')] if '\n' in text else text
@@ -324,13 +342,10 @@ class ProgramLedger:
             program_fingerprint(lowered.as_text()), 'stablehlo')
       except Exception:  # pylint: disable=broad-except
         pass
-    if not fingerprint:
-      try:
-        fingerprint, fp_source = (
-            program_fingerprint(compiled.as_text()), 'hlo')
-      except Exception:  # pylint: disable=broad-except
-        pass
-    aliased = _aliased_param_numbers(compiled)
+    text = _compiled_text(compiled)
+    if not fingerprint and text:
+      fingerprint, fp_source = program_fingerprint(text), 'hlo'
+    aliased = _aliased_param_numbers(text)
     aliased_n = None if aliased is None else len(aliased)
     undonated = None
     if donated_params is not None and aliased_n is not None:
@@ -369,6 +384,8 @@ class ProgramLedger:
                      else _device_kind()),
         source=source,
         recorded_unix=time.time(),
+        custom_calls=text.count('tpu_custom_call'),
+        collectives=_collective_counts(text),
     )
 
   def get(self, name: str) -> Optional[ProgramRecord]:
@@ -405,6 +422,7 @@ class ProgramLedger:
             'donated': (None if rec.donated_params is None
                         else f'{rec.aliased_params}/{rec.donated_params}'),
             'recompiles': rec.recompiles,
+            'custom_calls': rec.custom_calls,
         } for rec in records
     }
 

@@ -23,6 +23,14 @@ contract, first established by ``flash_attention`` and lifted here so
   =1``) to drill policy-on-vs-off equivalence through the interpreter.
   The gate is consulted at TRACE time: a jitted program bakes in
   whichever path was live when it traced.
+* **Loud refusal** (:func:`refuse`): a kernel the caller asked for
+  (``kernel_policy``, ``fused_update=True``) that the live target cannot
+  run gives way to its XLA reference with one WARNING and a
+  ``kernels/refused`` count — never silently. Each kernel's
+  ``is_supported`` answers for the target it would actually lower to:
+  what the interpreter accepts and what Mosaic lowers differ (lane
+  blocks, scoped VMEM, unimplemented primitives), and only a compile for
+  the chip shows the second (tests/test_chip_compile.py).
 
 The ``kernel_policy`` model knob (``'none' | 'pool' | 'pool_conv'``,
 same shape as ``remat_policy``) also lives here: it names which kernel
@@ -32,11 +40,14 @@ families a tower routes through its gated call sites.
 from __future__ import annotations
 
 import contextlib
+import logging
 import os
 import threading
 from typing import Optional
 
 import jax
+
+from tensor2robot_tpu.observability import metrics as metrics_lib
 
 # ------------------------------------------------------- kernel policies
 
@@ -78,6 +89,32 @@ def use_interpret() -> bool:
 
 def tpu_available() -> bool:
   return not use_interpret()
+
+
+# Scoped VMEM one Mosaic kernel instance may allocate on a TPU v5e with
+# default compiler parameters — the limit the chip's compiler enforced
+# when the pool kernel was compiled for a described v5e ("Scoped
+# allocation with size 40.27M and limit 16.00M").
+MOSAIC_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+# A VMEM-resident block is stored in (sublane, lane) tiles: the last dim
+# pads to 128 lanes, the second-to-last to 8 sublanes of 32-bit words.
+MOSAIC_LANES = 128
+MOSAIC_SUBLANES = 8
+
+
+def refuse(kernel: str, reason: str) -> None:
+  """Records that a REQUESTED hand kernel gave way to its XLA reference.
+
+  Called at trace time by the gated entry points when the caller's
+  explicit ask (a ``kernel_policy`` tower, ``fused_update=True``) cannot
+  be honoured at the shapes in hand. chip_smoke.py fails a run whose
+  report shows ``kernels/refused`` > 0.
+  """
+  metrics_lib.counter('kernels/refused').inc()
+  logging.warning(
+      'Pallas %s kernel was requested but cannot run here (%s; lowering '
+      'target: %s); the XLA reference runs instead.', kernel, reason,
+      'the Pallas interpreter' if use_interpret() else 'Mosaic')
 
 
 def min_lane_block(interpret: Optional[bool] = None) -> int:

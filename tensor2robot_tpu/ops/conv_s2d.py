@@ -31,6 +31,18 @@ interpret mode off-TPU so tier-1 runs the same kernel code;
 drop-in whose parameter tree is byte-identical to ``nn.Conv`` (kernel
 ``(kh, kw, cin, cout)``, optional bias), so kernel-policy-on/off
 checkpoints interchange.
+
+On the chip: this kernel has NO Mosaic lowering on the installed JAX
+(0.9.0). Compiled for a described TPU v5e, every geometry is refused —
+the row-block ``lax.dynamic_slice`` on a staged value is an
+unimplemented primitive in the Pallas TPU lowering — and even past that
+a 3-channel NHWC block pads its lane dimension 3 → 128 in VMEM, so the
+QT-Opt conv1 image block alone would be 114 MB against 16 MB of scoped
+VMEM. ``is_supported(interpret=False)`` is therefore False for
+everything, and ``kernel_policy='pool_conv'`` on a TPU runs
+``lax.conv_general_dilated`` and says so (``_pallas_dispatch.refuse``).
+The kernel remains an interpret-mode numerics reference until the chip
+numbers decide whether a lowering is worth writing.
 """
 
 from __future__ import annotations
@@ -48,7 +60,8 @@ from tensor2robot_tpu.ops.pool import resolve_padding
 
 Pads = Tuple[Tuple[int, int], Tuple[int, int]]
 
-_VMEM_BUDGET_BYTES = 10 * 1024 * 1024
+# Interpret-mode working-set bound (no VMEM exists there).
+_INTERPRET_BUDGET_BYTES = 10 * 1024 * 1024
 _ROW_BLOCKS = (16, 8, 4, 2, 1)
 # The patch depth k·k·C_in this form pays off for: a deep-C_in conv is
 # already MXU-shaped and XLA wins; the shallow first layer is the case.
@@ -56,7 +69,13 @@ _MAX_CIN = 8
 _MAX_PATCH_DEPTH = 512
 
 
-def _plan(xshape, wshape, strides, pads):
+def _plan(xshape, wshape, strides, pads, interpret: Optional[bool] = None):
+  """The static kernel geometry; None when the target the call would
+  lower to cannot run it. Only the interpreter can (module docstring)."""
+  if interpret is None:
+    interpret = dispatch.use_interpret()
+  if not interpret:
+    return None
   if len(xshape) != 4 or len(wshape) != 4:
     return None
   _, h, w, cin = xshape
@@ -77,11 +96,11 @@ def _plan(xshape, wshape, strides, pads):
   ohb = next(rb for rb in _ROW_BLOCKS if oh % rb == 0)
   patch = kh * kw * cin
   # fwd/dW stage the whole padded image + one row-block patch matrix;
-  # dx stages the whole cotangent + per-phase planes. 4-byte itemsize
-  # bounds the f32 interpret path (bf16 on chip is half).
+  # dx stages the whole cotangent + per-phase planes, all float32 under
+  # the interpreter.
   fwd_bytes = hp * wp * cin * 4 * 2 + ohb * ow * patch * 4
   dx_bytes = (oh * ow * cout + 2 * hp * wp * cin) * 4
-  if max(fwd_bytes, dx_bytes) > _VMEM_BUDGET_BYTES:
+  if max(fwd_bytes, dx_bytes) > _INTERPRET_BUDGET_BYTES:
     return None
   return dict(h=h, w=w, cin=cin, cout=cout, kh=kh, kw=kw, sh=sh, sw=sw,
               plh=plh, plw=plw, oh=oh, ow=ow, hp=hp, wp=wp, ohb=ohb,
@@ -92,14 +111,18 @@ def is_supported(xshape: Sequence[int],
                  wshape: Sequence[int],
                  strides: Tuple[int, int],
                  padding: Union[str, Sequence[Tuple[int, int]]],
-                 ) -> bool:
-  """Whether the s2d-matmul kernel handles an NHWC/HWIO conv problem."""
+                 interpret: Optional[bool] = None) -> bool:
+  """Whether the s2d-matmul kernel handles an NHWC/HWIO conv problem on
+  the target the call would lower to (``interpret=None`` resolves from
+  the backend; ``False`` — a real Mosaic lowering — is never
+  supported, see the module docstring)."""
   xshape = tuple(int(d) for d in xshape)
   if len(xshape) != 4:
     return False
   pads = resolve_padding(padding, tuple(wshape[:2]), tuple(strides),
                          xshape[1:3])
-  return _plan(xshape, tuple(wshape), tuple(strides), pads) is not None
+  return _plan(xshape, tuple(wshape), tuple(strides), pads,
+               interpret) is not None
 
 
 # ----------------------------------------------------------------- kernels
@@ -326,7 +349,8 @@ def conv2d(x, w, strides: Tuple[int, int],
            enabled: Optional[bool] = None):
   """Size-gated conv dispatch: Pallas s2d matmul when the kernel gate is
   live and the geometry fits, stock ``lax.conv_general_dilated``
-  otherwise."""
+  otherwise — announced through ``_pallas_dispatch.refuse`` when the
+  gate was live, since the call site asked for the kernel."""
   strides = tuple(strides)
   if enabled is None:
     enabled = dispatch.kernels_enabled()
@@ -335,6 +359,12 @@ def conv2d(x, w, strides: Tuple[int, int],
                            x.shape[1:3])
     if _plan(x.shape, w.shape, strides, pads) is not None:
       return pallas_conv2d(x, w, strides, pads)
+  if enabled:
+    dispatch.refuse(
+        'conv_s2d',
+        f'x {x.dtype.name}{list(x.shape)} w {list(w.shape)} strides '
+        f'{strides} padding {padding!r} is outside '
+        'ops.conv_s2d.is_supported')
   return reference_conv2d(x, w, strides, padding)
 
 

@@ -21,8 +21,12 @@ Recognition is by TAGGING, not introspection: the factories in
 (a duck-typed ``(init, update, fused_spec)`` NamedTuple — optax only
 ever touches ``.init``/``.update``) carrying the hyperparameters the
 kernel needs. Anything untagged — clipping chains, ``MultiSteps``
-wrappers, custom transformations — silently keeps the stock path, as
-does any opt-state whose structure the plan doesn't recognize.
+wrappers, custom transformations, QT-Opt's own momentum optimizer —
+keeps the stock path, as does any opt-state whose structure the plan
+doesn't recognize; ``plan_for`` says so with a WARNING and a
+``kernels/refused`` count. Compiled for a described TPU v5e
+(tests/test_chip_compile.py) the pass lowers at the Grasping44 parameter
+shapes: one ``tpu_custom_call`` per leaf.
 
 Supported optimizer kinds:
 
@@ -44,7 +48,6 @@ rtol 1e-5 on f32 params after multi-step training).
 from __future__ import annotations
 
 import dataclasses
-import logging
 import math
 from typing import Any, Callable, NamedTuple, Optional, Tuple, Union
 
@@ -160,22 +163,24 @@ def plan_for(optimizer, ema_decay: Optional[float] = None,
   None whenever the kernel gate is off (``dispatch.kernels_enabled()``
   consulted at trace/build time), the optimizer is untagged or of an
   unsupported kind, or ``opt_state`` (when provided) has structure the
-  kernel doesn't rebuild. Each fallback logs its reason once per build
-  so a silently-stock run is diagnosable from the log.
+  kernel doesn't rebuild. The caller asked for the fused pass
+  (``TrainerConfig.fused_update``), so each of these is announced
+  through ``dispatch.refuse`` — a stock run is never silent about it.
   """
   if not dispatch.kernels_enabled():
-    logging.info('fused_update: kernel gate off (no TPU / no force); '
-                 'using the stock optax update path.')
+    dispatch.refuse('fused_update', 'kernel gate off: no TPU and no force')
     return None
   spec = spec_of(optimizer)
   if spec is None or spec.kind not in ('adam', 'sgd'):
-    logging.info('fused_update: optimizer is untagged or of an '
-                 'unsupported kind; using the stock optax update path.')
+    dispatch.refuse(
+        'fused_update',
+        'optimizer is not a tagged adam/sgd factory from '
+        'models/optimizers.py')
     return None
   if opt_state is not None and not supports_state(spec, opt_state):
-    logging.info('fused_update: opt_state structure not recognized '
-                 '(wrapped/chained transforms); using the stock optax '
-                 'update path.')
+    dispatch.refuse(
+        'fused_update',
+        'opt_state structure not recognized (wrapped/chained transforms)')
     return None
   return FusedPlan(spec=spec, ema_decay=ema_decay)
 

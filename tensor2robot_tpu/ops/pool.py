@@ -30,6 +30,15 @@ interpret mode off-TPU so the tier-1 suite runs this exact kernel code;
 :func:`max_pool` is the size-gated entry that falls back to the stock
 ``lax.reduce_window`` form when the gate or :func:`is_supported` says
 no (off-TPU training, exotic shapes, VMEM-overflowing blocks).
+
+On the chip: compiled for a described TPU v5e (jax 0.9.0), this kernel
+lowers only for float32, stride == window, and maps small enough for 12
+tile-padded input blocks to fit 16 MB of scoped VMEM (about 46x46 at
+C <= 128). Neither QT-Opt pool ([32,236,236,64], [32,79,79,64]) is
+inside that, in either dtype, so ``kernel_policy='pool'`` on the
+published Grasping44 runs the XLA reference and says so
+(``_pallas_dispatch.refuse``). A row-tiled kernel would be needed to
+change that; whether it is worth writing is a chip-number decision.
 """
 
 from __future__ import annotations
@@ -46,12 +55,18 @@ from tensor2robot_tpu.ops import _pallas_dispatch as dispatch
 
 Pads = Tuple[Tuple[int, int], Tuple[int, int]]
 
-# Per-kernel-instance VMEM budget for block sizing: the padded input
-# block plus the backward's assembly buffers must fit well under the
-# ~16 MB/core with headroom for Mosaic's own staging.
-_VMEM_BUDGET_BYTES = 10 * 1024 * 1024
+# Interpret-mode block sizing only (no VMEM exists there): keeps the
+# interpreter's per-instance working set small enough to stay fast.
+_INTERPRET_BUDGET_BYTES = 10 * 1024 * 1024
 
 _CHANNEL_BLOCKS = (128, 64, 32, 16, 8)
+
+# Scoped VMEM one forward instance takes, in multiples of its padded,
+# tile-aligned input block. Measured by compiling 3x3/s3 and 2x2/s2
+# float32 pools for a described v5e (the compiler reports the
+# allocation when it refuses one): 10.4-10.7x over H=W in 60..96, 8.3x
+# for 2x2. 12 leaves Mosaic's own staging some room.
+_MOSAIC_VMEM_BLOCKS = 12
 
 
 def resolve_padding(padding: Union[str, Sequence[Tuple[int, int]]],
@@ -82,16 +97,50 @@ def _out_size(size: int, k: int, s: int, lo: int, hi: int) -> int:
   return (size + lo + hi - k) // s + 1
 
 
-def _channel_block(c: int, per_channel_bytes: int) -> Optional[int]:
+def _round_up(n: int, m: int) -> int:
+  return -(-n // m) * m
+
+
+def _interpret_channel_block(c: int, per_channel_bytes: int) -> Optional[int]:
   """Largest lane block dividing C whose working set fits the budget."""
   for cb in _CHANNEL_BLOCKS:
-    if c % cb == 0 and per_channel_bytes * cb <= _VMEM_BUDGET_BYTES:
+    if c % cb == 0 and per_channel_bytes * cb <= _INTERPRET_BUDGET_BYTES:
       return cb
   return None
 
 
-def _plan(shape, window, strides, pads, dtype):
-  """Resolves the static kernel geometry; None when unsupported."""
+def _mosaic_channel_block(c, window, strides, hp, wp, dtype) -> Optional[int]:
+  """The lane block a real Mosaic lowering accepts, or None.
+
+  What the chip's compiler showed (sandbox compile for a described v5e,
+  jax 0.9.0; tests/test_chip_compile.py keeps the QT-Opt cases):
+
+  * the lane block must be the whole of C or a multiple of 128;
+  * only float32 lowers — the bf16 phase reshape is an "unsupported
+    shape cast" in infer-vector-layout;
+  * overlapping windows lower forward but not backward (``lax.pad``
+    with interior padding is unimplemented), so stride must equal the
+    window;
+  * one instance's scoped VMEM is ``_MOSAIC_VMEM_BLOCKS`` tile-padded
+    input blocks, against ``dispatch.MOSAIC_SCOPED_VMEM_BYTES``.
+  """
+  if jnp.dtype(dtype) != jnp.float32 or tuple(window) != tuple(strides):
+    return None
+  cb = dispatch.MOSAIC_LANES if c % dispatch.MOSAIC_LANES == 0 else c
+  block_bytes = (hp * _round_up(wp, dispatch.MOSAIC_SUBLANES) *
+                 _round_up(cb, dispatch.MOSAIC_LANES) * 4)
+  if _MOSAIC_VMEM_BLOCKS * block_bytes > dispatch.MOSAIC_SCOPED_VMEM_BYTES:
+    return None
+  return cb
+
+
+def _plan(shape, window, strides, pads, dtype,
+          interpret: Optional[bool] = None):
+  """Resolves the static kernel geometry; None when the target the call
+  would lower to (the interpreter, or Mosaic on a TPU backend) cannot
+  run it. ``interpret=None`` resolves from the backend."""
+  if interpret is None:
+    interpret = dispatch.use_interpret()
   if len(shape) != 4:
     return None
   _, h, w, c = shape
@@ -106,17 +155,22 @@ def _plan(shape, window, strides, pads, dtype):
     # its (zero) cotangent to; SAME/VALID never produce such pads.
     return None
   if not np.issubdtype(np.dtype(dtype), np.floating):
+    # numpy's floats only: bfloat16 is refused on both targets (Mosaic
+    # cannot lower its phase reshape; the interpreter never ran it).
     return None
   oh = _out_size(h, kh, sh, plh, phh)
   ow = _out_size(w, kw, sw, plw, phw)
   if oh < 1 or ow < 1 or c % _CHANNEL_BLOCKS[-1]:
     return None
   hp, wp = oh * sh + kh - 1, ow * sw + kw - 1
-  itemsize = np.dtype(dtype).itemsize
-  # Padded input (fwd) / assembly accumulator (bwd) dominate; slots and
-  # pooled blocks ride along. ×3 covers staged copies of the big buffer.
-  per_channel = 3 * hp * wp * itemsize + 2 * oh * ow * (itemsize + 4)
-  cb = _channel_block(c, per_channel)
+  if interpret:
+    itemsize = np.dtype(dtype).itemsize
+    # Padded input (fwd) / assembly accumulator (bwd) dominate; slots
+    # and pooled blocks ride along.
+    per_channel = 3 * hp * wp * itemsize + 2 * oh * ow * (itemsize + 4)
+    cb = _interpret_channel_block(c, per_channel)
+  else:
+    cb = _mosaic_channel_block(c, window, strides, hp, wp, dtype)
   if cb is None:
     return None
   return dict(h=h, w=w, c=c, kh=kh, kw=kw, sh=sh, sw=sw, plh=plh, plw=plw,
@@ -129,15 +183,17 @@ def is_supported(shape: Sequence[int],
                  padding: Union[str, Sequence[Tuple[int, int]]] = 'VALID',
                  dtype=jnp.float32,
                  interpret: Optional[bool] = None) -> bool:
-  """Whether the Pallas pool handles an NHWC problem — the dispatch
-  predicate :func:`max_pool` (and the kernel-policy towers) consult
-  before committing to the kernel path."""
-  del interpret  # lane minimum is on C, gated to 8-multiples either way
+  """Whether the Pallas pool handles an NHWC problem on the target the
+  call would lower to — the dispatch predicate :func:`max_pool` (and the
+  kernel-policy towers) consult before committing to the kernel path.
+  ``interpret=None`` resolves from the backend; ``False`` asks about a
+  real Mosaic lowering (see :func:`_mosaic_channel_block`)."""
   shape = tuple(int(d) for d in shape)
   if len(shape) != 4:
     return False
   pads = resolve_padding(padding, window, strides, shape[1:3])
-  return _plan(shape, tuple(window), tuple(strides), pads, dtype) is not None
+  return _plan(shape, tuple(window), tuple(strides), pads, dtype,
+               interpret) is not None
 
 
 # ----------------------------------------------------------------- kernels
@@ -152,15 +208,15 @@ def _pad_neg_inf(x, plh, plw, hp, wp):
   = pool pad + slice filler; filler positions are never selected)."""
   h, w, cb = x.shape
   dt = x.dtype
-  if plh or hp > h + plh:
-    top = jnp.full((plh, w, cb), _neg_inf(dt))
-    bottom = jnp.full((hp - h - plh, w, cb), _neg_inf(dt))
-    x = jnp.concatenate([top, x, bottom], axis=0)
-  if plw or wp > w + plw:
-    left = jnp.full((hp, plw, cb), _neg_inf(dt))
-    right = jnp.full((hp, wp - w - plw, cb), _neg_inf(dt))
-    x = jnp.concatenate([left, x, right], axis=1)
-  return x
+  # Zero-extent pieces are skipped: Mosaic rejects a zero-size
+  # broadcast, and SAME pools routinely have no low-side padding.
+  def fill(shape):
+    return [jnp.full(shape, _neg_inf(dt))] if min(shape) else []
+
+  x = jnp.concatenate(
+      fill((plh, w, cb)) + [x] + fill((hp - h - plh, w, cb)), axis=0)
+  return jnp.concatenate(
+      fill((hp, plw, cb)) + [x] + fill((hp, wp - w - plw, cb)), axis=1)
 
 
 def _window_slices(xp, kh, kw, sh, sw, oh, ow, wp, cb):
@@ -350,7 +406,9 @@ def max_pool(x, window_shape, strides=None, padding='VALID',
   Takes the Pallas kernel when the dispatch gate is live
   (:func:`_pallas_dispatch.kernels_enabled` — TPU, or forced for tests)
   AND the geometry is supported; otherwise the stock ``reduce_window``
-  form, bitwise-identical either way.
+  form, bitwise-identical either way. A live gate with unsupported
+  geometry says so (:func:`_pallas_dispatch.refuse`): the call site
+  asked for the kernel through its ``kernel_policy``.
   """
   window = tuple(window_shape)
   strides = tuple(strides or (1,) * len(window))
@@ -360,4 +418,10 @@ def max_pool(x, window_shape, strides=None, padding='VALID',
     pads = resolve_padding(padding, window, strides, x.shape[1:3])
     if _plan(x.shape, window, strides, pads, x.dtype) is not None:
       return pallas_max_pool(x, window, strides, pads)
+  if enabled:
+    dispatch.refuse(
+        'max_pool',
+        f'x {x.dtype.name}{list(x.shape)} window {window} strides '
+        f'{strides} padding {padding!r} is outside '
+        'ops.pool.is_supported')
   return reference_max_pool(x, window_shape, strides, padding)

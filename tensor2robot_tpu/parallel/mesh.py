@@ -322,11 +322,12 @@ def shard_batch(batch: Any, mesh: Mesh, formats: Any = None,
   (``utils/tfdata.py:43-66``); feeding a host-global batch on every host
   would silently duplicate data across hosts.
 
-  ``formats``: optional pytree of ``jax.experimental.layout.Format``
-  matching ``batch`` — place each leaf in the COMPILED EXECUTABLE's
-  preferred layout (see ``Trainer`` auto input layouts) so XLA never
-  re-lays the batch out inside the step. Single-process only; the
-  multi-host assembly path ignores it.
+  ``formats``: optional pytree matching ``batch`` of
+  ``jax.experimental.layout.Format`` (place the leaf in the COMPILED
+  EXECUTABLE's preferred layout — see ``Trainer`` auto input layouts —
+  so XLA never re-lays it out inside the step) or plain shardings (a
+  plain transfer). Single-process only; the multi-host assembly path
+  ignores it.
 
   ``stacked``: the batch is a ``[K, batch, ...]`` step-group
   (``steps_per_dispatch``); shard dim 1 instead of dim 0.

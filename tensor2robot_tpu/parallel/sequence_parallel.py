@@ -176,27 +176,16 @@ def ulysses_attention(q: jax.Array,
   return to_seqsharded(out)
 
 
-def _shard_map(fn, mesh: Mesh, in_specs, out_specs):
-  """shard_map across jax API generations: ``jax.shard_map(...,
-  check_vma=)`` (new) vs ``jax.experimental.shard_map.shard_map(...,
-  check_rep=)`` (0.4.x). Replication checking stays off either way —
-  the attention bodies use unchecked collectives."""
-  if hasattr(jax, 'shard_map'):
-    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-  from jax.experimental.shard_map import shard_map as legacy_shard_map
-
-  return legacy_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
-
 def _sharded_apply(fn, mesh: Mesh, axis_name: str, causal: bool):
   spec = P(None, axis_name, None, None)
 
   def apply(q, k, v):
     return fn(q, k, v, axis_name=axis_name, causal=causal)
 
-  return _shard_map(apply, mesh, (spec, spec, spec), spec)
+  # Replication checking stays off: the attention bodies use unchecked
+  # collectives.
+  return jax.shard_map(apply, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
 
 
 def make_ring_attention(mesh: Mesh,

@@ -1125,6 +1125,10 @@ class DynamicBatcher:
         'max_batch': self._max_batch,
         'batch_deadline_ms': self._deadline_s * 1e3,
         'buckets': list(self._buckets),
+        # Which executor serves: 'JitBucketExecutor' (AOT buckets over
+        # the export's jax core) or the degraded whole-batch
+        # 'PredictCallableExecutor' — a check reads it, not the log.
+        'executor': type(self.current_executor()).__name__,
         'model_version': self.model_version,
         'queue_depth': snap.get(f'{p}/queue_depth', 0.0),
         'requests': snap.get(f'{p}/requests', 0),

@@ -35,7 +35,10 @@ Endpoints:
 * ``GET /statz`` — the plane's report (same document the registry's
   ``/metricsz`` embeds via ``register_report_provider``), including the
   bounded slow-request log and latency exemplars; router mode nests
-  per-model sections plus paging/admission SLOs.
+  per-model sections plus paging/admission SLOs. Also the process's
+  ``device`` (platform / kind / count as jax reports them) and its
+  ``compile`` counters (backend compiles, seconds, persistent-cache
+  hits and misses).
 
 Status codes: 400 malformed request, 404 unknown path/model, 503 shed /
 queue full / shutting down (back off and honor ``Retry-After``), 504
@@ -55,9 +58,11 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from tensor2robot_tpu.observability import device as device_lib
 from tensor2robot_tpu.observability import slo as slo_lib
 from tensor2robot_tpu.observability import tracing
 from tensor2robot_tpu.serving import batching as batching_lib
+from tensor2robot_tpu.utils import compilation_cache
 
 _MODELS_PREFIX = '/v1/models/'
 _PREDICT_SUFFIX = '/predict'
@@ -107,6 +112,11 @@ class _Handler(http.server.BaseHTTPRequestHandler):
     elif path == '/statz':
       plane = router if router is not None else batcher
       doc = plane.report()
+      # What this replica runs on and what its start-up cost: the
+      # process facts a check of a chip deployment reads beside the
+      # plane's own.
+      doc['device'] = device_lib.describe()
+      doc['compile'] = compilation_cache.report()
       engine = slo_lib.global_engine()
       if engine is not None:
         doc['slo'] = engine.report()
@@ -251,7 +261,6 @@ class ServingServer:
                port: int = 0,
                host: str = '127.0.0.1',
                request_timeout_secs: float = 30.0,
-               compilation_cache_dir: Optional[str] = None,
                timeseries_interval_secs: float = 10.0,
                router=None,
                **batcher_kwargs):
@@ -263,11 +272,10 @@ class ServingServer:
           'ModelRouter, not the server, in router mode')
     # Persistent compile cache first: bucket warmup is the serving
     # plane's restart cost, and a cache hit turns each bucket compile
-    # into a deserialize (utils/compilation_cache.py).
-    from tensor2robot_tpu.utils.compilation_cache import (
-        maybe_enable_compilation_cache)
-
-    maybe_enable_compilation_cache(compilation_cache_dir)
+    # into a deserialize (utils/compilation_cache.py has the one rule
+    # for where the cache lives).
+    compilation_cache.enable_compilation_cache()
+    device_lib.announce('Serving plane')
     # Metrics history for /metricsz?history=1 and postmortem bundles
     # (0 disables; idempotent process-global recorder).
     from tensor2robot_tpu.observability import timeseries

@@ -33,8 +33,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.experimental.layout import Format, Layout
 
 from tensor2robot_tpu.modes import ModeKeys
+from tensor2robot_tpu.observability import device as device_lib
 from tensor2robot_tpu.observability import flight
 from tensor2robot_tpu.observability import memory as memory_lib
 from tensor2robot_tpu.observability import metrics as metrics_lib
@@ -287,8 +289,8 @@ class TrainerConfig:
   # Train steps folded into ONE device dispatch (TPUEstimator's
   # iterations_per_loop, tpu_config.py in the reference's stack): the
   # loop stacks K host batches and a lax.scan runs K optimizer steps
-  # per XLA program, so per-dispatch host overhead (RPC latency on
-  # remote/tunneled devices, python dispatch otherwise) amortizes K×.
+  # per XLA program, so per-dispatch host overhead (python dispatch,
+  # argument handling) amortizes K×.
   # Training math is IDENTICAL to K single dispatches (same rng stream:
   # the per-step fold_in keys off state.step). Logging, checkpointing
   # and eval quantize to dispatch boundaries — intervals fire at the
@@ -400,14 +402,6 @@ class TrainerConfig:
   # not just where it ended. 0 disables; the process-global recorder is
   # started once (first cadence wins).
   timeseries_interval_secs: float = 10.0
-  # Persistent XLA compilation cache (utils/compilation_cache.py): a
-  # restarted process deserializes prior executables instead of
-  # re-lowering the K×M train program, so restart-to-first-step time
-  # (the `trainer/restart_to_first_step_seconds` gauge, recorded per
-  # bench round) drops to checkpoint-restore + cache-read. None also
-  # consults the T2R_COMPILATION_CACHE_DIR env var; still-None keeps
-  # jax's in-memory cache only.
-  compilation_cache_dir: Optional[str] = None
   # Distributed resilience (train/distributed_resilience.py), the
   # multi-process extension of handle_preemption: coordinated preemption
   # (any host's SIGTERM → ALL hosts checkpoint the same step and exit
@@ -553,6 +547,7 @@ class _DevicePrefetcher:
     self._m_batches = prefetch_metrics.counter('batches')
     if place_stage is None:
       place_stage = jax.default_backend() == 'tpu'
+    prefetch_metrics.gauge('place_stage').set(float(place_stage))
     self._consumer_place = None if place_stage else place
     self._threads = []
 
@@ -835,28 +830,27 @@ def _grouped_batches(it: Iterator[Batch], k: int, start_step: int,
   return _SuperbatchAssembler(it, k, start_step, max_steps, release=release)
 
 
-def _layout_api():
-  """Adapters across jax's Layout→Format API rename.
+# Compiler-chosen (AUTO) input layouts are asked for only where the
+# re-layout copy they save would cost something: the 251 MB QT-Opt image
+# superbatch, the 507 MB WTL episode batch. A few-KB action or reward
+# leaf keeps the default layout and a plain transfer — a custom layout
+# saves it nothing and costs a jitted identity program per leaf.
+_AUTO_LAYOUT_MIN_BYTES = 1 << 20
 
-  Returns ``(make_auto, compiled_input_formats, leaf_format)``:
-  jax >= 0.5 spells compiler-chosen layouts ``Format(Layout.AUTO, s)``
-  with ``compiled.input_formats`` / ``array.format``; jax 0.4.x spells
-  them ``Layout(DeviceLocalLayout.AUTO, s)`` with
-  ``compiled.input_layouts`` / ``array.layout``. Everything downstream
-  (device_put placement, equality checks) is API-compatible.
+
+def _placed_as_asked(placed, targets) -> bool:
+  """Whether every leaf placed with a ``Format`` has that layout.
+
+  Not a formality: on the TPU v5e (jax 0.9.0, libtpu 0.0.34) the jitted
+  identity that ``jax.device_put(x, Format)`` runs, when it came back
+  from the persistent compilation cache, returned an f32[8,32,3] leaf in
+  another layout than the one it was compiled for (and still claimed),
+  and the dispatch then refused the batch (PR 21, CHANGES.md).
   """
-  try:
-    from jax.experimental.layout import Format, Layout
-
-    return (lambda s: Format(Layout.AUTO, s),
-            lambda c: c.input_formats,
-            lambda a: getattr(a, 'format', None))
-  except ImportError:
-    from jax.experimental.layout import DeviceLocalLayout, Layout
-
-    return (lambda s: Layout(DeviceLocalLayout.AUTO, s),
-            lambda c: c.input_layouts,
-            lambda a: getattr(a, 'layout', None))
+  return all(
+      not isinstance(target, Format) or leaf.format.layout == target.layout
+      for leaf, target in zip(jax.tree_util.tree_leaves(placed),
+                              jax.tree_util.tree_leaves(targets)))
 
 
 def _mean_metrics(metric_batches: List[MetricDict]) -> MetricDict:
@@ -1035,6 +1029,7 @@ class Trainer:
                shutdown: Optional[resilience.GracefulShutdown] = None):
     self._model = model
     self._config = config
+    device_lib.announce('Trainer')
     if config.matmul_precision is not None:
       # Before any module build: modules bake the precision in at
       # construction (the Dense/Conv injection classes).
@@ -1133,17 +1128,15 @@ class Trainer:
     # Metrics history ring: feeds /metricsz?history=1 and the postmortem
     # bundle's time-series window (idempotent, first cadence wins).
     timeseries.maybe_start(config.timeseries_interval_secs or None)
-    # Before the first lowering: the restart-goodput slice — executables
-    # compiled by a previous incarnation load from disk instead of
-    # recompiling (measured by restart_to_first_step_seconds below).
+    # Before the first lowering: executables compiled by a previous
+    # incarnation load from disk instead of recompiling (measured by
+    # restart_to_first_step_seconds; the compile/* counters it installs
+    # are the cause line). Where the cache lives is not this config's
+    # business: utils/compilation_cache.py has the one rule.
     from tensor2robot_tpu.utils.compilation_cache import (
-        install_compile_counters, maybe_enable_compilation_cache)
+        enable_compilation_cache)
 
-    # Cache-hit/miss + backend-compile-seconds counters ride jax's
-    # monitoring events whether or not the persistent cache is on: the
-    # restart-goodput gauge gets its cause line either way.
-    install_compile_counters()
-    maybe_enable_compilation_cache(config.compilation_cache_dir)
+    enable_compilation_cache()
 
   # ------------------------------------------------------------- properties
 
@@ -1466,29 +1459,31 @@ class Trainer:
     the train loop dispatches ``self._auto_step`` and ``place`` uses
     ``self._batch_formats``; any failure (backend without layout
     support, exotic batch leaves) permanently falls back to the default
-    jitted step. Thread-safe: the prefetcher's worker may be the first
-    caller.
+    jitted step, with a WARNING and ``trainer/auto_input_layouts`` = 0.
+    Thread-safe: the prefetcher's worker may be the first caller.
     """
     # Double-checked fast path: both fields are written exactly once,
     # under the build lock; a racing reader that sees a stale None just
     # falls through to the locked re-check below.
+    if self._auto_disabled or self._state is None:  # ANALYSIS_OK(lock-discipline): double-checked fast path; locked re-check follows
+      return False
     if self._auto_step is not None:  # ANALYSIS_OK(lock-discipline): published-once ref; locked re-check follows
       return True
-    if self._auto_disabled or self._state is None:  # ANALYSIS_OK(lock-discipline): same double-checked fast path
-      return False
     with self._auto_build_lock:
-      if self._auto_step is not None:
-        return True
       if self._auto_disabled:
         return False
+      if self._auto_step is not None:
+        return True
       try:
-        make_auto, input_formats_of, leaf_format = _layout_api()
-
         state_sharding = self._state_sharding()
-        auto = make_auto(self._loop_batch_sharding())
+        sharding = self._loop_batch_sharding()
+        auto = Format(Layout.AUTO, sharding)
+        asked = jax.tree_util.tree_map(
+            lambda x: (auto if np.asarray(x).nbytes >= _AUTO_LAYOUT_MIN_BYTES
+                       else sharding), (features, labels))
         jitted = jax.jit(
             self._loop_step_body(),
-            in_shardings=(state_sharding, auto, auto),
+            in_shardings=(state_sharding,) + asked,
             out_shardings=(state_sharding, None),
             donate_argnums=self._donate_argnums())
         t_compile0 = time.perf_counter()
@@ -1497,7 +1492,7 @@ class Trainer:
           lowered = jitted.lower(self._state, features, labels)
           compiled = lowered.compile()
         compile_seconds = time.perf_counter() - t_compile0
-        (state_fmt, feat_fmt, label_fmt), _ = input_formats_of(compiled)
+        (state_fmt, feat_fmt, label_fmt), _ = compiled.input_formats
         leaves, treedef = jax.tree_util.tree_flatten((features, labels))
         self._auto_batch_avals = (
             treedef, [(tuple(np.shape(x)), np.result_type(x))
@@ -1506,14 +1501,20 @@ class Trainer:
         # state is actually placed (state keeps its concrete sharding;
         # only batches are AUTO) — a mismatch would error mid-train, so
         # verify statically and fall back instead.
-        placed = [leaf_format(leaf)
+        placed = [getattr(leaf, 'format', None)
                   for leaf in jax.tree_util.tree_leaves(self._state)]
         expected = list(jax.tree_util.tree_leaves(state_fmt))
         if len(placed) != len(expected) or any(
             p is not None and p != e for p, e in zip(placed, expected)):
           raise ValueError('state layout mismatch vs compiled step')
-        self._batch_formats = (feat_fmt, label_fmt)
+        # place() targets: the executable's own format where the layout
+        # was its choice, the plain sharding (default layout, a plain
+        # transfer) everywhere else.
+        self._batch_formats = jax.tree_util.tree_map(
+            lambda want, fmt: fmt if isinstance(want, Format) else want,
+            asked, (feat_fmt, label_fmt))
         self._auto_step = compiled
+        metrics_lib.gauge('trainer/auto_input_layouts').set(1.0)
         if self._config.program_ledger:
           # This executable IS the program driving steady-state
           # dispatches, so it owns the 'train/step' ledger entry (the
@@ -1532,11 +1533,17 @@ class Trainer:
               steps_per_execution=self._loop_k)
         return True
       except Exception as e:  # pylint: disable=broad-except
-        logging.info(
-            'Auto input layouts unavailable (%s); using default layouts.',
-            e)
-        self._auto_disabled = True
+        self._give_up_auto_layouts(f'could not be built: {e!r}')
         return False
+
+  def _give_up_auto_layouts(self, why: str) -> None:  # HOLDS(self._auto_build_lock)
+    """The run goes on with default layouts, and says so: the gauge is
+    what chip_smoke.py reads to refuse a degraded chip run."""
+    self._auto_disabled = True
+    metrics_lib.gauge('trainer/auto_input_layouts').set(0.0)
+    logging.warning(
+        'Auto input layouts were asked for and %s; the step runs with '
+        'default layouts from here on.', why)
 
   def _batch_matches_auto(self, batch: Batch) -> bool:
     """Whether a batch has the avals the AOT auto-layout step expects.
@@ -1750,6 +1757,20 @@ class Trainer:
     # h2d_dispatches_per_step line).
     h2d_puts = metrics_lib.counter('trainer/h2d/device_puts')
 
+    def put(batch: Batch, formats):
+      if device_feed:
+        # Device feed: the whole (features, labels) group moves in ONE
+        # device_put call — one H2D burst per dispatch — instead of
+        # shard_batch's per-leaf puts. The target is the executable's
+        # preferred format tree when the auto build landed, else the
+        # loop sharding replicated over the batch's structure.
+        target = (formats if formats is not None else
+                  jax.tree_util.tree_map(lambda _: feed_sharding, batch))
+        h2d_puts.inc()
+        return jax.device_put(batch, target)
+      return mesh_lib.shard_batch(
+          batch, self._mesh, formats, stacked=self._loop_k > 1)
+
     def place(batch: Batch):
       # First placement builds the auto-layout executable from this
       # batch's avals, so every batch (including this one) lands in the
@@ -1766,19 +1787,18 @@ class Trainer:
       # lock published _batch_formats before _maybe_build_auto_step
       # returned (happens-before via the lock release).
       formats = self._batch_formats if use_auto else None
-      if device_feed:
-        # Device feed: the whole (features, labels) group moves in ONE
-        # device_put call — one H2D burst per dispatch — instead of
-        # shard_batch's per-leaf puts. The target is the executable's
-        # preferred format tree when the auto build landed, else the
-        # loop sharding replicated over the batch's structure.
-        target = (formats if formats is not None else
-                  jax.tree_util.tree_map(lambda _: feed_sharding, batch))
-        placed = jax.device_put(batch, target)
-        h2d_puts.inc()
-      else:
-        placed = mesh_lib.shard_batch(
-            batch, self._mesh, formats, stacked=self._loop_k > 1)
+      placed = put(batch, formats)
+      if formats is not None and not _placed_as_asked(placed, formats):
+        # jax handed back another layout than the one asked for:
+        # dispatching that into the layout-specialized executable is a
+        # runtime error. Give the executable up, loudly, and place this
+        # batch (and every later one) the default way.
+        with self._auto_build_lock:
+          self._give_up_auto_layouts(
+              'a placed batch came back in another layout than the '
+              'executable was compiled for')
+        use_auto = False
+        placed = put(batch, None)
       place_ms = (time.perf_counter() - t0) * 1e3
       if threading.get_ident() == loop_ident:
         # Critical-path placement: carved out of host_wait in the
@@ -1934,6 +1954,9 @@ class Trainer:
           # one-off block adds no steady-state sync (first dispatch is
           # excluded from the breakdown as compile anyway).
           jax.block_until_ready(scalars)
+          # Wait for the batch + compile + first K steps, to readiness.
+          metrics_lib.gauge('trainer/first_dispatch_seconds').set(
+              time.perf_counter() - t_wait0)
           _record_restart_to_first_step()
           _record_sigterm_to_resumed(config.model_dir, step)
         before = step

@@ -1,17 +1,25 @@
-"""Persistent XLA compilation cache wiring (restart-goodput slice).
+"""Persistent XLA compilation cache: one rule for where it lives.
 
 Preemption resilience (PR 1/5) makes restarts *correct*; this makes them
-*cheap*: every restart of the trainer or the serving plane otherwise pays
-full XLA recompilation of the train program / all serving buckets before
-the first useful step. Pointing ``jax_compilation_cache_dir`` at a
-persistent directory lets a restarted process deserialize yesterday's
-executables instead of re-lowering them.
+*cheap*: every start of the trainer or the serving plane otherwise pays
+full XLA compilation of the train program / all serving buckets before
+the first useful step. With the persistent cache a later process
+deserializes those executables instead of compiling them.
 
-Opt-in via ``TrainerConfig.compilation_cache_dir``, the serving plane's
-``compilation_cache_dir`` knob, or the ``T2R_COMPILATION_CACHE_DIR`` env
-var. The restart payoff is measured by the
-``trainer/restart_to_first_step_seconds`` gauge (set by the trainer at
-its first completed dispatch) and recorded per bench round.
+The rule (:func:`enable_compilation_cache`, called by the trainer and
+the serving plane before their first lowering):
+
+* ``JAX_COMPILATION_CACHE_DIR`` set — jax itself already placed the
+  cache there when it was imported; this module sets no directory.
+* unset — the cache goes to :data:`DEFAULT_DIR`, one fixed path inside
+  the checkout (git-ignored). Fixed because the path is part of the
+  cache key's surroundings: a directory that moves never hits.
+
+So the cache is on by default and only jax's own variable places it;
+there is no config field, flag or repo-specific variable to disagree
+with it. The restart payoff is measured by the
+``trainer/restart_to_first_step_seconds`` gauge and explained by the
+``compile/*`` counters below.
 """
 
 from __future__ import annotations
@@ -19,16 +27,21 @@ from __future__ import annotations
 import logging
 import os
 import threading
-from typing import Optional
+from typing import Dict, Optional
 
-ENV_VAR = 'T2R_COMPILATION_CACHE_DIR'
+from tensor2robot_tpu.observability import metrics as metrics_lib
+
+ENV_VAR = 'JAX_COMPILATION_CACHE_DIR'
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), '.jax_cache')
 
 _lock = threading.Lock()
 _enabled_dir: Optional[str] = None  # GUARDED_BY(_lock)
 _counters_installed = False  # GUARDED_BY(_lock)
 
 
-def install_compile_counters() -> bool:
+def install_compile_counters() -> None:
   """Wires jax's monitoring events into compile/cache counters.
 
   Registers process-wide listeners translating jax's internal
@@ -42,104 +55,88 @@ def install_compile_counters() -> bool:
     XLA backend compile and its total wall time (the denominator
     restart goodput is trying to erase).
 
-  Idempotent, False (and silent) when jax or its monitoring module is
-  unavailable — same never-raises contract as the cache enabling.
+  Idempotent.
   """
   global _counters_installed
   with _lock:
     if _counters_installed:
-      return True
-    try:
-      from jax import monitoring
+      return
+    from jax import monitoring
 
-      from tensor2robot_tpu.observability import metrics as metrics_lib
+    hits = metrics_lib.counter('compile/cache_hits')
+    misses = metrics_lib.counter('compile/cache_misses')
+    compiles = metrics_lib.counter('compile/backend_compiles')
+    seconds = metrics_lib.counter('compile/compile_seconds')
 
-      hits = metrics_lib.counter('compile/cache_hits')
-      misses = metrics_lib.counter('compile/cache_misses')
-      compiles = metrics_lib.counter('compile/backend_compiles')
-      seconds = metrics_lib.counter('compile/compile_seconds')
+    # The callbacks run inside jax's compile path and must stay
+    # allocation-light and exception-free.
+    def on_event(name: str, **kwargs) -> None:
+      del kwargs
+      if name == '/jax/compilation_cache/cache_hits':
+        hits.inc()
+      elif name == '/jax/compilation_cache/cache_misses':
+        misses.inc()
 
-      # Suffix-matched (not equality) so minor jax event renames keep
-      # counting; the callbacks run inside jax's compile path and must
-      # stay allocation-light and exception-free.
-      def on_event(name: str, **kwargs) -> None:
-        del kwargs
-        if name.endswith('/cache_hits'):
-          hits.inc()
-        elif name.endswith('/cache_misses'):
-          misses.inc()
+    def on_duration(name: str, duration_secs: float, **kwargs) -> None:
+      del kwargs
+      if name == '/jax/core/compile/backend_compile_duration':
+        compiles.inc()
+        seconds.inc(duration_secs)
 
-      def on_duration(name: str, duration_secs: float, **kwargs) -> None:
-        del kwargs
-        if name.endswith('/backend_compile_duration'):
-          compiles.inc()
-          seconds.inc(duration_secs)
-
-      monitoring.register_event_listener(on_event)
-      monitoring.register_event_duration_secs_listener(on_duration)
-      _counters_installed = True
-      return True
-    except Exception as e:  # pylint: disable=broad-except
-      logging.info('Compile counters unavailable: %r', e)
-      return False
+    monitoring.register_event_listener(on_event)
+    monitoring.register_event_duration_secs_listener(on_duration)
+    _counters_installed = True
 
 
 def enabled_dir() -> Optional[str]:
-  """The cache dir this process enabled, or None."""
+  """The cache dir in force since :func:`enable_compilation_cache`."""
   with _lock:
     return _enabled_dir
 
 
-def maybe_enable_compilation_cache(
-    cache_dir: Optional[str] = None) -> Optional[str]:
-  """Enables the persistent compilation cache if configured.
+def report() -> Dict[str, object]:
+  """Where the cache is and what compiling cost so far: the ``compile``
+  section of ``metrics.report()`` and of the serving plane's ``/statz``."""
+  return dict(metrics_lib.snapshot('compile/'), dir=enabled_dir())
 
-  ``cache_dir=None`` consults ``T2R_COMPILATION_CACHE_DIR``; still-None
-  leaves jax's default behavior untouched (in-memory cache only).
-  Idempotent and first-wins: jax reads the config at compile time, so a
-  second caller asking for a DIFFERENT directory gets a warning and the
-  already-enabled one. Never raises — a cache is an optimization and
-  must not take down a training job or a serving host.
+
+def enable_compilation_cache() -> Optional[str]:
+  """Turns the persistent compilation cache on; returns its directory.
+
+  Idempotent. With ``JAX_COMPILATION_CACHE_DIR`` set the directory is
+  jax's own reading of it and nothing is set here; otherwise
+  :data:`DEFAULT_DIR`. Returns None only when the default directory
+  cannot be created (a read-only checkout): the run goes on uncached
+  and says so.
   """
   global _enabled_dir
-  resolved = cache_dir or os.environ.get(ENV_VAR, '').strip() or None
-  if not resolved:
-    with _lock:
-      return _enabled_dir
   with _lock:
-    if _enabled_dir is not None:
-      if os.path.abspath(resolved) != os.path.abspath(_enabled_dir):
-        logging.warning(
-            'Compilation cache already enabled at %r; ignoring request '
-            'for %r.', _enabled_dir, resolved)
-      return _enabled_dir
-    try:
+    if _enabled_dir is None:
       import jax
 
-      os.makedirs(resolved, exist_ok=True)
-      jax.config.update('jax_compilation_cache_dir', resolved)
+      if os.environ.get(ENV_VAR, '').strip():
+        resolved = jax.config.jax_compilation_cache_dir
+      else:
+        resolved = DEFAULT_DIR
+        try:
+          os.makedirs(resolved, exist_ok=True)
+        except OSError as e:
+          logging.warning(
+              'Cannot create the compilation cache at %r (%r); every '
+              'start of this program will recompile. Set %s to a '
+              'writable directory.', resolved, e, ENV_VAR)
+          return None
+        jax.config.update('jax_compilation_cache_dir', resolved)
       # Cache EVERYTHING: the defaults skip fast-compiling programs, but
       # restart goodput is the sum over all of them (K×M train program +
       # every serving bucket), and disk is cheap next to a restart.
-      for knob, value in (
-          ('jax_persistent_cache_min_compile_time_secs', 0.0),
-          ('jax_persistent_cache_min_entry_size_bytes', -1),
-      ):
-        try:
-          jax.config.update(knob, value)
-        except Exception:  # pylint: disable=broad-except
-          pass  # knob renamed/absent in this jax: dir alone still caches
+      jax.config.update('jax_persistent_cache_min_compile_time_secs', 0.0)
+      jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
       _enabled_dir = resolved
-      from tensor2robot_tpu.observability import metrics as metrics_lib
-
       metrics_lib.gauge('compile_cache/enabled').set(1.0)
-      logging.info('Persistent compilation cache enabled at %r', resolved)
-    except Exception as e:  # pylint: disable=broad-except
-      logging.warning('Could not enable compilation cache at %r: %r',
-                      resolved, e)
-  # Hit/miss/compile-time counters are meaningful exactly when the
-  # cache is in play; installed outside the state lock (the installer
-  # takes it itself).
+      metrics_lib.register_report_provider('compile', report)
+      logging.info('Persistent compilation cache at %r', resolved)
+  # Installed outside the state lock (the installer takes it itself).
   install_compile_counters()
   with _lock:
     return _enabled_dir

@@ -1,0 +1,172 @@
+"""AOT-compiles the main path's kernels for a DESCRIBED TPU v5e.
+
+No chip is attached: ``topologies.get_topology_desc`` hands the TPU
+compiler installed in this sandbox a described v5e, and it raises what
+the chip's compiler would raise. That is the one check interpret mode
+cannot make — a lane block Mosaic rejects, a kernel over its scoped
+VMEM, a primitive with no TPU lowering (on-chip-measurement guide §2.3).
+Each case pins the outcome settled for it: the kernel lowers with at
+least one ``tpu_custom_call`` at the real width, or ``is_supported``
+says no for the Mosaic target AND an explicit request is refused loudly
+(``kernels/refused`` + WARNING) rather than silently running the XLA
+reference. A compile that passes here is not a chip run.
+"""
+
+import logging
+import os
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from tensor2robot_tpu.models import optimizers
+from tensor2robot_tpu.observability import metrics
+from tensor2robot_tpu.ops import _pallas_dispatch as dispatch
+from tensor2robot_tpu.ops import (conv_s2d, flash_attention, fused_update,
+                                  photometric, pool)
+from tensor2robot_tpu.research.qtopt import optimizer_builder
+
+pytestmark = pytest.mark.kernels
+
+# QT-Opt Grasping44 at its published width, batch 32.
+IMAGE = (32, 472, 472, 3)
+CONV1_W = (6, 6, 3, 64)
+POOL1 = (32, 236, 236, 64)  # after conv1 (6x6 stride 2)
+POOL2 = (32, 79, 79, 64)  # after pool1 (3x3 stride 3 SAME)
+# Every distinct parameter shape of GraspingModelWrapper().
+PARAM_SHAPES = ((64,), (3, 3, 64, 64), (5, 5, 64, 64), (256,), (6, 6, 3, 64),
+                (4096, 64), (64, 64), (5, 256), (256, 64), (1,), (64, 1))
+
+
+@pytest.fixture(scope='module')
+def chip():
+  """``shape, dtype -> ShapeDtypeStruct`` placed on one described v5e
+  chip; the module is skipped where the topology cannot be described."""
+  os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+  try:
+    from jax.experimental import topologies
+
+    topo = topologies.get_topology_desc(platform='tpu',
+                                        topology_name='v5e:2x2')
+  except Exception as e:  # pylint: disable=broad-except
+    pytest.skip(f'cannot describe a TPU v5e here: {e!r}')
+  # A compile for a described chip is written to the persistent cache
+  # but can never be read back without the chip (every later run would
+  # warn and recompile): keep these out of it.
+  was_enabled = jax.config.jax_enable_compilation_cache
+  jax.config.update('jax_enable_compilation_cache', False)
+  compilation_cache.reset_cache()
+  sharding = SingleDeviceSharding(topo.devices[0])
+  try:
+    with mock.patch.object(dispatch, 'use_interpret', lambda: False):
+      yield lambda shape, dtype: jax.ShapeDtypeStruct(
+          shape, dtype, sharding=sharding)
+  finally:
+    jax.config.update('jax_enable_compilation_cache', was_enabled)
+    compilation_cache.reset_cache()
+
+
+def _custom_calls(fn, *args) -> int:
+  return jax.jit(fn).lower(*args).compile().as_text().count(
+      'tpu_custom_call')
+
+
+def _refused_loudly(caplog, fn, *args) -> None:
+  """Tracing ``fn`` counts one refusal, warns, and emits no kernel."""
+  before = metrics.counter('kernels/refused').value
+  with caplog.at_level(logging.WARNING), dispatch.force_kernels(True):
+    text = jax.jit(fn).lower(*args).as_text()
+  assert metrics.counter('kernels/refused').value == before + 1
+  assert 'was requested but cannot run here' in caplog.text
+  assert 'tpu_custom_call' not in text
+
+
+def _photometric(chip, caplog):
+  del caplog
+  assert _custom_calls(
+      lambda x, b, c: photometric.fused_brightness_contrast(
+          x, b, c, interpret=False),
+      chip(IMAGE, jnp.float32), chip(IMAGE[:1], jnp.float32),
+      chip(IMAGE[:1], jnp.float32)) == 1
+
+
+def _flash_attention(chip, caplog):
+  del caplog
+  qkv = chip((2, 4096, 8, 64), jnp.bfloat16)
+  assert flash_attention.is_supported(4096, 64, interpret=False)
+
+  def loss(q, k, v):
+    out = flash_attention.flash_attention(q, k, v, causal=True)
+    return out.astype(jnp.float32).sum()
+
+  # Forward + the dq and dk/dv backward kernels.
+  assert _custom_calls(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv) == 3
+
+
+def _pool_qtopt_refused(chip, caplog):
+  window = (3, 3)
+  for shape in (POOL1, POOL2):
+    for dtype in (jnp.float32, jnp.bfloat16):
+      assert not pool.is_supported(shape, window, window, 'SAME', dtype,
+                                   interpret=False), (shape, dtype)
+  _refused_loudly(
+      caplog, lambda x: pool.max_pool(x, window, window, 'SAME'),
+      chip(POOL2, jnp.bfloat16))
+
+
+def _pool_small_lowers(chip, caplog):
+  # The Mosaic rules are not vacuous: a map inside them lowers, forward
+  # and routed backward.
+  del caplog
+  shape, window = (2, 40, 40, 64), (2, 2)
+  assert pool.is_supported(shape, window, window, 'SAME', jnp.float32,
+                           interpret=False)
+  with dispatch.force_kernels(True):
+    assert _custom_calls(
+        jax.grad(lambda x: pool.max_pool(x, window, window, 'SAME').sum()),
+        chip(shape, jnp.float32)) == 2
+
+
+def _conv_s2d_refused(chip, caplog):
+  # Nothing lowers for Mosaic, the first-layer width or a small one.
+  for shape in (IMAGE, (2, 32, 32, 3)):
+    assert not conv_s2d.is_supported(shape, CONV1_W, (2, 2), 'SAME',
+                                     interpret=False)
+  _refused_loudly(
+      caplog, lambda x, w: conv_s2d.conv2d(x, w, (2, 2), 'SAME'),
+      chip(IMAGE, jnp.bfloat16), chip(CONV1_W, jnp.bfloat16))
+
+
+def _fused_update(chip, caplog):
+  params = {str(i): chip(shape, jnp.float32)
+            for i, shape in enumerate(PARAM_SHAPES)}
+  scalar = chip((), jnp.bool_)
+  with dispatch.force_kernels(True):
+    adam = optimizers.create_adam_optimizer(1e-4)
+    opt_state = jax.tree_util.tree_map(
+        lambda x: chip(x.shape, x.dtype), jax.eval_shape(adam.init, params))
+    plan = fused_update.plan_for(adam, ema_decay=0.9999, opt_state=opt_state)
+    assert plan is not None
+    assert _custom_calls(
+        lambda p, g, s, e, ok: fused_update.apply_update(
+            plan, p, g, s, e, ok=ok),
+        params, params, opt_state, params, scalar) == len(PARAM_SHAPES)
+    # QT-Opt's own optimizer (momentum, untagged) cannot be fused: the
+    # explicit request is refused loudly, not dropped.
+    before = metrics.counter('kernels/refused').value
+    with caplog.at_level(logging.WARNING):
+      qtopt = optimizer_builder.build_opt(optimizer_builder.default_hparams())
+      assert fused_update.plan_for(qtopt, ema_decay=0.9999) is None
+    assert metrics.counter('kernels/refused').value == before + 1
+    assert 'fused_update kernel was requested' in caplog.text
+
+
+@pytest.mark.parametrize('case', [
+    _photometric, _flash_attention, _pool_qtopt_refused, _pool_small_lowers,
+    _conv_s2d_refused, _fused_update,
+], ids=lambda fn: fn.__name__.lstrip('_'))
+def test_compiles_for_described_v5e(case, chip, caplog):
+  case(chip, caplog)

@@ -305,9 +305,15 @@ def test_grad_accum_matches_reference_accumulation(workload):
       grad_accum_microbatches=2))
   state0 = trainer.initialize(features)
   state0 = jax.device_get(state0)
-  reference = _reference_accum_step(
-      model, trainer._optimizer, jax.tree_util.tree_map(jnp.asarray, state0),  # pylint: disable=protected-access
-      features, labels, m=2)
+  # The naive loop, compiled: run op by op it is not its own oracle —
+  # on jaxlib 0.9.0 the eager form of this very function differs from
+  # its jitted form by 3.5e-3 on the grasp2vec arm (ghost BatchNorm over
+  # 2-example microbatches amplifies XLA's eager-vs-fused rounding),
+  # while the trainer's scan agrees with the jitted form to 7e-7.
+  reference = jax.jit(
+      lambda state, f, l: _reference_accum_step(
+          model, trainer._optimizer, state, f, l, m=2))(  # pylint: disable=protected-access
+              jax.tree_util.tree_map(jnp.asarray, state0), features, labels)
 
   trainer.train(iter([(features, labels)]), None)
   got = trainer.state
@@ -317,11 +323,11 @@ def test_grad_accum_matches_reference_accumulation(workload):
     assert (a is None) == (b is None), name
     if a is None:
       continue
-    # Tolerance: the reference runs eagerly while the trainer's step is
-    # one fused XLA program over bf16 towers — summation orders differ,
-    # so pin semantics at ~1e-5 absolute (params are O(1e-2); a wrong
-    # rng key, a missed EMA update, or f32-vs-bf16 accumulators all
-    # blow past this by orders of magnitude).
+    # Tolerance: the reference is the unrolled loop while the trainer's
+    # step is a scan with accumulator carries — summation orders
+    # differ, so pin semantics at ~1e-5 absolute (params are O(1e-2); a
+    # wrong rng key, a missed EMA update, or f32-vs-bf16 accumulators
+    # all blow past this by orders of magnitude).
     jax.tree_util.tree_map(
         lambda x, y: np.testing.assert_allclose(
             np.asarray(x, np.float32), np.asarray(y, np.float32),

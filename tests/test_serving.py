@@ -545,33 +545,54 @@ def test_metricsz_serving_report_e2e():
 # --------------------------------------------------- restart goodput slice
 
 
-def test_compilation_cache_populates_dir(tmp_path):
-  """End-to-end in a clean subprocess (the cache config is process-
-  global): enabling via TrainerConfig.compilation_cache_dir writes
-  reusable executables into the directory."""
-  cache_dir = str(tmp_path / 'xla-cache')
+@pytest.mark.parametrize('placed', [True, False],
+                         ids=['env_places_it', 'default_in_checkout'])
+def test_compilation_cache_populates_dir(tmp_path, placed):
+  """The one cache rule, end-to-end in a clean subprocess (the cache
+  config is process-global). With JAX_COMPILATION_CACHE_DIR set, the
+  program leaves ``jax_compilation_cache_dir`` as jax read it and the
+  executables land there; unset, the cache is the fixed in-checkout
+  path. Either way it is on without being asked for."""
+  cache_dir = (str(tmp_path / 'xla-cache') if placed
+               else os.path.join(REPO, '.jax_cache'))
   script = (
       "import os, sys\n"
-      "os.environ['JAX_PLATFORMS'] = 'cpu'\n"
       "import jax, jax.numpy as jnp\n"
-      "from tensor2robot_tpu.utils.compilation_cache import ("
-      "maybe_enable_compilation_cache, enabled_dir)\n"
-      "d = sys.argv[1]\n"
-      "assert maybe_enable_compilation_cache(d) == d\n"
-      "assert enabled_dir() == d\n"
-      "# Idempotent + first-wins:\n"
-      "assert maybe_enable_compilation_cache('/elsewhere') == d\n"
-      "jax.jit(lambda x: x * 2 + 1)(jnp.arange(8.0))\n"
-      "entries = os.listdir(d)\n"
-      "assert entries, 'no cache entries written'\n"
-      "print('CACHE_OK', len(entries))\n")
+      "from tensor2robot_tpu.utils import compilation_cache as cc\n"
+      "want, placed = sys.argv[1], sys.argv[2] == 'True'\n"
+      "assert placed or want == cc.DEFAULT_DIR\n"
+      "updated = []\n"
+      "update = jax.config.update\n"
+      "jax.config.update = lambda k, v: (updated.append(k), update(k, v))\n"
+      "assert cc.enable_compilation_cache() == want, cc.enabled_dir()\n"
+      "assert cc.enable_compilation_cache() == want  # idempotent\n"
+      "assert cc.enabled_dir() == want\n"
+      "assert jax.config.jax_compilation_cache_dir == want\n"
+      "assert ('jax_compilation_cache_dir' in updated) == (not placed)\n"
+      "if placed:\n"
+      "  jax.jit(lambda x: x * 2 + 1)(jnp.arange(8.0))\n"
+      "  assert os.listdir(want), 'no cache entries written'\n"
+      "print('CACHE_OK')\n")
   env = dict(os.environ)
   env['PYTHONPATH'] = REPO + os.pathsep + env.get('PYTHONPATH', '')
-  proc = subprocess.run([sys.executable, '-c', script, cache_dir],
-                        capture_output=True, text=True, timeout=300,
-                        env=env)
+  env['JAX_PLATFORMS'] = 'cpu'
+  # conftest turns the cache off for the suite; this child is the test
+  # OF the cache.
+  env.pop('JAX_ENABLE_COMPILATION_CACHE', None)
+  env.pop('JAX_COMPILATION_CACHE_DIR', None)
+  if placed:
+    env['JAX_COMPILATION_CACHE_DIR'] = cache_dir
+  default_dir = os.path.join(REPO, '.jax_cache')
+  before = os.listdir(default_dir) if os.path.isdir(default_dir) else None
+  proc = subprocess.run(
+      [sys.executable, '-c', script, cache_dir, str(placed)],
+      capture_output=True, text=True, timeout=300, env=env)
   assert proc.returncode == 0, proc.stderr[-2000:]
   assert 'CACHE_OK' in proc.stdout
+  if placed:
+    # ... and nowhere else: the in-checkout default stayed as it was.
+    assert before == (os.listdir(default_dir)
+                      if os.path.isdir(default_dir) else None)
 
 
 def test_restart_to_first_step_gauge(tmp_path):

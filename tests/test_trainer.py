@@ -481,6 +481,47 @@ def test_auto_input_layouts_matches_default_path():
   np.testing.assert_allclose(loss_auto, loss_def, rtol=1e-5)
 
 
+def test_auto_input_layouts_give_way_loudly(monkeypatch, caplog):
+  """Compiler-chosen layouts are asked for only on leaves big enough to
+  matter; and a placed batch that comes back in another layout than the
+  executable was compiled for (seen on the TPU after a persistent-cache
+  hit) does not reach the dispatch: the run goes on the default way,
+  with a WARNING and the gauge at 0, and trains the same."""
+  from jax.experimental.layout import Format
+  from tensor2robot_tpu.observability import metrics as metrics_lib
+  from tensor2robot_tpu.train import trainer as trainer_mod
+
+  def run(auto):
+    model = MockT2RModel(device_type='tpu', create_optimizer_fn=fast_adam)
+    gen = MockInputGenerator(batch_size=16)
+    gen.set_specification_from_model(model, ModeKeys.TRAIN)
+    trainer = Trainer(model, TrainerConfig(
+        model_dir='', max_train_steps=3, eval_interval_steps=0,
+        log_interval_steps=0, prefetch_batches=0, auto_input_layouts=auto))
+    scalars = trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
+    return trainer, float(scalars['loss'])
+
+  def formats_of(trainer):
+    return [type(f) for f in
+            jax.tree_util.tree_leaves(trainer._batch_formats)]
+
+  trainer, _ = run(True)
+  assert Format not in formats_of(trainer)  # the mock's leaves are tiny
+
+  monkeypatch.setattr(trainer_mod, '_AUTO_LAYOUT_MIN_BYTES', 0)
+  trainer, loss_auto = run(True)
+  assert set(formats_of(trainer)) == {Format}
+  assert metrics_lib.gauge('trainer/auto_input_layouts').value == 1.0
+
+  monkeypatch.setattr(trainer_mod, '_placed_as_asked', lambda *a: False)
+  with caplog.at_level('WARNING'):
+    trainer, loss_fallback = run(True)
+  assert 'another layout than the executable was compiled for' in caplog.text
+  assert metrics_lib.gauge('trainer/auto_input_layouts').value == 0.0
+  assert trainer.step == 3
+  np.testing.assert_allclose(loss_fallback, loss_auto, rtol=1e-5)
+
+
 def test_steps_per_dispatch_matches_single_step_path():
   """K steps folded into one lax.scan dispatch train IDENTICALLY to K
   single dispatches (same batches, same per-step rng fold_in keyed off
