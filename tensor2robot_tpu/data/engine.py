@@ -383,7 +383,11 @@ class ParallelBatchEngine:
         # outstanding-ticket bound, and a ticket only exists with its
         # semaphore permit held.
       try:
-        with tracing.span('data/engine/parse_decode', annotate=False):
+        # Keyed by the ticket: the engine's own sequence (it restarts
+        # with every engine and counts batches the trainer may drop), so
+        # not the trainer's batch ordinal.
+        with tracing.span('data/engine/parse_decode', key=seq,
+                          annotate=False):
           if slot is None:
             batch = self._parse_fn(records)
           else:
@@ -525,7 +529,8 @@ class ParallelBatchEngine:
     for record in self._records:
       pending.append(record)
       if len(pending) >= self._batch_size:
-        with tracing.span('data/engine/parse_decode', annotate=False):
+        with tracing.span('data/engine/parse_decode', key=self.delivered,
+                          annotate=False):
           batch = self._parse_fn(pending)
         self.delivered += 1
         self._m_batches.inc()

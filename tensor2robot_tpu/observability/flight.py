@@ -20,9 +20,10 @@ Design constraints, in the observability tradition:
   time (:data:`MAX_DETAIL_CHARS`), so the ring's byte footprint is
   stable no matter how many events flow through it (pinned by the
   100k-event soak in ``tests/test_postmortem.py``). Overwritten events
-  are simply gone — a flight recorder keeps the LAST N, which is the
-  opposite retention policy from ``tracing.start_capture`` (keeps the
-  first N and counts drops): incidents need the end of the story.
+  are simply gone — a flight recorder keeps the LAST N, as the span ring
+  of ``tracing`` does (a ``tracing.start_capture`` view of that ring
+  keeps the first N since its mark and counts drops): incidents need
+  the end of the story.
 * **Cheap enough for dispatch boundaries.** ``event()`` is one enabled
   check, one tuple build, one lock'd slot store (~1 µs); disabled it is
   a single module-global read. Span feeding filters on duration BEFORE

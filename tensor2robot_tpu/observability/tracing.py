@@ -1,31 +1,51 @@
-"""Host-side span tracing that lines up with XLA device traces.
+"""Host-side span tracing: one always-on ring of finished spans.
 
-``with span('data/decode'):`` does three things at once:
+``with span('data/decode', key=n):`` does three things at once:
 
 1. accumulates the span's wall time into the metrics registry
    (histogram ``'<name>_ms'``), so per-scope totals are queryable
    without any trace viewer;
-2. when a capture is active (:func:`start_capture` /
-   :func:`capture`), appends a Chrome-trace ``X`` (complete) event to a
-   bounded in-memory buffer, exportable with :func:`dump_chrome_trace`
-   and viewable in ``chrome://tracing`` / Perfetto — or summarized by
-   ``tools/trace_summary.py``;
-3. enters a ``jax.profiler.TraceAnnotation`` so that when a
-   ``jax.profiler`` trace is running, the host span appears on the host
-   threads of the SAME xplane timeline as the XLA device ops — host
-   wait-for-batch and device step line up in one view.
+2. appends ONE tuple ``(name, start_ns, end_ns, thread, key)`` to the
+   process-global span ring: bounded (the last :data:`RING_CAPACITY`
+   spans stay), always on, and lock-free on this path (a ``deque``
+   append and an ``itertools.count`` draw are each atomic). Times are
+   ``time.perf_counter_ns()``; ``thread`` is the recording thread's
+   name; ``key`` is whatever identifier the call site shares with the
+   work the span belongs to (the trainer keys every stage by the batch
+   ordinal, which is the dispatch ordinal). The parent of a span is the
+   span that encloses it on its thread: derived when read, not stored;
+3. enters a ``jax.profiler.TraceAnnotation`` so that a ``jax.profiler``
+   trace taken WITH the host tracer shows the span on the host threads.
+   That tracer slows a TPU host several times over (PERF.md), so device
+   traces are as a rule taken without it, and a reader places the ring's
+   spans on the trace's clock itself: :func:`clock_anchor` ties
+   ``perf_counter_ns`` to the wall clock, the trace's
+   ``profile_start_time`` ties the wall clock to the trace
+   (``benchmark/metrics/_program_spans.py`` does exactly this).
 
-(1) is always on and costs two ``perf_counter`` calls plus one lock'd
-histogram update (~1 µs); (2) and (3) are no-ops unless their capture
-is active. jax itself is imported lazily so the metrics/tracing pair
-stays importable on hosts without jax (the serving-host contract);
-everything degrades gracefully to host-only timing.
+:func:`record` takes a finished span from a call site that already holds
+both timestamps (the trainer's loop reads the clock once a boundary and
+hands the same reads to its breakdown and to the ring). :func:`recent`
+returns the ring's spans, :func:`taken` how many it ever took: a reader
+that finds ``taken() > RING_CAPACITY`` and the oldest span younger than
+the stretch it wants knows that stretch has been overwritten.
 
-Spans nest lexically (the Chrome trace nests ``X`` events per thread by
-ts/dur containment). :func:`step_annotation` wraps
-``jax.profiler.StepTraceAnnotation`` so trainer dispatches carry step
-markers in captured traces (TensorBoard's step-time view keys off
-them).
+The operator's capture surface (:func:`start_capture` / :func:`capture`
+/ :func:`chrome_trace` / :func:`dump_chrome_trace`) is a VIEW of the
+ring: a mark at start, the spans since the mark turned into Chrome-trace
+``X`` events when read (viewable in ``chrome://tracing`` / Perfetto, or
+summarized by ``tools/trace_summary.py``). ``max_events`` bounds the
+view; what a view could not hold, beyond ``max_events`` or overwritten
+in the ring before it was read, counts as dropped
+(``tracing/dropped_events``).
+
+jax itself is imported lazily so the metrics/tracing pair stays
+importable on hosts without jax (the serving-host contract); everything
+degrades gracefully to host-only timing. Spans nest lexically (the
+Chrome trace nests ``X`` events per thread by ts/dur containment).
+:func:`step_annotation` wraps ``jax.profiler.StepTraceAnnotation`` so
+trainer dispatches carry step markers in captured traces (TensorBoard's
+step-time view keys off them).
 
 **Cross-process request tracing** (the fleet half of this module): a
 request entering the fleet carries a W3C-``traceparent``-style context —
@@ -48,18 +68,23 @@ request), and nothing at all on untraced requests.
 from __future__ import annotations
 
 import binascii
+import collections
 import contextlib
 import gzip
+import itertools
 import json
 import os
 import threading
 import time
-from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Sequence
+import zlib
+from typing import (Any, Dict, Hashable, Iterator, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 from tensor2robot_tpu.observability import flight, metrics
 
 __all__ = [
-    'span', 'step_annotation', 'start_capture', 'stop_capture', 'capture',
+    'span', 'record', 'recent', 'taken', 'clock_anchor', 'RING_CAPACITY',
+    'step_annotation', 'start_capture', 'stop_capture', 'capture',
     'capturing', 'chrome_trace', 'dump_chrome_trace',
     'TraceContext', 'parse_traceparent', 'format_traceparent',
     'mint_trace_id', 'mint_span_id', 'SpanIndex', 'span_index',
@@ -67,14 +92,19 @@ __all__ = [
     'tracez_document', 'TRACEPARENT_HEADER',
 ]
 
-# perf_counter epoch for event timestamps: Chrome trace wants µs from an
-# arbitrary-but-consistent origin.
-_EPOCH = time.perf_counter()
+# (name, start_ns, end_ns, thread, key), times in perf_counter_ns.
+Span = Tuple[str, int, int, str, Optional[Hashable]]
 
-_lock = threading.Lock()
-_events: Optional[List[dict]] = None  # None = capture off  # GUARDED_BY(_lock)
-_events_cap = 0  # GUARDED_BY(_lock)
-_dropped = 0  # GUARDED_BY(_lock)
+# The last this-many finished spans stay. The record-fed trainer writes
+# some tens a second, so the ring reaches back a quarter of an hour or
+# more; full, it holds ~10 MB of tuples.
+RING_CAPACITY = 65536
+
+# One store. An entry is a span behind its sequence number: the draw
+# from ``_SEQ`` is atomic, so the numbers count every span ever taken
+# with no lock, and a view can tell "since my mark" exactly.
+_RING: 'collections.deque' = collections.deque(maxlen=RING_CAPACITY)
+_SEQ = itertools.count()
 
 
 _ANNOTATION_CLS = None  # lazily resolved; False = unavailable
@@ -99,27 +129,41 @@ def _annotation_class():
   return _ANNOTATION_CLS or None
 
 
+def record(name: str, t0_ns: int, t1_ns: int,
+           key: Optional[Hashable] = None) -> None:
+  """Takes one finished span, ``perf_counter_ns`` endpoints, recorded
+  under the calling thread's name: histogram, flight feed, ring."""
+  metrics.histogram(name + '_ms').observe((t1_ns - t0_ns) / 1e6)
+  # Flight-recorder feed: coarse (>= flight.span_feed_min_ms) spans
+  # land in the crash-forensics ring; the duration filter runs before
+  # any locking, so hot-loop micro-spans pay two float compares.
+  flight.note_span(name, t0_ns / 1e9, t1_ns / 1e9)
+  _RING.append((next(_SEQ), name, t0_ns, t1_ns,
+                threading.current_thread().name, key))
+
+
 class span:  # noqa: N801 - context manager used as a function
   """Times a host-side region under ``name`` (slash-scoped).
 
   A slotted class rather than a ``@contextmanager`` generator: this
-  sits in the trainer's per-dispatch hot path, and the generator
-  protocol alone costs ~3 µs per use (measured) — the class form runs
-  in ~1 µs, keeping full instrumentation inside the hot loop's <2%
-  overhead budget.
+  sits in per-batch hot paths, and the generator protocol alone costs
+  ~3 µs per use (measured) — the class form stays near 1 µs.
 
-  ``annotate=False`` skips the jax TraceAnnotation — for regions inside
-  tight per-record loops where even a no-op TraceMe is measurable; the
-  registry histogram and capture buffer still record.
+  ``key`` ties the span to the work it belongs to (see the module
+  docstring). ``annotate=False`` skips the jax TraceAnnotation — for
+  regions inside tight per-record loops where even a no-op TraceMe is
+  measurable; the registry histogram and the ring still record.
   """
 
-  __slots__ = ('_name', '_annotate', '_ann', '_t0')
+  __slots__ = ('_name', '_key', '_annotate', '_ann', '_t0')
 
-  def __init__(self, name: str, annotate: bool = True):
+  def __init__(self, name: str, key: Optional[Hashable] = None,
+               annotate: bool = True):
     self._name = name
+    self._key = key
     self._annotate = annotate
     self._ann = None
-    self._t0 = 0.0
+    self._t0 = 0
 
   def __enter__(self) -> 'span':
     if self._annotate:
@@ -130,74 +174,116 @@ class span:  # noqa: N801 - context manager used as a function
       if cls is not None:
         self._ann = cls(self._name)
         self._ann.__enter__()
-    self._t0 = time.perf_counter()
+    self._t0 = time.perf_counter_ns()
     return self
 
   def __exit__(self, *exc) -> bool:
-    t1 = time.perf_counter()
+    t1 = time.perf_counter_ns()
     if self._ann is not None:
       self._ann.__exit__(None, None, None)
       self._ann = None
-    metrics.histogram(self._name + '_ms').observe((t1 - self._t0) * 1e3)
-    # Flight-recorder feed: coarse (>= flight.span_feed_min_ms) spans
-    # land in the crash-forensics ring; the duration filter runs before
-    # any locking, so hot-loop micro-spans pay two float compares.
-    flight.note_span(self._name, self._t0, t1)
-    # ANALYSIS_OK(lock-discipline): racy fast-path probe on the hot
-    # span exit; _record_event re-checks under the lock before writing.
-    if _events is not None:
-      _record_event(self._name, self._t0, t1)
+    record(self._name, self._t0, t1, self._key)
     return False
 
 
-def _record_event(name: str, t0: float, t1: float) -> None:
-  global _dropped
+def clock_anchor() -> Tuple[int, int]:
+  """A fresh ``(time.time_ns(), time.perf_counter_ns())`` pair: what a
+  reader needs to move the ring's times onto the wall clock, and from
+  there (``profile_start_time``) onto a profiler trace's. Read when
+  asked, never kept: the wall clock is slewed against the monotonic
+  one, so an anchor ages."""
+  return time.time_ns(), time.perf_counter_ns()
+
+
+def _snapshot() -> Tuple[List[tuple], int]:
+  """The ring's entries, oldest first, and how many spans it has ever
+  taken: the highest number among them, plus one. A scan, not the last
+  entry's: two threads may append in the other order than they drew."""
+  entries = list(_RING)  # one C call: consistent under appends
+  return entries, max((e[0] for e in entries), default=-1) + 1
+
+
+def taken() -> int:
+  """How many spans the ring has ever taken (it holds the last
+  :data:`RING_CAPACITY`). For readers, not for hot paths."""
+  return _snapshot()[1]
+
+
+def recent(since_ns: Optional[int] = None) -> List[Span]:
+  """The ring's spans, oldest first; with ``since_ns``
+  (``perf_counter_ns``) only those that ended at or after it."""
+  entries, _ = _snapshot()
+  if since_ns is None:
+    return [e[1:] for e in entries]
+  return [e[1:] for e in entries if e[3] >= since_ns]
+
+
+# ------------------------------------------------- the capture view
+
+_capture_lock = threading.Lock()
+# (first sequence number of the view, max_events, drops already mirrored
+# into the registry); None = no capture.  # GUARDED_BY(_capture_lock)
+_capture_state: Optional[List[int]] = None
+_last_dropped = 0  # of the newest capture  # GUARDED_BY(_capture_lock)
+
+
+def _chrome_event(name: str, t0_ns: int, t1_ns: int, thread: str,
+                  key: Optional[Hashable]) -> dict:
   event = {
       'name': name,
       'ph': 'X',
-      'ts': (t0 - _EPOCH) * 1e6,
-      'dur': (t1 - t0) * 1e6,
+      'ts': t0_ns / 1e3,
+      'dur': (t1_ns - t0_ns) / 1e3,
       'pid': os.getpid(),
-      'tid': threading.get_ident(),
+      # Chrome-trace thread ids are numbers; the name rides in args.
+      'tid': zlib.crc32(thread.encode()) & 0x7fffffff,
+      'args': {'thread': thread},
   }
-  with _lock:
-    if _events is None:
-      return
-    if len(_events) >= _events_cap:
-      _dropped += 1
-      dropped_now = True
-    else:
-      _events.append(event)
-      dropped_now = False
-  if dropped_now:
+  if key is not None:
+    event['args']['key'] = key
+  return event
+
+
+def _view() -> Tuple[List[dict], int]:  # HOLDS(_capture_lock)
+  """The open capture's events and its dropped count so far."""
+  mark, cap, mirrored = _capture_state
+  entries, taken_now = _snapshot()
+  kept = [e for e in entries if e[0] >= mark][:cap]
+  dropped = taken_now - mark - len(kept)
+  if dropped > mirrored:
     # Registry mirror: a truncated capture is DETECTABLE from report()/
     # /metricsz ('tracing/dropped_events'), not only from the trace
-    # file's own metadata. Outside the lock — the counter has its own.
-    metrics.counter('tracing/dropped_events').inc()
+    # file's own metadata.
+    metrics.counter('tracing/dropped_events').inc(dropped - mirrored)
+    _capture_state[2] = dropped
+  return [_chrome_event(*e[1:]) for e in kept], dropped
 
 
 def start_capture(max_events: int = 200_000) -> None:
-  """Begins buffering span events (bounded; overflow counts as dropped)."""
-  global _events, _events_cap, _dropped
-  with _lock:
-    _events = []
-    _events_cap = int(max_events)
-    _dropped = 0
+  """Marks the ring: the capture is the spans from here on, the first
+  ``max_events`` of them (and no more than the ring still holds when
+  they are read; the rest count as dropped)."""
+  global _capture_state, _last_dropped
+  with _capture_lock:
+    _capture_state = [taken(), int(max_events), 0]
+    _last_dropped = 0
 
 
 def stop_capture() -> List[dict]:
-  """Stops buffering and returns the captured events."""
-  global _events
-  with _lock:
-    events = _events or []
-    _events = None
+  """Ends the capture and returns its events."""
+  global _capture_state, _last_dropped
+  with _capture_lock:
+    if _capture_state is None:
+      return []
+    events, _last_dropped = _view()
+    _capture_state = None
   return events
 
 
 def capturing() -> bool:
   # ANALYSIS_OK(lock-discipline): advisory single-read probe; callers
   # must not (and do not) make correctness decisions on it.
-  return _events is not None
+  return _capture_state is not None
 
 
 @contextlib.contextmanager
@@ -212,13 +298,15 @@ def capture(max_events: int = 200_000) -> Iterator[List[dict]]:
 
 
 def chrome_trace(events: Optional[List[dict]] = None) -> Dict[str, object]:
-  """Wraps events as a Chrome-trace JSON object (Perfetto-loadable)."""
-  with _lock:
-    if events is None:
-      events = list(_events) if _events is not None else []
-    dropped = _dropped
+  """Wraps events (default: the open capture's so far) as a Chrome-trace
+  JSON object (Perfetto-loadable)."""
+  with _capture_lock:
+    if _capture_state is not None:
+      seen, dropped = _view()
+    else:
+      seen, dropped = [], _last_dropped
   return {
-      'traceEvents': events,
+      'traceEvents': seen if events is None else events,
       'displayTimeUnit': 'ms',
       'metadata': {
           'producer': 'tensor2robot_tpu.observability.tracing',

@@ -172,18 +172,31 @@ def _record_sigterm_to_resumed(model_dir: str, step: int) -> None:
     logging.warning('Cannot persist loop-restart measurement: %r', e)
 
 
-def _place_releasing(place: Callable[[Batch], 'PlacedBatch'],
-                     release: Callable[[], None],
-                     batch: Batch) -> 'PlacedBatch':
-  """Places ``batch`` and returns its ring-buffer lease (data/engine.py).
+def _place_batch(place: Callable[[Batch], 'PlacedBatch'],
+                 release: Optional[Callable[[], None]],
+                 batch: Batch, key: int,
+                 wait_transfer: bool = False) -> 'PlacedBatch':
+  """Places batch ``key``, waits for its transfer where that is this
+  thread's to wait for, and returns its ring-buffer lease
+  (data/engine.py) if it holds one.
 
-  The release point depends on what placement actually does with the
-  host bytes:
+  The one placement routine of every path (placement stage, consumer
+  thread, no prefetch), so each path leaves the same spans, keyed by
+  the batch: ``trainer/place_stage`` round the whole of it and, inside,
+  ``trainer/place/put`` round the ``place`` call (layout choice and the
+  ``device_put`` / ``shard_batch`` call: host staging, returns before
+  the bytes have moved) and ``trainer/place/transfer`` round the block
+  on the placed leaves (transfer completion only, never compute).
+
+  Who blocks: the placement stage always (``wait_transfer``: it runs
+  off the loop thread, and a queue depth >= 2 keeps a placed batch
+  ahead), so that the moment a batch is really on the device is on
+  record; any thread that holds a lease, because the release point
+  depends on what placement actually does with the host bytes:
 
   * Accelerator backends: ``device_put`` COPIES to device memory, so
-    place, block on the placed leaves (transfer completion only — never
-    compute), then release. This is the ROADMAP PR-3 follow-up's
-    transfer-completion release point.
+    place, block on the placed leaves, then release. This is the
+    ROADMAP PR-3 follow-up's transfer-completion release point.
   * XLA-CPU: ``device_put`` may ZERO-COPY alias the host numpy buffer —
     "transfer completion" never copies, and releasing would let the
     engine overwrite the live batch under the step (observed as
@@ -191,13 +204,24 @@ def _place_releasing(place: Callable[[Batch], 'PlacedBatch'],
     release, then place the copy — exactly the copy ``np.stack`` paid
     before ring buffers existed.
   """
-  if jax.default_backend() == 'cpu':
+  clock = time.perf_counter_ns
+  t_start = clock()
+  on_cpu = jax.default_backend() == 'cpu'
+  if release is not None and on_cpu:
     batch = jax.tree_util.tree_map(lambda x: np.array(x, copy=True), batch)
     release()
-    return place(batch)
+    release = None
+  t_put = clock()
   placed = place(batch)
-  jax.block_until_ready(placed[0])
-  release()
+  t_placed = clock()
+  tracing.record('trainer/place/put', t_put, t_placed, key)
+  if wait_transfer or release is not None:
+    jax.block_until_ready(placed[0])
+    t_ready = clock()
+    tracing.record('trainer/place/transfer', t_placed, t_ready, key)
+  if release is not None:
+    release()
+  tracing.record('trainer/place_stage', t_start, clock(), key)
   return placed
 
 
@@ -556,18 +580,6 @@ class _DevicePrefetcher:
       self._host_q = host_q
       m_host_depth = prefetch_metrics.gauge('host_queue_depth')
 
-      def fetch():
-        try:
-          for batch in it:
-            if self._stop.is_set():
-              return
-            host_q.put(batch)
-            m_host_depth.set(host_q.qsize())
-        except BaseException as e:  # surfaced on the consumer side
-          self._err = e
-        finally:
-          host_q.put(self._DONE)
-
       def placer():
         try:
           while not self._stop.is_set():
@@ -577,12 +589,9 @@ class _DevicePrefetcher:
             # Placement overlaps the device step and the upstream
             # decode; its time shows up as placement_overlapped_ms in
             # the breakdown (off the dispatch critical path).
-            with tracing.span('trainer/place_stage', annotate=False):
-              if self._release is not None:
-                placed = _place_releasing(place, self._release, item)
-              else:
-                placed = place(item)
-            self._q.put(placed)
+            key, batch = item
+            self._q.put(_place_batch(place, self._release, batch, key,
+                                     wait_transfer=True))
         except BaseException as e:
           if self._err is None:
             self._err = e
@@ -590,28 +599,48 @@ class _DevicePrefetcher:
           self._q.put(self._DONE)
 
       self._threads = [
-          threading.Thread(target=fetch, daemon=True,
+          threading.Thread(target=self._fetch_into,
+                           args=(it, host_q, m_host_depth), daemon=True,
                            name='t2r-prefetch-fetch'),
           threading.Thread(target=placer, daemon=True,
                            name='t2r-prefetch-place'),
       ]
     else:
-      def worker():
-        try:
-          for batch in it:
-            if self._stop.is_set():
-              return
-            self._q.put(batch)
-        except BaseException as e:  # surfaced on the consumer side
-          self._err = e
-        finally:
-          self._q.put(self._DONE)
-
       self._threads = [
-          threading.Thread(target=worker, daemon=True, name='t2r-prefetch')
+          threading.Thread(target=self._fetch_into, args=(it, self._q),
+                           daemon=True, name='t2r-prefetch')
       ]
     for thread in self._threads:
       thread.start()
+
+  def _fetch_into(self, it: Iterator[Batch], out_q: 'queue.Queue',
+                  depth_gauge=None) -> None:
+    """The fetch stage: numbers the host batches as it takes them (the
+    n-th batch is the n-th dispatch: FIFO through every stage) and
+    hands ``(n, batch)`` on. ``trainer/fetch`` is the time blocked in
+    ``next(it)`` for batch n; ``trainer/fetch_put`` the time blocked
+    handing it on: the feed is ahead (back-pressure)."""
+    clock = time.perf_counter_ns
+    it = iter(it)
+    try:
+      for key in itertools.count():
+        t_fetch = clock()
+        try:
+          batch = next(it)
+        except StopIteration:
+          return
+        t_fetched = clock()
+        tracing.record('trainer/fetch', t_fetch, t_fetched, key)
+        if self._stop.is_set():
+          return
+        out_q.put((key, batch))
+        tracing.record('trainer/fetch_put', t_fetched, clock(), key)
+        if depth_gauge is not None:
+          depth_gauge.set(out_q.qsize())
+    except BaseException as e:  # surfaced on the consumer side
+      self._err = e
+    finally:
+      out_q.put(self._DONE)
 
   def __iter__(self):
     return self
@@ -638,10 +667,8 @@ class _DevicePrefetcher:
         raise self._err
       raise StopIteration
     if self._consumer_place is not None:
-      if self._release is not None:
-        item = _place_releasing(self._consumer_place, self._release, item)
-      else:
-        item = self._consumer_place(item)
+      key, batch = item
+      item = _place_batch(self._consumer_place, self._release, batch, key)
     self._m_batches.inc()
     return item
 
@@ -696,7 +723,7 @@ class _SuperbatchAssembler:
   * ``reuse=False`` (default, and the CPU path): every group gets fresh
     buffers and :meth:`release` is a no-op. Required wherever a
     zero-copy ``device_put`` may alias the host buffer for the
-    dispatch's lifetime (XLA-CPU — see ``_place_releasing``).
+    dispatch's lifetime (XLA-CPU — see ``_place_batch``).
   * ``reuse=True`` (device feed on accelerators): ``slots``
     preallocated buffer sets are recycled as a ring, mirroring the
     input engine's lease contract — the consumer calls
@@ -868,7 +895,10 @@ class _DispatchBreakdown:
   A *boundary* is the instant right after a dispatch's one-behind
   device block. ``wall(i) = boundary(i) - boundary(i-1)`` then
   decomposes EXACTLY (no untracked residue — every interval between
-  the five timestamps is attributed):
+  the five timestamps is attributed). The timestamps are the loop's one
+  set of ``perf_counter_ns`` reads, which it also hands to the span ring
+  (``trainer/after_dispatch``, ``wait_batch``, ``dispatch``,
+  ``device_wait`` tile the same intervals, keyed by dispatch):
 
     callback_ms   boundary(i-1) → start of wait: callbacks, logging,
                   checkpoint saves, interleaved eval — everything the
@@ -896,7 +926,7 @@ class _DispatchBreakdown:
     # Written by place() when it runs on the loop thread; drained by
     # record(). A plain list cell: single producer+consumer (the loop).
     self.place_ms = [0.0]
-    self._boundary: Optional[float] = None
+    self._boundary: Optional[int] = None
     self._dispatches = metrics_lib.counter('trainer/dispatches')
     self._steps = metrics_lib.counter('trainer/steps')
     self._examples = metrics_lib.counter('trainer/examples')
@@ -918,10 +948,11 @@ class _DispatchBreakdown:
     self._win_examples = 0
     self._win_skipped0 = self._skipped_counter.value
 
-  def record(self, t_wait0: float, t_wait1: float, t_disp: float,
-             t_boundary: float, steps: int, examples: int) -> None:
-    """Closes one dispatch given its four loop timestamps: start-of-wait,
-    batch-in-hand, dispatch-enqueued, after-device-block."""
+  def record(self, t_wait0: int, t_wait1: int, t_disp: int,
+             t_boundary: int, steps: int, examples: int) -> None:
+    """Closes one dispatch given its four loop timestamps
+    (``perf_counter_ns``): start-of-wait, batch-in-hand,
+    dispatch-enqueued, after-device-block."""
     self._dispatches.inc()
     self._steps.inc(steps)
     self._examples.inc(examples)
@@ -933,15 +964,15 @@ class _DispatchBreakdown:
     self._place_hist.observe(place_ms)
     if prev_boundary is None:
       return  # first dispatch: jit compile dominates; not a steady-state sample
-    callback_ms = (t_wait0 - prev_boundary) * 1e3
-    wall_ms = (t_boundary - prev_boundary) * 1e3
+    callback_ms = (t_wait0 - prev_boundary) / 1e6
+    wall_ms = (t_boundary - prev_boundary) / 1e6
     self._callback_hist.observe(callback_ms)
     self._wall_hist.observe(wall_ms)
     self._win['callback'] += callback_ms
-    self._win['wait'] += max(0.0, (t_wait1 - t_wait0) * 1e3 - place_ms)
+    self._win['wait'] += max(0.0, (t_wait1 - t_wait0) / 1e6 - place_ms)
     self._win['place'] += place_ms
-    self._win['dispatch'] += (t_disp - t_wait1) * 1e3
-    self._win['device'] += (t_boundary - t_disp) * 1e3
+    self._win['dispatch'] += (t_disp - t_wait1) / 1e6
+    self._win['device'] += (t_boundary - t_disp) / 1e6
     self._win_wall += wall_ms
     self._win_dispatches += 1
     self._win_steps += steps
@@ -1756,8 +1787,13 @@ class Trainer:
     # dispatch per K steps" (tests/test_device_feed.py; bench.py's
     # h2d_dispatches_per_step line).
     h2d_puts = metrics_lib.counter('trainer/h2d/device_puts')
+    # Bytes handed to the put, on every placement path: over the time in
+    # ``trainer/place/transfer`` they give the H2D rate.
+    h2d_bytes = metrics_lib.counter('trainer/h2d/bytes')
 
     def put(batch: Batch, formats):
+      h2d_bytes.inc(sum(getattr(leaf, 'nbytes', 0)
+                        for leaf in jax.tree_util.tree_leaves(batch)))
       if device_feed:
         # Device feed: the whole (features, labels) group moves in ONE
         # device_put call — one H2D burst per dispatch — instead of
@@ -1822,7 +1858,7 @@ class Trainer:
       # accelerator device feed the superbatch buffers are themselves a
       # two-slot ring: the assembler leases a slot per group and the
       # placement stage frees it once the H2D burst completes
-      # (``_place_releasing`` blocks on the placed arrays, then calls
+      # (``_place_batch`` blocks on the placed arrays, then calls
       # ``assembler.release``) — the host half of the double-buffered
       # donated input ring. On CPU ``device_put`` aliases host memory
       # (zero copy), so reusing buffers would corrupt in-flight
@@ -1845,11 +1881,9 @@ class Trainer:
       prefetcher = _DevicePrefetcher(host_iter, place, prefetch_depth,
                                      release=place_release)
       batches: Iterator[PlacedBatch] = iter(prefetcher)
-    elif place_release is not None:
-      batches = (_place_releasing(place, place_release, b)
-                 for b in host_iter)
     else:
-      batches = (place(b) for b in host_iter)
+      batches = (_place_batch(place, place_release, b, key)
+                 for key, b in enumerate(host_iter))
     # Previous dispatch's device-side non-finite count, evaluated one
     # dispatch behind so policy enforcement adds no sync (the update was
     # already guarded on device; the lagged dispatch ran on clean state).
@@ -1887,6 +1921,17 @@ class Trainer:
     # until it reaches it, so every host's forced checkpoint lands on
     # one common step.
     stop_step: Optional[int] = None
+    # The loop reads the clock once a boundary; the same reads feed the
+    # breakdown and the span ring. Four spans tile the loop thread's time
+    # between boundaries, keyed by the dispatch ordinal (= the batch
+    # ordinal the fetch stage counts: FIFO): ``trainer/wait_batch`` and
+    # ``trainer/dispatch`` of dispatch n, ``trainer/device_wait`` on the
+    # outputs of n-1, and ``trainer/after_dispatch``, the tail from n's
+    # boundary to the next wait (breakdown, probes, callbacks, saves,
+    # eval), closed when the next wait opens or the loop ends.
+    clock = time.perf_counter_ns
+    key = 0  # of the next dispatch
+    t_tail: Optional[int] = None  # boundary of dispatch key-1, tail open
     try:
       while step < config.max_train_steps:
         if stop_step is None:
@@ -1929,25 +1974,32 @@ class Trainer:
           for cb in self._callbacks:
             cb.end(self)
           raise resilience.PreemptedError(self.step)
-        t_wait0 = time.perf_counter()
-        with tracing.span('trainer/wait_batch'):
+        t_wait0 = clock()
+        if t_tail is not None:
+          tracing.record('trainer/after_dispatch', t_tail, t_wait0, key - 1)
+          t_tail = None
+        try:
           (features, labels), use_auto = next(batches)
-        t_wait1 = time.perf_counter()
+        finally:  # an ended stream leaves here: its wait is on record
+          t_wait1 = clock()
+          tracing.record('trainer/wait_batch', t_wait0, t_wait1, key)
         # ANALYSIS_OK(lock-discipline): published-once executable; the
         # use_auto flag travelled with the batch from under the lock.
         step_fn = (self._auto_step if use_auto and self._auto_step is not None
                    else self._train_step_fn)
-        with tracing.span('trainer/dispatch'):
-          self._state, scalars = step_fn(self._state, features, labels)
-        t_disp = time.perf_counter()
+        self._state, scalars = step_fn(self._state, features, labels)
+        t_disp = t_boundary = clock()
+        tracing.record('trainer/dispatch', t_wait1, t_disp, key)
         if breakdown.enabled and prev_out is not None:
           # One dispatch behind: the current dispatch is already on
           # device, so this block never drains the pipeline — it
           # measures the device compute not hidden by host work.
-          with tracing.span('trainer/device_wait'):
-            jax.block_until_ready(prev_out)
+          jax.block_until_ready(prev_out)
+          t_boundary = clock()
+          tracing.record('trainer/device_wait', t_disp, t_boundary, key - 1)
         prev_out = scalars
-        t_boundary = time.perf_counter()
+        t_tail = t_boundary
+        key += 1
         if not _restart_recorded:
           # Restart-goodput mark: the first dispatch's outputs becoming
           # ready means compile + restore + warmup are all paid. The
@@ -1956,7 +2008,7 @@ class Trainer:
           jax.block_until_ready(scalars)
           # Wait for the batch + compile + first K steps, to readiness.
           metrics_lib.gauge('trainer/first_dispatch_seconds').set(
-              time.perf_counter() - t_wait0)
+              (clock() - t_wait0) / 1e9)
           _record_restart_to_first_step()
           _record_sigterm_to_resumed(config.model_dir, step)
         before = step
@@ -1995,7 +2047,7 @@ class Trainer:
           # sub-ms steps only shorten the window it covers).
           flight.event(
               'dispatch', 'trainer/boundary',
-              f'step={step} wall_ms={(t_boundary - t_wait0) * 1e3:.3f}')
+              f'step={step} wall_ms={(t_boundary - t_wait0) / 1e6:.3f}')
         if self._heartbeat is not None:
           # Liveness payload: peers (and post-mortem tooling) see the
           # last COMPLETED dispatch boundary, not a wall-clock guess.
@@ -2035,8 +2087,9 @@ class Trainer:
             # summed, per-host step/age gauges — into the same scalars
             # dict TensorBoard already publishes.
             scalars.update(self._heartbeat.aggregated_scalars())
-        for cb in self._callbacks:
-          cb.after_step(self, step, scalars)
+        with tracing.span('trainer/callbacks', key=key - 1, annotate=False):
+          for cb in self._callbacks:
+            cb.after_step(self, step, scalars)
         if (self._manager is not None and
             crossed_interval(config.save_interval_steps, before, step)):
           # K > 1 boundary steps are rarely exact interval multiples;
@@ -2048,6 +2101,8 @@ class Trainer:
              step >= config.max_train_steps)):
           eval_metrics = self.evaluate(eval_iter_fn())
     finally:
+      if t_tail is not None:
+        tracing.record('trainer/after_dispatch', t_tail, clock(), key - 1)
       # A still-pending deferred harvest serves no live gauge once the
       # loop ends — cancel it (and tell an already-fired one to bail)
       # so short runs and benchmarks never pay the AOT compile.

@@ -17,8 +17,8 @@ Covers the observability subsystem end-to-end on the CPU backend:
 import json
 import os
 import threading
+import time
 
-import numpy as np
 import pytest
 
 from tensor2robot_tpu.modes import ModeKeys
@@ -182,6 +182,116 @@ class TestTracing:
         with tracing.span('spam'):
           pass
     assert len(events) == 2  # overflow dropped, not unbounded
+
+  def test_capture_is_a_view_of_the_ring(self, tmp_path):
+    """The first ``max_events`` spans since the mark, as Chrome-trace
+    ``X`` events in microseconds; the rest are counted as dropped, in
+    the dump's metadata too; a span from before the mark is not seen."""
+    with tracing.span('before/the_mark'):
+      pass
+    tracing.start_capture(max_events=3)
+    t0 = time.perf_counter_ns()
+    tracing.record('view/recorded', t0, t0 + 2_500_000, key=7)
+    for i in range(4):
+      with tracing.span('view/span', key=i):
+        pass
+    assert tracing.capturing()
+    live = tracing.chrome_trace()
+    events = tracing.stop_capture()
+    assert not tracing.capturing()
+    assert [e['name'] for e in events] == ['view/recorded'] + ['view/span'] * 2
+    assert events == live['traceEvents']
+    assert live['metadata']['dropped_events'] == 2
+    first = events[0]
+    assert first['ph'] == 'X' and first['pid'] == os.getpid()
+    assert first['ts'] == pytest.approx(t0 / 1e3)
+    assert first['dur'] == pytest.approx(2500.0)
+    assert isinstance(first['tid'], int)
+    assert first['args'] == {
+        'thread': threading.current_thread().name, 'key': 7}
+    assert [e['args']['key'] for e in events[1:]] == [0, 1]
+    # The dropped count outlives the capture, for a dump made after it.
+    path = tracing.dump_chrome_trace(str(tmp_path / 'view.json'), events)
+    with open(path) as f:
+      dumped = json.load(f)
+    assert len(dumped['traceEvents']) == 3
+    assert dumped['metadata']['dropped_events'] == 2
+    assert tracing.stop_capture() == []  # no capture: nothing, no error
+
+  def test_ring_is_bounded_and_counts_overwrites(self):
+    """The ring keeps the last RING_CAPACITY spans and counts all it
+    ever took, so a reader can tell that a stretch was overwritten."""
+    capacity = tracing.RING_CAPACITY
+    before = tracing.taken()
+    mark = time.perf_counter_ns()
+    for i in range(capacity + 10):
+      tracing.record('ring/fill', mark + i, mark + i + 1, key=i)
+    assert tracing.taken() == before + capacity + 10
+    kept = tracing.recent()
+    assert len(kept) == capacity
+    # The oldest ten of this stretch are gone; what stays is in order.
+    assert [s[4] for s in kept] == list(range(10, capacity + 10))
+    name, start_ns, end_ns, thread, key = kept[-1]
+    assert (name, end_ns - start_ns, key) == ('ring/fill', 1, capacity + 9)
+    assert thread == threading.current_thread().name
+    assert tracing.taken() - len(kept) >= 10  # the reader's wrap signal
+    # A capture that outlives the ring's reach reports what it lost.
+    with tracing.capture(max_events=capacity * 2) as events:
+      for i in range(capacity + 5):
+        tracing.record('ring/fill2', mark, mark + 1)
+    assert len(events) == capacity
+    assert tracing.chrome_trace()['metadata']['dropped_events'] == 5
+
+  def test_recent_since_and_clock_anchor(self):
+    t0 = time.perf_counter_ns()
+    tracing.record('since/old', t0 - 2_000, t0 - 1_000)
+    tracing.record('since/new', t0, t0 + 1_000, key='k')
+    names = [s[0] for s in tracing.recent(since_ns=t0)]
+    assert 'since/new' in names and 'since/old' not in names
+    assert tracing.recent()[-1] == (
+        'since/new', t0, t0 + 1_000, threading.current_thread().name, 'k')
+    # The anchor places a perf_counter time on the wall clock.
+    wall_ns, perf_ns = tracing.clock_anchor()
+    assert abs(wall_ns - time.time_ns()) < 1e9
+    assert 0 <= time.perf_counter_ns() - perf_ns < 1e9
+    wall2, perf2 = tracing.clock_anchor()
+    assert abs((wall2 - wall_ns) - (perf2 - perf_ns)) < 5e6  # one clock rate
+
+  def test_concurrent_appends_lose_nothing(self):
+    """8 threads, more than the cores, a shortened switch interval: every
+    span is counted and, the ring being large enough, kept."""
+    import sys
+
+    per_thread, n_threads = 2000, 8
+    before = tracing.taken()
+    start = threading.Barrier(n_threads)
+
+    def work(t):
+      start.wait(timeout=30)
+      for i in range(per_thread):
+        with tracing.span('stress/span', key=(t, i), annotate=False):
+          pass
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+      threads = [threading.Thread(target=work, args=(t,), name=f'stress-{t}')
+                 for t in range(n_threads)]
+      for thread in threads:
+        thread.start()
+      for thread in threads:
+        thread.join(timeout=120)
+        assert not thread.is_alive()
+    finally:
+      sys.setswitchinterval(interval)
+    assert tracing.taken() == before + per_thread * n_threads
+    mine = [s for s in tracing.recent() if s[0] == 'stress/span']
+    assert len(mine) == per_thread * n_threads
+    assert {s[4] for s in mine} == {
+        (t, i) for t in range(n_threads) for i in range(per_thread)}
+    for t in range(n_threads):  # each thread's spans under its own name
+      keys = [s[4][1] for s in mine if s[3] == f'stress-{t}']
+      assert keys == list(range(per_thread))
 
   def test_trace_summary_tool(self, tmp_path):
     from tools import trace_summary

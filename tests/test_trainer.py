@@ -6,6 +6,7 @@ fixture pattern of ``utils/t2r_test_fixture.py:37-128``.
 """
 
 import os
+import threading
 
 import jax
 import numpy as np
@@ -628,6 +629,106 @@ def test_steps_per_dispatch_handles_ragged_tail():
       steps_per_dispatch=3))
   trainer.train(iter([make_batch(8), make_batch(5)]), None)
   assert int(trainer.step) == 2
+
+
+@pytest.mark.parametrize('mode,k', [('staged', 1), ('consumer', 1),
+                                    ('inline', 1), ('staged', 2)])
+def test_spans_are_keyed_by_dispatch_and_tile_the_loop(monkeypatch, mode, k):
+  """Every stage's spans carry the batch ordinal, which is the dispatch
+  ordinal (under K > 1, the group's), on every placement path: the
+  dedicated place stage (forced on: it is TPU-only by default), the
+  consumer-thread placement behind one fetch thread, and no prefetch.
+  The four loop-thread spans leave no time between boundaries outside
+  them."""
+  import time
+
+  import tensor2robot_tpu.train.trainer as trainer_mod
+  from tensor2robot_tpu.observability import metrics, tracing
+
+  if mode == 'staged':
+    original = trainer_mod._DevicePrefetcher
+
+    class ForcedPlaceStage(original):
+
+      def __init__(self, it, place, depth, place_stage=None, **kwargs):
+        super().__init__(it, place, depth, place_stage=True, **kwargs)
+
+    monkeypatch.setattr(trainer_mod, '_DevicePrefetcher', ForcedPlaceStage)
+  steps = 12
+  dispatches = steps // k
+  model = MockT2RModel(device_type='cpu', create_optimizer_fn=fast_adam)
+  gen = MockInputGenerator(batch_size=8)
+  gen.set_specification_from_model(model, ModeKeys.TRAIN)
+  trainer = Trainer(model, TrainerConfig(
+      model_dir='', max_train_steps=steps, eval_interval_steps=0,
+      log_interval_steps=0, steps_per_dispatch=k,
+      prefetch_batches=0 if mode == 'inline' else 2))
+  bytes_before = metrics.counter('trainer/h2d/bytes').value
+  mark = time.perf_counter_ns()
+  trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
+  spans = [s for s in tracing.recent(since_ns=mark) if s[1] >= mark]
+
+  def keys(name):
+    return [s[4] for s in spans if s[0] == name]
+
+  def threads(name):
+    return {s[3] for s in spans if s[0] == name}
+
+  loop_thread = threading.current_thread().name
+  every = list(range(dispatches))
+  for name in ('trainer/wait_batch', 'trainer/dispatch',
+               'trainer/after_dispatch', 'trainer/callbacks'):
+    assert keys(name) == every, name
+    assert threads(name) == {loop_thread}, name
+  # The wait after enqueueing dispatch n is for the outputs of n-1.
+  assert keys('trainer/device_wait') == every[:-1]
+  # The feed runs ahead of the loop: the first N batches are the N
+  # dispatches, in order, and what it fetched beyond them is numbered on.
+  staged_names = ['trainer/place_stage', 'trainer/place/put']
+  if mode != 'inline':
+    staged_names += ['trainer/fetch', 'trainer/fetch_put']
+  for name in staged_names:
+    got = keys(name)
+    assert got[:dispatches] == every and got == list(range(len(got))), name
+  if mode == 'staged':
+    assert threads('trainer/fetch') == {'t2r-prefetch-fetch'}
+    assert threads('trainer/place_stage') == {'t2r-prefetch-place'}
+    # The place stage waits for each batch to be on the device.
+    assert keys('trainer/place/transfer')[:dispatches] == every
+  else:
+    assert threads('trainer/place_stage') == {loop_thread}
+    assert not keys('trainer/place/transfer')  # no lease, loop thread
+    if mode == 'consumer':
+      assert threads('trainer/fetch') == {'t2r-prefetch'}
+    else:
+      assert not keys('trainer/fetch')
+  # Children lie inside their parent, on its thread, under its key.
+  stages = {s[4]: s for s in spans if s[0] == 'trainer/place_stage'}
+  for s in spans:
+    if s[0] in ('trainer/place/put', 'trainer/place/transfer'):
+      parent = stages[s[4]]
+      assert parent[1] <= s[1] and s[2] <= parent[2] and s[3] == parent[3]
+  tails = {s[4]: s for s in spans if s[0] == 'trainer/after_dispatch'}
+  for s in spans:
+    if s[0] == 'trainer/callbacks':
+      assert tails[s[4]][1] <= s[1] and s[2] <= tails[s[4]][2]
+  # The four loop-thread spans tile the time between the first and the
+  # last boundary: each starts where the one before ended.
+  tiling = sorted((s for s in spans if s[0] in (
+      'trainer/after_dispatch', 'trainer/wait_batch', 'trainer/dispatch',
+      'trainer/device_wait')), key=lambda s: s[1:3])
+  first = next(s[2] for s in tiling if s[0] == 'trainer/dispatch')
+  last = max(s[2] for s in tiling if s[0] == 'trainer/device_wait')
+  inside = [s for s in tiling if s[1] >= first and s[2] <= last]
+  covered = sum(s[2] - s[1] for s in inside)
+  assert covered >= 0.99 * (last - first)
+  assert all(a[2] == b[1] for a, b in zip(inside, inside[1:]))
+  # Bytes handed to the put: every batch the loop consumed, at least.
+  batch_bytes = sum(
+      np.asarray(x).nbytes for x in jax.tree_util.tree_leaves(
+          next(gen.create_iterator(ModeKeys.TRAIN))))
+  sent = metrics.counter('trainer/h2d/bytes').value - bytes_before
+  assert sent >= steps * batch_bytes and sent % batch_bytes == 0
 
 
 def test_profiler_callback_window_at_k_dispatch(monkeypatch):
