@@ -21,6 +21,7 @@ through the callback protocol (the reference's HookBuilder surface).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import logging
 import os
@@ -58,6 +59,10 @@ Batch = Tuple[Any, Any]
 # What the train loop's place() emits and the prefetch queue carries:
 # (placed (features, labels), use_auto_layout_executable).
 PlacedBatch = Tuple[Batch, bool]
+# What place() returns (``_place_batch``): the batch as plain copies, and
+# the second half that re-lays those out (copies -> the batch as the step
+# takes it), or None where the copies are already that.
+Placement = Tuple[PlacedBatch, Optional[Callable[[PlacedBatch], PlacedBatch]]]
 MetricDict = Dict[str, float]
 
 
@@ -172,31 +177,50 @@ def _record_sigterm_to_resumed(model_dir: str, step: int) -> None:
     logging.warning('Cannot persist loop-restart measurement: %r', e)
 
 
-def _place_batch(place: Callable[[Batch], 'PlacedBatch'],
+def _place_batch(place: Callable[[Batch], 'Placement'],
                  release: Optional[Callable[[], None]],
                  batch: Batch, key: int,
                  wait_transfer: bool = False) -> 'PlacedBatch':
-  """Places batch ``key``, waits for its transfer where that is this
-  thread's to wait for, and returns its ring-buffer lease
+  """Places batch ``key`` in two halves, waits for the first where that
+  is this thread's to wait for, and returns its ring-buffer lease
   (data/engine.py) if it holds one.
+
+  ``place(batch)`` is the first half, the COPY: it returns the batch on
+  the device as plain host-to-device copies (no program on the device)
+  and, where leaves are still to be re-laid out into the layout the
+  executable asked for, ``relayout``: ``relayout(copied)`` is the second
+  half and returns the batch as the step takes it. ``None`` where there
+  is no second half (default layouts).
 
   The one placement routine of every path (placement stage, consumer
   thread, no prefetch), so each path leaves the same spans, keyed by
   the batch: ``trainer/place_stage`` round the whole of it and, inside,
   ``trainer/place/put`` round the ``place`` call (layout choice and the
   ``device_put`` / ``shard_batch`` call: host staging, returns before
-  the bytes have moved) and ``trainer/place/transfer`` round the block
-  on the placed leaves (transfer completion only, never compute).
+  the bytes have moved), ``trainer/place/transfer`` round the block on
+  the copies (transfer completion only, never compute: a copy is ready
+  when its bytes have arrived, whatever the device is running) and
+  ``trainer/place/relayout`` round the enqueue of the second half.
 
-  Who blocks: the placement stage always (``wait_transfer``: it runs
-  off the loop thread, and a queue depth >= 2 keeps a placed batch
-  ahead), so that the moment a batch is really on the device is on
-  record; any thread that holds a lease, because the release point
-  depends on what placement actually does with the host bytes:
+  Who blocks, and on what: on the COPIES only, never on the re-layout's
+  outputs. Those are programs on the device's compute queue, behind the
+  step that is running: a thread that waited for them would hand batch
+  n on only when step n-1 had ended, and the feed would run in series
+  with the step (PERF.md, PR 25 and 26). The placement stage always
+  blocks (``wait_transfer``: it runs off the loop thread, and a queue
+  depth >= 2 keeps a placed batch ahead), so that the moment a batch's
+  bytes are on the device is on record; any thread with a re-layout to
+  enqueue blocks, so that the program reads an array that is already
+  there and never sits in the queue waiting for its copy, holding back
+  the step enqueued after it; any thread that holds a lease blocks,
+  because the release point depends on what placement actually does
+  with the host bytes:
 
   * Accelerator backends: ``device_put`` COPIES to device memory, so
-    place, block on the placed leaves, then release. This is the
-    ROADMAP PR-3 follow-up's transfer-completion release point.
+    place, block on the copies, then release: the host bytes are not
+    read again (a re-layout that fails its check falls back to the
+    copies, not to the host batch). This is the ROADMAP PR-3
+    follow-up's transfer-completion release point.
   * XLA-CPU: ``device_put`` may ZERO-COPY alias the host numpy buffer —
     "transfer completion" never copies, and releasing would let the
     engine overwrite the live batch under the step (observed as
@@ -212,15 +236,18 @@ def _place_batch(place: Callable[[Batch], 'PlacedBatch'],
     release()
     release = None
   t_put = clock()
-  placed = place(batch)
+  placed, relayout = place(batch)
   t_placed = clock()
   tracing.record('trainer/place/put', t_put, t_placed, key)
-  if wait_transfer or release is not None:
+  if wait_transfer or release is not None or relayout is not None:
     jax.block_until_ready(placed[0])
-    t_ready = clock()
-    tracing.record('trainer/place/transfer', t_placed, t_ready, key)
+    tracing.record('trainer/place/transfer', t_placed, clock(), key)
   if release is not None:
     release()
+  if relayout is not None:
+    t_relayout = clock()
+    placed = relayout(placed)
+    tracing.record('trainer/place/relayout', t_relayout, clock(), key)
   tracing.record('trainer/place_stage', t_start, clock(), key)
   return placed
 
@@ -525,10 +552,16 @@ class _DevicePrefetcher:
   * Real TPU backends run a THREE-stage pipeline: a fetch worker pulls
     host batches from ``it`` (with the parallel input engine upstream
     this is mostly dequeueing — the engine's own workers do the decode),
-    and a DEDICATED placement worker applies ``place`` (the auto-layout
-    H2D shard_batch), so the decode of batch N+2, the placement of N+1
-    and the device step of N all overlap across batches instead of
-    serializing behind one thread.
+    and a DEDICATED placement worker applies ``place`` (the H2D copy of
+    ``shard_batch``, then the auto-layout re-layout), so the decode of
+    batch N+2, the placement of N+1 and the device step of N all overlap
+    across batches instead of serializing behind one thread. They do
+    overlap because the placement worker waits for the batch's COPY
+    alone (``_place_batch``): the re-layout programs run on the device's
+    compute queue behind the running step, so they are enqueued and not
+    waited for, and the batch is handed on while step N still runs. The
+    loop then finds batch N+1 waiting, enqueues step N+1 a whole step
+    early and blocks in ``trainer/device_wait``, as it was written to.
   * On the forced-host CPU platform placement happens on the consumer
     thread and a single fetch worker is the only stage — XLA CPU runs an
     N-device mesh's collectives as N in-process threads, and a
@@ -542,7 +575,7 @@ class _DevicePrefetcher:
   _DONE = object()
 
   def __init__(self, it: Iterator[Batch],
-               place: Callable[[Batch], 'PlacedBatch'], depth: int,
+               place: Callable[[Batch], 'Placement'], depth: int,
                place_stage: Optional[bool] = None,
                release: Optional[Callable[[], None]] = None):
     import queue
@@ -556,9 +589,9 @@ class _DevicePrefetcher:
     # once per batch AFTER its H2D transfer completes, so the engine may
     # recycle the host buffers the batch's arrays were views of. The
     # placement stage is the transfer-completion point this closes the
-    # ROADMAP PR-3 follow-up with: place() → block on the placed leaves
-    # → release() — all on the place/consumer thread, off the dispatch
-    # critical path.
+    # ROADMAP PR-3 follow-up with: place() → block on the copies →
+    # release() → enqueue the re-layout — all on the place/consumer
+    # thread, off the dispatch critical path.
     self._release = release
     # Queue telemetry: a depth gauge pinned near 0 plus a climbing
     # starvation counter is the registry's signature of an input-bound
@@ -1788,53 +1821,38 @@ class Trainer:
     # h2d_dispatches_per_step line).
     h2d_puts = metrics_lib.counter('trainer/h2d/device_puts')
     # Bytes handed to the put, on every placement path: over the time in
-    # ``trainer/place/transfer`` they give the H2D rate.
+    # ``trainer/place/transfer`` (which ends when the copy does) they
+    # give the H2D rate.
     h2d_bytes = metrics_lib.counter('trainer/h2d/bytes')
+    # Leaves handed to the second half of a placement, one a ``Format``
+    # in the target: 0 a batch on the default-layout path. A re-layout
+    # program each, unless the copy already has the format (jax then
+    # hands the copy back).
+    relayout_leaves = metrics_lib.counter('trainer/place/relayout_leaves')
 
     def put(batch: Batch, formats):
+      """The copy: every leaf with its plain sharding (a ``Format``'s
+      own), so that nothing here reaches the device's compute queue."""
       h2d_bytes.inc(sum(getattr(leaf, 'nbytes', 0)
                         for leaf in jax.tree_util.tree_leaves(batch)))
+      shardings = mesh_lib.copy_shardings(formats)  # None stays None
       if device_feed:
         # Device feed: the whole (features, labels) group moves in ONE
         # device_put call — one H2D burst per dispatch — instead of
-        # shard_batch's per-leaf puts. The target is the executable's
-        # preferred format tree when the auto build landed, else the
-        # loop sharding replicated over the batch's structure.
-        target = (formats if formats is not None else
-                  jax.tree_util.tree_map(lambda _: feed_sharding, batch))
+        # shard_batch's per-leaf puts. The target is the sharding tree
+        # of the executable's preferred formats when the auto build
+        # landed, else the loop sharding replicated over the batch's
+        # structure.
+        if shardings is None:
+          shardings = jax.tree_util.tree_map(lambda _: feed_sharding, batch)
         h2d_puts.inc()
-        return jax.device_put(batch, target)
+        return jax.device_put(batch, shardings)
       return mesh_lib.shard_batch(
-          batch, self._mesh, formats, stacked=self._loop_k > 1)
+          batch, self._mesh, shardings, stacked=self._loop_k > 1)
 
-    def place(batch: Batch):
-      # First placement builds the auto-layout executable from this
-      # batch's avals, so every batch (including this one) lands in the
-      # layout the step prefers — no re-layout copy inside the step.
-      # Off-shape batches (ragged tails) place default and the loop
-      # dispatches the jitted step for them. The auto decision travels
-      # WITH the placed batch: dispatching a default-layout batch into
-      # the layout-specialized executable would be a runtime error, so
-      # the choice is made exactly once, here.
-      t0 = time.perf_counter()
-      use_auto = (self._maybe_build_auto_step(batch[0], batch[1]) and
-                  self._batch_matches_auto(batch))
-      # ANALYSIS_OK(lock-discipline): use_auto=True implies the build
-      # lock published _batch_formats before _maybe_build_auto_step
-      # returned (happens-before via the lock release).
-      formats = self._batch_formats if use_auto else None
-      placed = put(batch, formats)
-      if formats is not None and not _placed_as_asked(placed, formats):
-        # jax handed back another layout than the one asked for:
-        # dispatching that into the layout-specialized executable is a
-        # runtime error. Give the executable up, loudly, and place this
-        # batch (and every later one) the default way.
-        with self._auto_build_lock:
-          self._give_up_auto_layouts(
-              'a placed batch came back in another layout than the '
-              'executable was compiled for')
-        use_auto = False
-        placed = put(batch, None)
+    def account(t0: float) -> None:
+      # Host time of one half of a placement, to whichever side of the
+      # breakdown the calling thread is on.
       place_ms = (time.perf_counter() - t0) * 1e3
       if threading.get_ident() == loop_ident:
         # Critical-path placement: carved out of host_wait in the
@@ -1845,7 +1863,54 @@ class Trainer:
         # Prefetch-worker placement overlaps the device step: real H2D
         # cost, but not on the dispatch critical path.
         overlap_place_hist.observe(place_ms)
+
+    def relayout(formats, leaves: int, copied: PlacedBatch) -> PlacedBatch:
+      # The second half, once the copies are on the device: the leaves
+      # with a Format go into the layout the executable was compiled
+      # for. Enqueued, not waited for (``_place_batch``).
+      t0 = time.perf_counter()
+      placed = mesh_lib.relayout_batch(copied[0], formats)
+      relayout_leaves.inc(leaves)
+      use_auto = _placed_as_asked(placed, formats)
+      if not use_auto:
+        # jax handed back another layout than the one asked for:
+        # dispatching that into the layout-specialized executable is a
+        # runtime error. Give the executable up, loudly; this batch goes
+        # on as its copies, which are the default placement (its host
+        # bytes may be gone: the lease is back), and every later one is
+        # placed the default way.
+        with self._auto_build_lock:
+          self._give_up_auto_layouts(
+              'a placed batch came back in another layout than the '
+              'executable was compiled for')
+        placed = copied[0]
+      account(t0)
       return placed, use_auto
+
+    def place(batch: Batch) -> Placement:
+      # First placement builds the auto-layout executable from this
+      # batch's avals, so every batch (including this one) lands in the
+      # layout the step prefers — no re-layout copy inside the step.
+      # Off-shape batches (ragged tails) place default and the loop
+      # dispatches the jitted step for them. The auto decision travels
+      # WITH the placed batch: dispatching a default-layout batch into
+      # the layout-specialized executable would be a runtime error, so
+      # the choice is made exactly once, here and in ``relayout``.
+      t0 = time.perf_counter()
+      use_auto = (self._maybe_build_auto_step(batch[0], batch[1]) and
+                  self._batch_matches_auto(batch))
+      # ANALYSIS_OK(lock-discipline): use_auto=True implies the build
+      # lock published _batch_formats before _maybe_build_auto_step
+      # returned (happens-before via the lock release).
+      formats = self._batch_formats if use_auto else None
+      copied = put(batch, formats)
+      account(t0)
+      leaves = sum(isinstance(f, Format)
+                   for f in jax.tree_util.tree_leaves(formats))
+      if not leaves:
+        # Default layouts throughout: the copies are the placed batch.
+        return (copied, use_auto), None
+      return (copied, False), functools.partial(relayout, formats, leaves)
 
     if first_batch is not None:
       train_iter = itertools.chain([first_batch], train_iter)
@@ -1858,7 +1923,7 @@ class Trainer:
       # accelerator device feed the superbatch buffers are themselves a
       # two-slot ring: the assembler leases a slot per group and the
       # placement stage frees it once the H2D burst completes
-      # (``_place_batch`` blocks on the placed arrays, then calls
+      # (``_place_batch`` blocks on the copies, then calls
       # ``assembler.release``) — the host half of the double-buffered
       # donated input ring. On CPU ``device_put`` aliases host memory
       # (zero copy), so reusing buffers would corrupt in-flight

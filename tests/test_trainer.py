@@ -188,7 +188,7 @@ def test_prefetch_depth1_close_terminates_worker():
   from tensor2robot_tpu.train.trainer import _DevicePrefetcher
 
   src = iter(itertools.count())
-  prefetcher = _DevicePrefetcher(src, lambda b: b, depth=1)
+  prefetcher = _DevicePrefetcher(src, lambda b: ((b, False), None), depth=1)
   next(iter(prefetcher))  # consume one so the worker is mid-stream
   prefetcher.close()
   for thread in prefetcher._threads:  # pylint: disable=protected-access
@@ -523,6 +523,82 @@ def test_auto_input_layouts_give_way_loudly(monkeypatch, caplog):
   np.testing.assert_allclose(loss_fallback, loss_auto, rtol=1e-5)
 
 
+@pytest.mark.parametrize('mode', ['inline', 'staged'])
+def test_relayout_in_another_layout_falls_back_to_the_copies(
+    request, monkeypatch, caplog, column_major_inputs, mode):
+  """The second half of a placement comes back in another layout than
+  the executable was compiled for (here: the copies themselves, row
+  major where column major was asked): the real ``_placed_as_asked``
+  sees it, the run gives the executable up with a WARNING and the gauge
+  at 0, and the batch goes on as its COPIES: the host batch is not put a
+  second time (its ring lease is already back), and training is what the
+  default path gives."""
+  from tensor2robot_tpu.observability import metrics as metrics_lib
+  from tensor2robot_tpu.parallel import mesh as mesh_lib
+
+  if mode == 'staged':
+    request.getfixturevalue('forced_place_stage')
+
+  def run(auto):
+    model = MockT2RModel(device_type='cpu', create_optimizer_fn=fast_adam)
+    gen = MockInputGenerator(batch_size=8)
+    gen.set_specification_from_model(model, ModeKeys.TRAIN)
+    trainer = Trainer(model, TrainerConfig(
+        model_dir='', max_train_steps=6, eval_interval_steps=0,
+        log_interval_steps=0, auto_input_layouts=auto,
+        prefetch_batches=0 if mode == 'inline' else 2))
+    before = metrics_lib.counter('trainer/h2d/bytes').value
+    trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
+    sent = metrics_lib.counter('trainer/h2d/bytes').value - before
+    return trainer, jax.device_get(trainer.state.params), sent
+
+  _, params_default, sent_default = run(False)
+  monkeypatch.setattr(mesh_lib, 'relayout_batch',
+                      lambda copied, formats: copied)
+  with caplog.at_level('WARNING'):
+    trainer, params_fallback, sent_fallback = run(True)
+  assert 'another layout than the executable was compiled for' in caplog.text
+  assert metrics_lib.gauge('trainer/auto_input_layouts').value == 0.0
+  assert trainer.step == 6
+  if mode == 'inline':  # the staged feed runs ahead by a varying count
+    assert sent_fallback == sent_default
+  for a, b in zip(jax.tree_util.tree_leaves(params_default),
+                  jax.tree_util.tree_leaves(params_fallback)):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_shard_batch_places_a_format_in_two_halves():
+  """``shard_batch`` with a ``Format``: the copy goes with the format's
+  own sharding and the default layout (``copy_shardings``), the
+  re-layout then runs on the device array (``relayout_batch``) and
+  touches only the leaves that have a ``Format``."""
+  from jax.experimental.layout import Format, Layout
+
+  from tensor2robot_tpu.parallel import mesh as mesh_lib
+
+  mesh = parallel.create_mesh(data=-1)
+  sharding = mesh_lib.batch_sharding(mesh)
+  column = Format(Layout(major_to_minor=(1, 0), tiling=()), sharding)
+  batch = {'x': np.arange(32, dtype=np.float32).reshape(16, 2),
+           'y': np.arange(16, dtype=np.float32)}
+  formats = {'x': column, 'y': sharding}
+  assert mesh_lib.copy_shardings(formats) == {'x': sharding, 'y': sharding}
+
+  copied = mesh_lib.shard_batch(batch, mesh, mesh_lib.copy_shardings(formats))
+  assert copied['x'].format.layout != column.layout  # a plain copy
+  relaid = mesh_lib.relayout_batch(copied, formats)
+  assert relaid['y'] is copied['y']
+  assert relaid['x'].format == column
+  assert mesh_lib.relayout_batch(relaid, formats)['x'] is relaid['x']
+
+  placed = mesh_lib.shard_batch(batch, mesh, formats)
+  assert placed['x'].format == column
+  assert placed['y'].sharding == sharding
+  for name, value in batch.items():
+    np.testing.assert_array_equal(np.asarray(placed[name]), value)
+    np.testing.assert_array_equal(np.asarray(relaid[name]), value)
+
+
 def test_steps_per_dispatch_matches_single_step_path():
   """K steps folded into one lax.scan dispatch train IDENTICALLY to K
   single dispatches (same batches, same per-step rng fold_in keyed off
@@ -631,29 +707,29 @@ def test_steps_per_dispatch_handles_ragged_tail():
   assert int(trainer.step) == 2
 
 
-@pytest.mark.parametrize('mode,k', [('staged', 1), ('consumer', 1),
-                                    ('inline', 1), ('staged', 2)])
-def test_spans_are_keyed_by_dispatch_and_tile_the_loop(monkeypatch, mode, k):
+@pytest.mark.parametrize('mode,k,layouts', [
+    ('staged', 1, 'default'), ('consumer', 1, 'default'),
+    ('inline', 1, 'default'), ('staged', 2, 'default'),
+    ('staged', 1, 'column'), ('consumer', 1, 'column'),
+    ('inline', 1, 'column')])
+def test_spans_are_keyed_by_dispatch_and_tile_the_loop(request, mode, k,
+                                                       layouts):
   """Every stage's spans carry the batch ordinal, which is the dispatch
   ordinal (under K > 1, the group's), on every placement path: the
   dedicated place stage (forced on: it is TPU-only by default), the
   consumer-thread placement behind one fetch thread, and no prefetch.
   The four loop-thread spans leave no time between boundaries outside
-  them."""
+  them. With a layout to re-lay into (``column``), every path records
+  the second half of the placement under the batch's key, inside its
+  stage, and counts its leaf; with default layouts there is none."""
   import time
 
-  import tensor2robot_tpu.train.trainer as trainer_mod
   from tensor2robot_tpu.observability import metrics, tracing
 
   if mode == 'staged':
-    original = trainer_mod._DevicePrefetcher
-
-    class ForcedPlaceStage(original):
-
-      def __init__(self, it, place, depth, place_stage=None, **kwargs):
-        super().__init__(it, place, depth, place_stage=True, **kwargs)
-
-    monkeypatch.setattr(trainer_mod, '_DevicePrefetcher', ForcedPlaceStage)
+    request.getfixturevalue('forced_place_stage')
+  if layouts == 'column':
+    request.getfixturevalue('column_major_inputs')
   steps = 12
   dispatches = steps // k
   model = MockT2RModel(device_type='cpu', create_optimizer_fn=fast_adam)
@@ -662,8 +738,10 @@ def test_spans_are_keyed_by_dispatch_and_tile_the_loop(monkeypatch, mode, k):
   trainer = Trainer(model, TrainerConfig(
       model_dir='', max_train_steps=steps, eval_interval_steps=0,
       log_interval_steps=0, steps_per_dispatch=k,
+      auto_input_layouts=layouts == 'column',
       prefetch_batches=0 if mode == 'inline' else 2))
   bytes_before = metrics.counter('trainer/h2d/bytes').value
+  relaid_before = metrics.counter('trainer/place/relayout_leaves').value
   mark = time.perf_counter_ns()
   trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
   spans = [s for s in tracing.recent(since_ns=mark) if s[1] >= mark]
@@ -697,17 +775,37 @@ def test_spans_are_keyed_by_dispatch_and_tile_the_loop(monkeypatch, mode, k):
     assert keys('trainer/place/transfer')[:dispatches] == every
   else:
     assert threads('trainer/place_stage') == {loop_thread}
-    assert not keys('trainer/place/transfer')  # no lease, loop thread
+    # No lease, loop thread: it waits for a copy only to re-lay it out.
+    assert keys('trainer/place/transfer') == keys('trainer/place/relayout')
     if mode == 'consumer':
       assert threads('trainer/fetch') == {'t2r-prefetch'}
     else:
       assert not keys('trainer/fetch')
-  # Children lie inside their parent, on its thread, under its key.
+  # The second half: once a batch where there is a layout to re-lay
+  # into (one leaf a batch), after the put and the wait for the copy;
+  # never on the default-layout path.
+  relaid = metrics.counter('trainer/place/relayout_leaves').value
+  if layouts == 'column':
+    assert keys('trainer/place/relayout') == keys('trainer/place_stage')
+    assert relaid - relaid_before == len(keys('trainer/place_stage'))
+    assert metrics.gauge('trainer/auto_input_layouts').value == 1.0
+  else:
+    assert not keys('trainer/place/relayout')
+    assert relaid == relaid_before
+  # Children lie inside their parent, on its thread, under its key, in
+  # the order put, transfer, relayout.
   stages = {s[4]: s for s in spans if s[0] == 'trainer/place_stage'}
+  order = ['trainer/place/put', 'trainer/place/transfer',
+           'trainer/place/relayout']
+  children = {}
   for s in spans:
-    if s[0] in ('trainer/place/put', 'trainer/place/transfer'):
+    if s[0] in order:
       parent = stages[s[4]]
       assert parent[1] <= s[1] and s[2] <= parent[2] and s[3] == parent[3]
+      children.setdefault(s[4], []).append(s)
+  for parts in children.values():
+    parts.sort(key=lambda s: order.index(s[0]))
+    assert all(a[2] <= b[1] for a, b in zip(parts, parts[1:]))
   tails = {s[4]: s for s in spans if s[0] == 'trainer/after_dispatch'}
   for s in spans:
     if s[0] == 'trainer/callbacks':
@@ -988,7 +1086,7 @@ def test_prefetcher_delivers_worker_error_promptly():
     raise IOError('pipeline died')
 
   prefetcher = _DevicePrefetcher(
-      source(), place=lambda b: (b, False), depth=4)
+      source(), place=lambda b: ((b, False), None), depth=4)
   for thread in prefetcher._threads:  # pylint: disable=protected-access
     thread.join(timeout=5)
     assert not thread.is_alive()
