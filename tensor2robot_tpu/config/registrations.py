@@ -104,6 +104,8 @@ def register() -> None:
   from tensor2robot_tpu.research import grasp2vec as grasp2vec_lib
   from tensor2robot_tpu.research import pose_env as pose_env_lib
   from tensor2robot_tpu.research import qtopt as qtopt_lib
+  from tensor2robot_tpu.research.token_policy import (
+      afmoe_model as token_policy_lib)
   from tensor2robot_tpu.research import vrgripper as vrgripper_lib
 
   reg(maml_model_lib.MAMLModel, 'MAMLModel')
@@ -120,6 +122,7 @@ def register() -> None:
   reg(qtopt_lib.Grasping44E2EOpenCloseTerminateGripperStatusHeightToBottom,
       'Grasping44E2EOpenCloseTerminateGripperStatusHeightToBottom')
   reg(grasp2vec_lib.Grasp2VecModel, 'Grasp2VecModel')
+  reg(token_policy_lib.AfmoeTokenPolicyModel, 'AfmoeTokenPolicyModel')
   reg(vrgripper_lib.VRGripperRegressionModel, 'VRGripperRegressionModel')
   reg(vrgripper_lib.VRGripperDomainAdaptiveModel,
       'VRGripperDomainAdaptiveModel')

@@ -1,0 +1,229 @@
+"""The afmoe decoder trunk (arcee-ai Trinity family): window and full
+attention mixed, grouped key/value heads, gated attention output, sparse
+experts after the leading dense layers.
+
+One layer, four RMS norms in sandwich order::
+
+    a  = h + N2(Attn(N1(h)))
+    h' = a + N4(FFN(N3(a)))
+
+Attn: q, k, v and a gate as wide as q, no biases; RMS norm over the head
+dimension of q and of k; on ``sliding_attention`` layers RoPE over the
+whole head and the mask ``0 <= i - j < sliding_window``, on
+``full_attention`` layers no positional embedding and the causal mask;
+``out = Wo (o * sigmoid(g))``. Attention runs in
+``ops/flash_attention.py`` (window and grouped heads there): at 8,192
+tokens x 32 heads plain logits are 8.6 GB, so there is no other path.
+FFN: SwiGLU on the leading dense layers, ``layers/moe.ExpertLayer`` on
+the others.
+
+Parameters are float32 and flat under each module (``q``, ``gate``,
+``norm1`` ...: the names of ``benchmark/reference/trinity_mini.py``);
+products take operands in ``dtype``, norms, the router, softmax and the
+loss are float32. The trunk returns the mean next-token loss itself, the
+head and the log-softmax computed a chunk of positions at a time: the
+[tokens, vocabulary] logits never exist whole.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional, Sequence
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from tensor2robot_tpu.layers import moe
+from tensor2robot_tpu.ops import flash_attention as fa
+
+SLIDING = 'sliding_attention'
+FULL = 'full_attention'
+
+
+def rms_norm(x, scale, eps: float, dtype):
+  x = x.astype(jnp.float32)
+  y = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True) + eps)
+  return (y * scale).astype(dtype)
+
+
+def rope(x, theta: float):
+  """Rotary embedding over the whole head (halves rotated against each
+  other) of [B, S, heads, head_dim], in float32."""
+  s, hd = x.shape[1], x.shape[-1]
+  half = hd // 2
+  freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+  angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None, :]
+  cos, sin = jnp.cos(angle)[None, :, None, :], jnp.sin(angle)[None, :, None, :]
+  x = x.astype(jnp.float32)
+  a, b = x[..., :half], x[..., half:]
+  return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+class Attention(nn.Module):
+  num_heads: int
+  num_kv_heads: int
+  head_dim: int
+  kind: str                      # SLIDING | FULL
+  sliding_window: int
+  rope_theta: float
+  eps: float
+  dtype: Any = jnp.float32
+  init_std: float = 0.02
+
+  @nn.compact
+  def __call__(self, x):
+    b, s, d = x.shape
+    init = moe.normal_init(self.init_std)
+    q_width = self.num_heads * self.head_dim
+    kv_width = self.num_kv_heads * self.head_dim
+    wq = self.param('q', init, (d, q_width))
+    wk = self.param('k', init, (d, kv_width))
+    wv = self.param('v', init, (d, kv_width))
+    wg = self.param('gate', init, (d, q_width))
+    wo = self.param('o', init, (q_width, d))
+    q_scale = self.param('q_norm', nn.initializers.ones, (self.head_dim,))
+    k_scale = self.param('k_norm', nn.initializers.ones, (self.head_dim,))
+    dt = self.dtype
+    x = x.astype(dt)
+    q = (x @ wq.astype(dt)).reshape(b, s, self.num_heads, self.head_dim)
+    k = (x @ wk.astype(dt)).reshape(b, s, self.num_kv_heads, self.head_dim)
+    v = (x @ wv.astype(dt)).reshape(b, s, self.num_kv_heads, self.head_dim)
+    gate = x @ wg.astype(dt)
+    q = rms_norm(q, q_scale, self.eps, dt)
+    k = rms_norm(k, k_scale, self.eps, dt)
+    window = None
+    if self.kind == SLIDING:
+      q, k = rope(q, self.rope_theta).astype(dt), rope(
+          k, self.rope_theta).astype(dt)
+      window = self.sliding_window
+    scope = 'afmoe/attn/window' if window else 'afmoe/attn/full'
+    with jax.named_scope(scope):
+      o = fa.flash_attention(q, k, v, True, None, None, window)
+    o = o.reshape(b, s, q_width) * jax.nn.sigmoid(
+        gate.astype(jnp.float32)).astype(dt)
+    return o @ wo.astype(dt)
+
+
+class DecoderLayer(nn.Module):
+  """One layer; returns (hidden, the expert layer's counts or None)."""
+
+  kind: str
+  sparse: bool
+  num_heads: int
+  num_kv_heads: int
+  head_dim: int
+  sliding_window: int
+  rope_theta: float
+  eps: float
+  dense_width: int
+  expert_kwargs: Optional[Dict[str, Any]] = None
+  dtype: Any = jnp.float32
+  init_std: float = 0.02
+
+  @nn.compact
+  def __call__(self, h, train: bool = False):
+    d = h.shape[-1]
+    norms = [self.param(f'norm{i}', nn.initializers.ones, (d,))
+             for i in (1, 2, 3, 4)]
+    dt = self.dtype
+    a = Attention(self.num_heads, self.num_kv_heads, self.head_dim,
+                  self.kind, self.sliding_window, self.rope_theta, self.eps,
+                  dt, self.init_std, name='attn')(
+                      rms_norm(h, norms[0], self.eps, dt))
+    a = h + rms_norm(a, norms[1], self.eps, dt)
+    x = rms_norm(a, norms[2], self.eps, dt)
+    stats = None
+    if self.sparse:
+      y, stats = moe.ExpertLayer(dtype=dt, init_std=self.init_std,
+                                 name='moe', **self.expert_kwargs)(x, train)
+    else:
+      y = moe.SwiGLU(self.dense_width, dt, self.init_std, name='mlp')(x)
+    return a + rms_norm(y, norms[3], self.eps, dt), stats
+
+
+class Trunk(nn.Module):
+  """Embedding, the layers, the final norm, the untied head and the
+  next-token loss over ``tokens`` ([B, S] integers)."""
+
+  vocab_size: int
+  hidden_size: int
+  layer_types: Sequence[str]        # of the layers run, in order
+  num_dense_layers: int
+  num_heads: int
+  num_kv_heads: int
+  head_dim: int
+  sliding_window: int
+  rope_theta: float
+  eps: float
+  dense_width: int
+  expert_kwargs: Dict[str, Any]
+  mup_enabled: bool = True
+  loss_chunk: int = 2048
+  dtype: Any = jnp.float32
+  init_std: float = 0.02
+
+  @nn.compact
+  def __call__(self, features, train: bool = False):
+    tokens = features['tokens'].astype(jnp.int32)
+    b, s = tokens.shape
+    init = moe.normal_init(self.init_std)
+    embed = self.param('embed', init, (self.vocab_size, self.hidden_size))
+    h = embed[tokens]
+    if self.mup_enabled:
+      h = h * self.hidden_size ** 0.5
+    h = h.astype(self.dtype)
+    # One layer's activations at a time: the backward pass recomputes a
+    # layer from its input (16 bytes a parameter leave room for no more).
+    layer_cls = nn.remat(DecoderLayer, static_argnums=(2,))
+    all_stats = []
+    for j, kind in enumerate(self.layer_types):
+      sparse = j >= self.num_dense_layers
+      h, stats = layer_cls(
+          kind, sparse, self.num_heads, self.num_kv_heads, self.head_dim,
+          self.sliding_window, self.rope_theta, self.eps, self.dense_width,
+          self.expert_kwargs if sparse else None, self.dtype, self.init_std,
+          name=f'layer{j}')(h, train)
+      if stats is not None:
+        all_stats.append(stats)
+    scale = self.param('final_norm', nn.initializers.ones,
+                       (self.hidden_size,))
+    head = self.param('head', init, (self.hidden_size, self.vocab_size))
+    h = rms_norm(h, scale, self.eps, self.dtype)
+    with jax.named_scope('afmoe/head_loss'):
+      loss = next_token_loss(h, head, tokens, self.loss_chunk, self.dtype)
+      last_logits = jnp.matmul(h[:, -1], head.astype(self.dtype),
+                               preferred_element_type=jnp.float32)
+    outputs = {'loss': loss, 'next_token_logits': last_logits}
+    if all_stats:
+      for key in all_stats[0]:
+        column = jnp.stack([st[key] for st in all_stats])
+        outputs[f'moe/{key}'] = (jnp.max(column) if key == 'rows_max_expert'
+                                 else jnp.sum(column))
+    return outputs
+
+
+def next_token_loss(h, head, tokens, chunk: int, dtype):
+  """Mean cross-entropy of position i's prediction of token i+1 over
+  each sequence's first S-1 positions, a ``chunk`` of positions at a
+  time; log-softmax in float32."""
+  b, s, d = h.shape
+  labels = jnp.roll(tokens, -1, axis=1).reshape(b * s)
+  counted = jnp.broadcast_to(jnp.arange(s) < s - 1, (b, s)).reshape(b * s)
+  rows = b * s
+  chunk = min(chunk, rows)
+  if rows % chunk:
+    raise ValueError(f'{rows} positions do not divide into chunks of {chunk}')
+  weight = head.astype(dtype)
+
+  @jax.checkpoint
+  def one_chunk(args):
+    hc, lc, mc = args
+    logits = jnp.matmul(hc, weight, preferred_element_type=jnp.float32)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    picked = jnp.take_along_axis(logp, lc[:, None], axis=-1)[:, 0]
+    return -jnp.sum(jnp.where(mc, picked, 0.0))
+
+  parts = jax.lax.map(one_chunk, (
+      h.reshape(rows // chunk, chunk, d), labels.reshape(rows // chunk, chunk),
+      counted.reshape(rows // chunk, chunk)))
+  return jnp.sum(parts) / (b * (s - 1))

@@ -299,6 +299,16 @@ class AbstractT2RModel(ModelInterface):
     del features
     return inference_outputs
 
+  # ---------------------------------------------------------------- counts
+
+  @property
+  def counter_scalars(self) -> Sequence[str]:
+    """Names of ``model_train_fn`` scalars that count what one step did
+    (tokens routed, rows computed). The trainer adds each to the
+    ``observability.metrics`` counter of the same name at the dispatch
+    boundary, one dispatch behind, so reading them adds no wait."""
+    return ()
+
   # ------------------------------------------------------------- optimizer
 
   def create_optimizer(self):

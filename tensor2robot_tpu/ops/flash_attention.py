@@ -48,17 +48,39 @@ def _use_interpret() -> bool:
   # everywhere Mosaic can't lower, not just cpu.
   return dispatch.use_interpret()
 
-def _block_live(q0, bq, k0):
-  """Causal block-liveness: a key block starting at ``k0`` contributes to
-  a query block [q0, q0+bq) iff its first key is not past the last query
-  (the companion of _scores' per-element mask)."""
-  return q0 + bq - 1 >= k0
+def _block_live(q0, bq, k0, bk=None, window=None):
+  """Causal block-liveness: a key block [k0, k0+bk) contributes to a
+  query block [q0, q0+bq) iff its first key is not past the last query
+  and, under a ``window``, its last key is not behind the first query's
+  window (the companion of _scores' per-element mask)."""
+  live = q0 + bq - 1 >= k0
+  if window is not None:
+    live = jnp.logical_and(live, k0 + bk - 1 > q0 - window)
+  return live
 
 
-def _scores(q, k, q0, k0, causal, scale=None):
+def _live_key_blocks(qb, bq, bk, nk, window):
+  """[first, last] key blocks that :func:`_block_live` admits for query
+  block ``qb`` of a causal problem."""
+  last = jnp.minimum((qb * bq + bq - 1) // bk, nk - 1)
+  if window is None:
+    return 0, last
+  return jnp.maximum(qb * bq - window + 1, 0) // bk, last
+
+
+def _live_query_blocks(kb, bq, bk, nq, window):
+  """[first, last] query blocks that see key block ``kb``."""
+  first = (kb * bk) // bq
+  if window is None:
+    return first, nq - 1
+  return first, jnp.minimum((kb * bk + bk + window - 2) // bq, nq - 1)
+
+
+def _scores(q, k, q0, k0, causal, scale=None, window=None):
   """Scaled (optional) masked q·kᵀ block scores; (q0, k0) are the global
-  offsets of the blocks — THE shared definition of the causal mask and
-  score math for every kernel variant (staged and streamed)."""
+  offsets of the blocks — THE shared definition of the mask (causal:
+  ``i >= j``; with a ``window`` also ``i - j < window``) and score math
+  for every kernel variant (staged and streamed)."""
   s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                           preferred_element_type=jnp.float32)
   if scale is not None:
@@ -67,7 +89,10 @@ def _scores(q, k, q0, k0, causal, scale=None):
     bq, bk = s.shape
     qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    s = jnp.where(qpos >= kpos, s, _NEG_INF)
+    seen = qpos >= kpos
+    if window is not None:
+      seen = jnp.logical_and(seen, qpos - kpos < window)
+    s = jnp.where(seen, s, _NEG_INF)
   return s
 
 
@@ -81,7 +106,8 @@ def _online_softmax_step(s, m, l, acc, v):
   corr = jnp.exp(m - m_sub)
   l = l * corr + jnp.sum(p, axis=1, keepdims=True)
   acc = acc * corr + jax.lax.dot_general(
-      p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+      p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+      preferred_element_type=jnp.float32)
   return m_new, l, acc
 
 
@@ -136,7 +162,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, bk, causal, scale):
 
 
 def _fwd_kernel_streamed(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                         acc_scr, *, causal, scale, nk):
+                         acc_scr, *, causal, scale, nk, window):
   qb, kb = pl.program_id(1), pl.program_id(2)
   bq, d = q_ref.shape[1], q_ref.shape[2]
   bk = k_ref.shape[1]
@@ -147,17 +173,20 @@ def _fwd_kernel_streamed(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
-  # Causal: key blocks strictly above the diagonal contribute nothing.
-  live = _block_live(qb * bq, bq, kb * bk) if causal else True
+  # Causal: key blocks strictly above the diagonal (and, windowed, wholly
+  # behind the window) contribute nothing.
+  live = _block_live(qb * bq, bq, kb * bk, bk, window) if causal else True
 
   @pl.when(live)
   def _():
-    q = q_ref[0].astype(jnp.float32) * scale
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    s = _scores(q, k, qb * bq, kb * bk, causal)
+    # Products take their operands as the caller holds them (bfloat16
+    # stays bfloat16 for the MXU); every sum is float32.
+    dt = q_ref.dtype
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(dt)
+    s = _scores(q, k_ref[0].astype(dt), qb * bq, kb * bk, causal,
+                window=window)
     m_new, l_new, acc_new = _online_softmax_step(
-        s, m_scr[...], l_scr[...], acc_scr[...], v)
+        s, m_scr[...], l_scr[...], acc_scr[...], v_ref[0].astype(dt))
     m_scr[...] = m_new
     l_scr[...] = l_new
     acc_scr[...] = acc_new
@@ -170,7 +199,7 @@ def _fwd_kernel_streamed(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 
 def _dq_kernel_streamed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                        dq_ref, dq_scr, *, causal, scale, nk):
+                        dq_ref, dq_scr, *, causal, scale, nk, window):
   qb, kb = pl.program_id(1), pl.program_id(2)
   bq, d = q_ref.shape[1], q_ref.shape[2]
   bk = k_ref.shape[1]
@@ -179,20 +208,19 @@ def _dq_kernel_streamed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
   def _():
     dq_scr[...] = jnp.zeros_like(dq_scr)
 
-  live = _block_live(qb * bq, bq, kb * bk) if causal else True
+  live = _block_live(qb * bq, bq, kb * bk, bk, window) if causal else True
 
   @pl.when(live)
   def _():
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    q = q_ref[0]
+    k, v, do = (r[0].astype(q.dtype) for r in (k_ref, v_ref, do_ref))
     lse = lse_ref[0, 0][:, None]
     delta = delta_ref[0, 0][:, None]
-    s = _scores(q, k, qb * bq, kb * bk, causal, scale)
+    s = _scores(q, k, qb * bq, kb * bk, causal, scale, window)
     _, ds = _ds_block(s, lse, do, v, delta)
     dq_scr[...] = dq_scr[...] + jax.lax.dot_general(
-        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        ds.astype(q.dtype), k, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
   @pl.when(kb == nk - 1)
   def _():
@@ -201,34 +229,37 @@ def _dq_kernel_streamed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _dkv_kernel_streamed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dk_ref, dv_ref, dk_scr, dv_scr, *, causal, scale,
-                         nq):
-  kb, qb = pl.program_id(1), pl.program_id(2)
+                         nq, group, window):
+  # The innermost grid dimension walks the ``group`` query heads that
+  # share this key/value head, ``nq`` query blocks each.
+  kb, r = pl.program_id(1), pl.program_id(2)
+  qb = r % nq
   bk, d = k_ref.shape[1], k_ref.shape[2]
   bq = q_ref.shape[1]
 
-  @pl.when(qb == 0)
+  @pl.when(r == 0)
   def _():
     dk_scr[...] = jnp.zeros_like(dk_scr)
     dv_scr[...] = jnp.zeros_like(dv_scr)
 
-  live = _block_live(qb * bq, bq, kb * bk) if causal else True
+  live = _block_live(qb * bq, bq, kb * bk, bk, window) if causal else True
 
   @pl.when(live)
   def _():
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
+    q = q_ref[0]
+    k, v, do = (r[0].astype(q.dtype) for r in (k_ref, v_ref, do_ref))
     lse = lse_ref[0, 0][:, None]
     delta = delta_ref[0, 0][:, None]
-    s = _scores(q, k, qb * bq, kb * bk, causal, scale)
+    s = _scores(q, k, qb * bq, kb * bk, causal, scale, window)
     p, ds = _ds_block(s, lse, do, v, delta)
     dv_scr[...] = dv_scr[...] + jax.lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        p.astype(q.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
     dk_scr[...] = dk_scr[...] + jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
 
-  @pl.when(qb == nq - 1)
+  @pl.when(r == group * nq - 1)
   def _():
     dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
@@ -328,21 +359,31 @@ def _use_streamed(t: int, d: int, itemsize: int = 2) -> bool:
 
 
 # Streamed-regime default tile: much larger than the staged default.
-# Measured r4 at [1, 65536, 8, 64] bf16 causal fwd: 256/512 → 187.6 ms,
-# 512/512 → 146.0, 512/1024 → 91.3, 1024/1024 → 75.5 ms (2.5×);
-# 2048/2048 fails Mosaic compile (VMEM). The staged kernels keep the
-# smaller q blocks so whole-KV staging + accumulators fit VMEM.
-_STREAMED_BLOCK = 1024
+# Measured r4 at [1, 65536, 8, 64] bf16 causal fwd (float32 products):
+# 256/512 → 187.6 ms, 512/512 → 146.0, 512/1024 → 91.3, 1024/1024 →
+# 75.5 ms (2.5×); 2048/2048 fails Mosaic compile (VMEM). The staged
+# kernels keep the smaller q blocks so whole-KV staging + accumulators
+# fit VMEM.
+_STREAMED_BLOCKS = (1024, 512, 256, 128, 64, 32, 16, 8)
+
+
+def _streams(t: int, d: int, itemsize: int, group: int = 1,
+             window: Optional[int] = None) -> bool:
+  """Grouped heads and a window exist in the streamed kernels only."""
+  return _use_streamed(t, d, itemsize) or group > 1 or window is not None
 
 
 def _resolve_blocks(t: int, d: int, block_q: Optional[int],
-                    block_k: Optional[int],
-                    itemsize: int = 2) -> Tuple[int, int]:
+                    block_k: Optional[int], itemsize: int = 2,
+                    group: int = 1,
+                    window: Optional[int] = None) -> Tuple[int, int]:
   """Regime-dependent block defaults (None → auto)."""
   if block_q is None or block_k is None:
-    if _use_streamed(t, d, itemsize):
-      best = next((blk for blk in (_STREAMED_BLOCK, 512, 256, 128, 8)
-                   if t % blk == 0), DEFAULT_BLOCK_Q)
+    if _streams(t, d, itemsize, group, window):
+      best = next((blk for blk in _STREAMED_BLOCKS if t % blk == 0),
+                  DEFAULT_BLOCK_Q)
+      while window is not None and best > max(window, 8):
+        best //= 2  # a block wider than the window is mostly masked
       block_q = block_q if block_q is not None else best
       block_k = block_k if block_k is not None else best
     else:
@@ -402,32 +443,56 @@ def _check(q, block_q, block_k):
   return bq, bk
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None):
+                    block_k: Optional[int] = None,
+                    window: Optional[int] = None):
   """[B, T, H, D] attention, O(T·D) memory. Same contract as
   ``sequence_parallel.reference_attention``. ``block_q``/``block_k``
   default per regime: staged 256/512; streamed 1024/1024 (see
-  ``_resolve_blocks``)."""
-  out, _ = _flash_fwd(q, k, v, causal, block_q, block_k)
+  ``_resolve_blocks``).
+
+  ``k`` and ``v`` may carry fewer heads than ``q`` ([B, T, Hkv, D], H a
+  multiple of Hkv): query head ``h`` attends to key/value head
+  ``h // (H // Hkv)``. ``window`` (causal only) keeps keys with
+  ``0 <= i - j < window``. Either one takes the streamed kernels, whose
+  key/value index maps skip the blocks the mask kills and whose matrix
+  products take their operands in ``q``'s own dtype (sums are float32
+  always; the staged kernels compute in float32 throughout)."""
+  out, _ = _flash_fwd(q, k, v, causal, block_q, block_k, window)
   return out
 
 
-def _flash_call(q, k, v, causal, bq, bk):
+def _plan(t, d, itemsize, group, block_q, block_k, window):
+  """(bq, bk, streamed?) of a problem, the same on the way back."""
+  block_q, block_k = _resolve_blocks(t, d, block_q, block_k, itemsize,
+                                     group, window)
+  return (min(block_q, t), min(block_k, t),
+          _streams(t, d, itemsize, group, window))
+
+
+def _flash_call(q, k, v, causal, bq, bk, group, streamed, window):
   bh, t, d = q.shape
   scale = 1.0 / np.sqrt(d)
-  if _use_streamed(t, d, q.dtype.itemsize):
+  if streamed:
     nk = t // bk
+
+    def kv_map(i, j, g):
+      if causal:  # a dead block re-reads a live one: no copy is made
+        first, last = _live_key_blocks(j, bq, bk, nk, window)
+        g = jnp.clip(g, first, last)
+      return (i // group, g, 0)
+
     kern = functools.partial(_fwd_kernel_streamed, causal=causal,
-                             scale=scale, nk=nk)
+                             scale=scale, nk=nk, window=window)
     return pl.pallas_call(
         kern,
         grid=(bh, t // bq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, g: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, g: (i, g, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, g: (i, g, 0)),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, g: (i, j, 0)),
@@ -443,6 +508,7 @@ def _flash_call(q, k, v, causal, bq, bk):
             pltpu.VMEM((bq, d), jnp.float32),
         ],
         interpret=_use_interpret(),
+        name='flash_attention_fwd',
     )(q, k, v)
   kern = functools.partial(_fwd_kernel, bk=bk, causal=causal, scale=scale)
   return pl.pallas_call(
@@ -465,36 +531,54 @@ def _flash_call(q, k, v, causal, bq, bk):
   )(q, k, v)
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k):
+def _flash_fwd(q, k, v, causal, block_q, block_k, window=None):
   b, t, h, d = q.shape
-  bq, bk = _check(q, block_q, block_k)
+  hkv = k.shape[2]
+  if h % hkv:
+    raise ValueError(f'{h} query heads do not divide over {hkv} key/value '
+                     'heads')
+  if window is not None and not causal:
+    raise ValueError('a window is causal: pass causal=True')
+  group = h // hkv
+  bq, bk, streamed = _plan(t, d, q.dtype.itemsize, group, block_q, block_k,
+                           window)
+  _check(q, bq, bk)
   qr, kr, vr = _fold_heads(q), _fold_heads(k), _fold_heads(v)
-  out, lse = _flash_call(qr, kr, vr, causal, bq, bk)
+  out, lse = _flash_call(qr, kr, vr, causal, bq, bk, group, streamed,
+                         window)
   return _unfold_heads(out, b, h), (qr, kr, vr, out, lse, (b, t, h, d))
 
 
-def _flash_bwd(causal, block_q, block_k, res, g):
+def _flash_bwd(causal, block_q, block_k, window, res, g):
   qr, kr, vr, out, lse, (b, t, h, d) = res
-  block_q, block_k = _resolve_blocks(t, d, block_q, block_k,
-                                     qr.dtype.itemsize)
-  bq, bk = min(block_q, t), min(block_k, t)
+  group = qr.shape[0] // kr.shape[0]
+  bq, bk, streamed = _plan(t, d, qr.dtype.itemsize, group, block_q, block_k,
+                           window)
   scale = 1.0 / np.sqrt(d)
   do = _fold_heads(g)
   bh = qr.shape[0]
+  bhkv = kr.shape[0]
   delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                   axis=-1)[:, None, :]  # [bh, 1, t]
 
-  if _use_streamed(t, d, qr.dtype.itemsize):
+  if streamed:
     nk, nq = t // bk, t // bq
+
+    def kv_map(i, j, g):
+      if causal:
+        first, last = _live_key_blocks(j, bq, bk, nk, window)
+        g = jnp.clip(g, first, last)
+      return (i // group, g, 0)
+
     dq_kern = functools.partial(_dq_kernel_streamed, causal=causal,
-                                scale=scale, nk=nk)
+                                scale=scale, nk=nk, window=window)
     dq = pl.pallas_call(
         dq_kern,
         grid=(bh, nq, nk),
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda i, j, g: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, g: (i, g, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, g: (i, g, 0)),
+            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, bk, d), kv_map),
             pl.BlockSpec((1, bq, d), lambda i, j, g: (i, j, 0)),
             pl.BlockSpec((1, 1, bq), lambda i, j, g: (i, 0, j)),
             pl.BlockSpec((1, 1, bq), lambda i, j, g: (i, 0, j)),
@@ -503,35 +587,51 @@ def _flash_bwd(causal, block_q, block_k, res, g):
         out_shape=jax.ShapeDtypeStruct((bh, t, d), qr.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_use_interpret(),
+        name='flash_attention_dq',
     )(qr, kr, vr, do, lse, delta)
 
+    def q_block(j, r):
+      qb = r % nq
+      if causal:
+        first, last = _live_query_blocks(j, bq, bk, nq, window)
+        qb = jnp.clip(qb, first, last)
+      return qb
+
+    def q_map(i, j, r):
+      return (i * group + r // nq, q_block(j, r), 0)
+
+    def row_map(i, j, r):
+      return (i * group + r // nq, 0, q_block(j, r))
+
     dkv_kern = functools.partial(_dkv_kernel_streamed, causal=causal,
-                                 scale=scale, nq=nq)
+                                 scale=scale, nq=nq, group=group,
+                                 window=window)
     dk, dv = pl.pallas_call(
         dkv_kern,
-        grid=(bh, nk, nq),
+        grid=(bhkv, nk, group * nq),
         in_specs=[
-            pl.BlockSpec((1, bq, d), lambda i, j, g: (i, g, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, g: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, g: (i, j, 0)),
-            pl.BlockSpec((1, bq, d), lambda i, j, g: (i, g, 0)),
-            pl.BlockSpec((1, 1, bq), lambda i, j, g: (i, 0, g)),
-            pl.BlockSpec((1, 1, bq), lambda i, j, g: (i, 0, g)),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, bk, d), lambda i, j, r: (i, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda i, j, r: (i, j, 0)),
+            pl.BlockSpec((1, bq, d), q_map),
+            pl.BlockSpec((1, 1, bq), row_map),
+            pl.BlockSpec((1, 1, bq), row_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, bk, d), lambda i, j, g: (i, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda i, j, g: (i, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda i, j, r: (i, j, 0)),
+            pl.BlockSpec((1, bk, d), lambda i, j, r: (i, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, t, d), kr.dtype),
-            jax.ShapeDtypeStruct((bh, t, d), vr.dtype),
+            jax.ShapeDtypeStruct((bhkv, t, d), kr.dtype),
+            jax.ShapeDtypeStruct((bhkv, t, d), vr.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=_use_interpret(),
+        name='flash_attention_dkv',
     )(qr, kr, vr, do, lse, delta)
-    return (_unfold_heads(dq, b, h), _unfold_heads(dk, b, h),
-            _unfold_heads(dv, b, h))
+    return (_unfold_heads(dq, b, h), _unfold_heads(dk, b, h // group),
+            _unfold_heads(dv, b, h // group))
 
   dq_kern = functools.partial(_dq_kernel, bk=bk, causal=causal, scale=scale)
   dq = pl.pallas_call(

@@ -145,6 +145,19 @@ def maybe_ignore_batch(spec_or_tensors, ignore_batch: bool = False):
   return SpecStruct((k, strip(v)) for k, v in flat.to_dict().items())
 
 
+def _narrowed_on_device(expected: TensorSpec, actual: TensorSpec) -> bool:
+  """Whether ``actual``, read off a tensor, holds ``expected``'s dtype as
+  a jax device keeps it: with 64-bit types off (jax's default) an int64
+  record feature is int32 from placement on, so the in-spec of a
+  preprocessor that runs inside the jitted step meets the narrowed
+  type."""
+  if not actual.is_extracted or expected.dtype.itemsize != 8:
+    return False
+  import jax
+
+  return jax.dtypes.canonicalize_dtype(expected.dtype) == actual.dtype
+
+
 def assert_equal_spec_or_tensor(expected_spec_or_tensor,
                                 actual_spec_or_tensor) -> None:
   """Checks dtype and per-dim shape (None = wildcard) of a single leaf."""
@@ -154,7 +167,8 @@ def assert_equal_spec_or_tensor(expected_spec_or_tensor,
   # sequence dim in its shape, so strip one leading dim before comparing.
   if expected.is_sequence and actual.is_extracted:
     actual = TensorSpec.from_spec(actual, shape=actual.shape[1:])
-  if expected.dtype != actual.dtype:
+  if expected.dtype != actual.dtype and not _narrowed_on_device(
+      expected, actual):
     raise ValueError(
         f'dtype mismatch: expected {expected.dtype} got {actual.dtype}\n'
         f' expected: {expected}\n actual: {actual}')

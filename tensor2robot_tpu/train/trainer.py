@@ -1423,6 +1423,9 @@ class Trainer:
         # The guard flag aggregates over the WHOLE group (a bad step in
         # the middle must not be masked by a clean last step).
         out['nonfinite_count'] = jnp.sum(scalars_k['nonfinite_count'])
+      for name in self._model.counter_scalars:
+        if name in out:  # a count is of the whole group, like the flag
+          out[name] = jnp.sum(scalars_k[name], axis=0)
       return state, out
 
     return multi_step
@@ -1986,6 +1989,18 @@ class Trainer:
     # until it reaches it, so every host's forced checkpoint lands on
     # one common step.
     stop_step: Optional[int] = None
+    # Scalars the model declares as counts (``counter_scalars``) go to the
+    # registry under their own names, read one dispatch behind like the
+    # non-finite flag: by then the values are on the host's side of a
+    # boundary the loop already took.
+    counted = tuple(self._model.counter_scalars)
+    pending_counts: Optional[MetricDict] = None
+
+    def publish_counts(values: MetricDict) -> None:
+      for name in counted:
+        if name in values:
+          metrics_lib.counter(name).inc(int(values[name]))
+
     # The loop reads the clock once a boundary; the same reads feed the
     # breakdown and the span ring. Four spans tile the loop thread's time
     # between boundaries, keyed by the dispatch ordinal (= the batch
@@ -2127,6 +2142,10 @@ class Trainer:
               scalars.get('nonfinite_count'), step)
           if prev is not None and prev[0] is not None:
             self._nonfinite_policy.observe(prev[0], prev[1])
+        if counted:
+          if pending_counts is not None:
+            publish_counts(pending_counts)
+          pending_counts = scalars
         if crossed_interval(config.log_interval_steps, before, step):
           scalars = {k: float(v) for k, v in scalars.items()}
           dt = time.time() - last_log
@@ -2183,6 +2202,8 @@ class Trainer:
         pending_nonfinite is not None and pending_nonfinite[0] is not None):
       # Flush the final dispatch's flag before declaring success.
       self._nonfinite_policy.observe(*pending_nonfinite)
+    if pending_counts is not None:
+      publish_counts(pending_counts)
     if coordinated is not None and stop_step is None:
       # Completion: publish this host's final boundary UNCONDITIONALLY —
       # a peer whose SIGTERM lands after this moment (the completed-host

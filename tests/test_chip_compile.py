@@ -164,9 +164,50 @@ def _fused_update(chip, caplog):
     assert 'fused_update kernel was requested' in caplog.text
 
 
+def _flash_attention_window_grouped(chip, caplog):
+  # The token policy's attention at its published widths: 8,192 tokens,
+  # 32 query heads over 4 key/value heads of 128 in bfloat16, a
+  # 2,048 window and the full causal layer.
+  del caplog
+  q = chip((1, 8192, 32, 128), jnp.bfloat16)
+  kv = chip((1, 8192, 4, 128), jnp.bfloat16)
+  for window in (2048, None):
+
+    def loss(q, k, v, window=window):
+      out = flash_attention.flash_attention(q, k, v, True, None, None,
+                                            window)
+      return out.astype(jnp.float32).sum()
+
+    assert _custom_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
+
+
+def _grouped_product_tiles(chip, caplog):
+  # ``ragged_dot`` lowers to a grouped-matmul kernel; its tile table has
+  # rows / tile + groups - 1 entries, which is where ``layers/moe.py``
+  # has its ``GROUPED_ROW_TILE`` from (the rows the product computes are
+  # counted from it).
+  del caplog
+  import re
+
+  from tensor2robot_tpu.layers import moe
+
+  rows, groups = 65536, 16
+  text = jax.jit(lambda x, w, g: jax.lax.ragged_dot(
+      x, w, g, preferred_element_type=jnp.bfloat16)).lower(
+          chip((rows, 2048), jnp.bfloat16),
+          chip((groups, 2048, 1024), jnp.bfloat16),
+          chip((groups,), jnp.int32)).compile().as_text()
+  assert text.count('tpu_custom_call') >= 2
+  table = re.search(r'ragged-dot-metadata[.\d]* = \(s32\[\d+\][^,]*, '
+                    r's32\[(\d+)\]', text)
+  assert table, text[:2000]
+  assert int(table.group(1)) == rows // moe.GROUPED_ROW_TILE + groups - 1
+
+
 @pytest.mark.parametrize('case', [
     _photometric, _flash_attention, _pool_qtopt_refused, _pool_small_lowers,
-    _conv_s2d_refused, _fused_update,
+    _conv_s2d_refused, _fused_update, _flash_attention_window_grouped,
+    _grouped_product_tiles,
 ], ids=lambda fn: fn.__name__.lstrip('_'))
 def test_compiles_for_described_v5e(case, chip, caplog):
   case(chip, caplog)

@@ -128,3 +128,103 @@ def test_is_supported_requires_lane_tile_blocks_on_tpu():
   assert not fa.is_supported(8, 64, interpret=False)
   assert fa.is_supported(128, 64, interpret=False)
   assert fa.is_supported(4096, 64, interpret=False)
+
+
+# ------------------------------------------- window and grouped heads
+
+
+def _masked_softmax_attention(q, k, v, window):
+  """Plain attention: causal, ``0 <= i - j < window``, query head h on
+  key/value head h // (H // Hkv)."""
+  t, h, d = q.shape[1], q.shape[2], q.shape[3]
+  group = h // k.shape[2]
+  k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+  s = jnp.einsum('bqhd,bkhd->bhqk', q, k) / np.sqrt(d)
+  i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+  seen = i >= j
+  if window is not None:
+    seen = seen & (i - j < window)
+  p = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+  return jnp.einsum('bhqk,bkhd->bqhd', p, v)
+
+
+@pytest.mark.parametrize('t,heads,kv_heads,window,bq,bk', [
+    (256, 4, 2, 64, 64, 64),       # a window of one block
+    (256, 8, 2, 96, 32, 64),       # a window across blocks, bq < bk
+    (256, 4, 1, None, 64, 128),    # grouped heads, no window
+    (256, 4, 4, 100, None, None),  # default blocks, cut to the window
+    (128, 2, 2, 128, 64, 64),      # a window as long as the sequence
+])
+def test_window_and_grouped_heads_match_masked_softmax(t, heads, kv_heads,
+                                                       window, bq, bk):
+  rng = np.random.RandomState(4)
+  q = jnp.asarray(rng.randn(2, t, heads, 16), jnp.float32)
+  k = jnp.asarray(rng.randn(2, t, kv_heads, 16), jnp.float32)
+  v = jnp.asarray(rng.randn(2, t, kv_heads, 16), jnp.float32)
+  ct = jnp.asarray(rng.randn(2, t, heads, 16), jnp.float32)
+
+  def flash(q, k, v):
+    return flash_attention(q, k, v, True, bq, bk, window)
+
+  np.testing.assert_allclose(
+      np.asarray(flash(q, k, v)),
+      np.asarray(_masked_softmax_attention(q, k, v, window)), atol=2e-5)
+  got = jax.grad(lambda *a: jnp.sum(flash(*a) * ct), (0, 1, 2))(q, k, v)
+  want = jax.grad(lambda *a: jnp.sum(
+      _masked_softmax_attention(*a, window) * ct), (0, 1, 2))(q, k, v)
+  for g, w in zip(got, want):
+    assert g.shape == w.shape
+    np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-4)
+
+
+@pytest.mark.parametrize('shape,bq,bk', [
+    ((2, 256, 2, 32), 64, 128),    # staged kernels
+    ((1, 256, 2, 16), 128, 64),
+])
+def test_no_window_output_is_what_it_was(shape, bq, bk):
+  """Without a window and with one head count the new argument changes
+  no bit: positional and keyword calls agree, and a window as long as
+  the sequence (the streamed kernels) agrees to rounding."""
+  q, k, v = _qkv(shape, seed=7)
+  old = flash_attention(q, k, v, True, bq, bk)
+  new = flash_attention(q, k, v, True, bq, bk, window=None)
+  np.testing.assert_array_equal(np.asarray(old), np.asarray(new))
+  windowed = flash_attention(q, k, v, True, bq, bk, shape[1])
+  np.testing.assert_allclose(np.asarray(old), np.asarray(windowed),
+                             atol=2e-6)
+
+
+def test_streamed_products_take_the_inputs_dtype():
+  """bfloat16 q/k/v keep bfloat16 operands in the streamed kernels'
+  products (sums in float32): close to the float32 answer on the same
+  rounded inputs, forward and backward, and bfloat16 out."""
+  rng = np.random.RandomState(5)
+  q, k, v, ct = (jnp.asarray(rng.randn(1, 256, h, 16), jnp.bfloat16)
+                 for h in (4, 2, 2, 4))
+
+  def flash(q, k, v):
+    return flash_attention(q, k, v, True, 64, 64, 96)
+
+  def plain(q, k, v):
+    return _masked_softmax_attention(*(x.astype(jnp.float32)
+                                       for x in (q, k, v)), 96)
+
+  out = flash(q, k, v)
+  assert out.dtype == jnp.bfloat16
+  np.testing.assert_allclose(np.asarray(out, np.float32),
+                             np.asarray(plain(q, k, v)), atol=3e-2)
+  got = jax.grad(lambda *a: jnp.sum(
+      flash(*a).astype(jnp.float32) * ct), (0, 1, 2))(q, k, v)
+  want = jax.grad(lambda *a: jnp.sum(plain(*a) * ct), (0, 1, 2))(q, k, v)
+  for g, w in zip(got, want):
+    assert g.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(g, np.float32),
+                               np.asarray(w, np.float32), atol=0.15)
+
+
+def test_window_needs_causal_and_heads_must_divide():
+  q, k, v = _qkv((1, 128, 4, 16))
+  with pytest.raises(ValueError, match='causal'):
+    flash_attention(q, k, v, False, 64, 64, 32)
+  with pytest.raises(ValueError, match='divide'):
+    flash_attention(q, k[:, :, :3], v[:, :, :3], True, 64, 64)
