@@ -18,17 +18,36 @@ expert. After a training step's forward pass, outside the gradient,
 ``moe_state``, which the trainer carries as ``model_state``.
 
 No token is dropped. The (token, choice) pairs are sorted by the slot of
-the expert they chose, held experts first; the buffer of routed rows has
-room for the worst case the share allows (every token choosing held
-experts only: ``tokens * min(k, held)`` rows), and the grouped matrix
-product (``jax.lax.ragged_dot``: on the TPU a grouped-matmul kernel that
-visits the tiles of live rows only) computes the rows that are there.
-Rows move by gathers in both directions (the sort's permutation and its
-inverse), never by a scatter.
+the expert they chose, held experts first, and the held ones' rows are
+copied into a buffer for the grouped matrix product
+(``jax.lax.ragged_dot``: on the TPU a grouped-matmul kernel that visits
+the tiles of live rows only). Rows move by gathers in both directions
+(the sort's permutation and its inverse), never by a scatter.
+
+The buffer's size follows the rows that were routed. It has two sizes
+(``ladder``), which the layer's shapes alone decide: twice the rows a
+balanced router sends to the held experts, and the worst case the share
+allows (every token choosing held experts only: ``tokens * min(k,
+held)`` rows). Inside the step, from this step's own count of routed
+rows, an on-device conditional takes the lowest rung that holds them;
+what lies between the sort and the weighted sum (the gather into the
+buffer, the three grouped products with silu x up, the gather back, and
+their gradients) runs at that rung's size. The top rung holds every
+case, so nothing is dropped whatever the router does. A layer that
+holds half the experts or more has one rung and no conditional. A rung's
+backward pass computes its buffer again from the layer's input rather
+than keep it: what passes from the forward conditional to the backward
+one then has the same shape on every rung (a conditional's branches
+must agree on their results, so a kept buffer of one rung would be
+written as zeros by the others). Each rung is compiled, and loaded at
+every start, with its own grouped products: rungs at the balance itself
+and between these two were tried and bought too little for that
+(PERF.md, PR 29).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
@@ -141,37 +160,115 @@ class SwiGLU(nn.Module):
 
 
 class _Experts(nn.Module):
-  """The held experts' three stacked matrices and the grouped products
-  over rows sorted by expert."""
+  """The held experts' three stacked matrices."""
 
   held: int
   width: int
-  dtype: Any
   init_std: float
 
   @nn.compact
-  def __call__(self, rows, group_sizes):
-    d = rows.shape[-1]
+  def __call__(self, d: int):
     gate = self.param('gate', normal_init(self.init_std),
                       (self.held, d, self.width))
     up = self.param('up', normal_init(self.init_std),
                     (self.held, d, self.width))
     down = self.param('down', normal_init(self.init_std),
                       (self.held, self.width, d))
+    return gate, up, down
 
-    def product(lhs, rhs):
-      return jax.lax.ragged_dot(lhs, rhs.astype(self.dtype), group_sizes,
-                                preferred_element_type=self.dtype)
 
-    h = jax.nn.silu(product(rows, gate)) * product(rows, up)
-    return product(h, down)
+# ------------------------------------------------- the routed-row buffer
+
+def ladder(tokens: int, k: int, held: int, num_experts: int
+           ) -> Tuple[int, ...]:
+  """The routed-row buffer's sizes, rising: twice the rows a balanced
+  router sends to ``held`` of ``num_experts`` experts, then the worst
+  case the share allows; the worst case alone where twice the balance
+  reaches it."""
+  worst = tokens * min(k, held)
+  twice_balanced = 2 * -(-tokens * k * held // num_experts)
+  return (twice_balanced, worst) if twice_balanced < worst else (worst,)
+
+
+def _through_buffer(k: int, room: int, x, experts, aux):
+  """[tokens, k, hidden]: each held pair's row of its expert's output
+  (other pairs 0), by way of a buffer of ``room`` rows sorted by expert:
+  gathered from ``x``, through the grouped products, gathered back."""
+  gate, up, down = experts
+  order, back, placed, group_sizes = aux
+  with jax.named_scope('afmoe/moe/route'):
+    source = order[:room]
+    rows = _dispatch(x, source // k, back, placed)
+
+  def product(lhs, rhs):
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=x.dtype)
+
+  with jax.named_scope('afmoe/moe/experts'):
+    y = product(jax.nn.silu(product(rows, gate)) * product(rows, up), down)
+  with jax.named_scope('afmoe/moe/route'):
+    source_live = jnp.arange(room) < jnp.sum(group_sizes)
+    return _collect(y, back, placed, source, source_live)
+
+
+def _cast(tree, dtype):
+  return jax.tree_util.tree_map(lambda leaf: leaf.astype(dtype), tree)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _on_rung(k, rungs, rung, x, experts, aux):
+  """``_through_buffer(k, rungs[rung], x, experts cast to x's dtype,
+  aux)``, the rung taken by a conditional on the device. The way back
+  takes the same rung and goes through its buffer again there: between
+  the two conditionals pass the arguments alone, which no rung shapes."""
+  return _on_rung_fwd(k, rungs, rung, x, experts, aux)[0]
+
+
+def _on_rung_fwd(k, rungs, rung, x, experts, aux):
+  cast = _cast(experts, x.dtype)
+  return _forward(k, rungs, rung, x, cast, aux), (rung, x, cast, aux)
+
+
+def _on_rung_bwd(k, rungs, res, g):
+  dx, dexperts = _backward(k, rungs, *res, g)
+  return None, dx, dexperts, None
+
+
+_on_rung.defvjp(_on_rung_fwd, _on_rung_bwd)
+
+
+# Jitted so that layers of one shape are traced and lowered once: every
+# rung is a branch, and a start pays for each trace (PERF.md, PR 29).
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _forward(k, rungs, rung, x, cast, aux):
+  return jax.lax.switch(
+      rung, [functools.partial(_through_buffer, k, room) for room in rungs],
+      x, cast, aux)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _backward(k, rungs, rung, x, cast, aux, g):
+  def back(room, x, cast, aux, g):
+    # The experts' gradients leave the branch in the parameters' dtype,
+    # converted where they are made: handed on in ``x``'s dtype, the
+    # conversion and the optimizer's first use of them ran as passes of
+    # their own over every expert matrix (PERF.md, PR 29).
+    dx, dcast = jax.vjp(
+        lambda x, cast: _through_buffer(k, room, x, cast, aux), x, cast)[1](g)
+    return dx, _cast(dcast, jnp.float32)
+
+  return jax.lax.switch(
+      rung, [functools.partial(back, room) for room in rungs],
+      x, cast, aux, g)
 
 
 class ExpertLayer(nn.Module):
   """See the module docstring. ``__call__`` takes [..., hidden] and
   returns the same shape and a dict of this call's counts (int32
   scalars: ``tokens``, ``rows_routed``, ``rows_computed``,
-  ``rows_max_expert``, ``rows_dropped``)."""
+  ``rows_max_expert``, ``rows_dropped``, and ``rows_room``, the rows of
+  the buffer's rung this call took: over ``tokens * min(k, held)`` it is
+  the share of the worst case that was moved)."""
 
   num_experts: int                 # the router's width: all published
   experts_per_token: int
@@ -192,7 +289,7 @@ class ExpertLayer(nn.Module):
                 else tuple(self.experts_held))
     held = len(held_ids)
     pairs = tokens * k
-    room = tokens * min(k, held)   # the worst case this share allows
+    rungs = ladder(tokens, k, held, self.num_experts)
 
     router = self.param('router', normal_init(self.init_std),
                         (shape[-1], self.num_experts))
@@ -216,19 +313,23 @@ class ExpertLayer(nn.Module):
       position = jnp.argsort(order).astype(jnp.int32).reshape(tokens, k)
       group_sizes = jnp.sum(flat[:, None] == jnp.arange(held), axis=0,
                             dtype=jnp.int32)
+      # The lowest rung that holds this call's rows (the last holds any).
+      rung = jnp.sum(jnp.sum(group_sizes) > jnp.asarray(rungs[:-1]),
+                     dtype=jnp.int32)
+      room = jnp.asarray(rungs, jnp.int32)[rung]
       is_held = slot < held
       placed = jnp.logical_and(is_held, position < room)
-      source = order[:room]
       back = jnp.minimum(position, room - 1)
-      rows = _dispatch(x, source // k, back, placed)
 
-    with jax.named_scope('afmoe/moe/experts'):
-      y = _Experts(held, self.expert_width, self.dtype, self.init_std,
-                   name='experts')(rows, group_sizes)
-
+    experts = _Experts(held, self.expert_width, self.init_std,
+                       name='experts')(shape[-1])
+    aux = (order, back, placed, group_sizes)
+    if len(rungs) == 1:                                   # [T, k, D]
+      picked = _through_buffer(k, rungs[0], x, _cast(experts, self.dtype),
+                               aux)
+    else:
+      picked = _on_rung(k, rungs, rung, x, experts, aux)
     with jax.named_scope('afmoe/moe/route'):
-      source_live = jnp.arange(room) < jnp.sum(group_sizes)
-      picked = _collect(y, back, placed, source, source_live)   # [T, k, D]
       routed = jnp.sum(picked.astype(jnp.float32) *
                        jnp.where(placed, weights, 0.0)[..., None], axis=1)
 
@@ -242,11 +343,12 @@ class ExpertLayer(nn.Module):
       # chips first; here they are this chip's tokens.
       bias.value = updated_bias(bias.value, counts, self.load_balance_coeff)
       last_counts.value = counts
-    stats = _stats(tokens, is_held, placed, group_sizes)
+    stats = _stats(tokens, is_held, placed, group_sizes, room)
     return out.reshape(shape), stats
 
 
-def _stats(tokens: int, is_held, placed, group_sizes) -> Dict[str, jax.Array]:
+def _stats(tokens: int, is_held, placed, group_sizes,
+           room) -> Dict[str, jax.Array]:
   starts = jnp.cumsum(group_sizes) - group_sizes
   last = starts + group_sizes - 1
   visits = jnp.where(group_sizes > 0,
@@ -260,4 +362,5 @@ def _stats(tokens: int, is_held, placed, group_sizes) -> Dict[str, jax.Array]:
       'rows_dropped': jnp.sum(jnp.logical_and(is_held,
                                               jnp.logical_not(placed)),
                               dtype=jnp.int32),
+      'rows_room': room,
   }

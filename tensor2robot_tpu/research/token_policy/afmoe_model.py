@@ -37,8 +37,11 @@ from tensor2robot_tpu.specs import SpecStruct, TensorSpec
 # boundary, one dispatch behind. ``moe/rows_max_expert`` is the step's
 # fullest held expert (the largest over the layers), summed over steps
 # like the rest: over ``trainer/dispatches`` it is the mean.
+# ``moe/rows_room`` is the rows of the routed-row buffer's rung that each
+# expert layer took: over ``moe/tokens`` x min(k, held) it is the share
+# of the worst case that was moved (1.0: the ladder never engaged).
 COUNTERS = ('moe/tokens', 'moe/rows_routed', 'moe/rows_computed',
-            'moe/rows_max_expert', 'moe/rows_dropped')
+            'moe/rows_max_expert', 'moe/rows_dropped', 'moe/rows_room')
 
 
 class _TokensFromRecords(SpecTransformationPreprocessor):

@@ -201,7 +201,95 @@ def test_no_row_dropped_when_every_token_chooses_held_experts():
   assert int(stats['rows_routed']) == x.shape[0] * 4   # the worst case
   assert np.all(np.asarray(counts[:4]) == x.shape[0])
   assert int(stats['rows_dropped']) == 0
+  # The buffer's top rung was taken, and it is the worst case.
+  assert int(stats['rows_room']) == x.shape[0] * 4 == moe.ladder(
+      x.shape[0], 4, 4, 16)[-1]
   close(out, want)
+
+
+def _routed_exactly(cfg, live, dtype):
+  """Parameters and tokens of which exactly ``live`` (token, choice)
+  pairs choose a held expert: the first three hidden dimensions say
+  which kind a token is (all four choices held; one held and three
+  absent; all absent) and the router's first three rows answer them far
+  above what the other dimensions add."""
+  params, x, _ = _expert_setup(cfg)
+  tokens, experts = x.shape[0], cfg['num_experts_published']
+  all_held, one_held = divmod(live, 4)
+  assert all_held + one_held <= tokens
+  kind = np.full((tokens,), 2)
+  kind[:all_held] = 0
+  kind[all_held:all_held + one_held] = 1
+  kind = np.random.RandomState(live).permutation(kind)
+  x = x.at[:, :3].set(jnp.asarray(np.eye(3)[kind], x.dtype))
+  answers = np.full((3, experts), -3.0, np.float32)
+  answers[0, [0, 1, 2, 3]] = 3.0
+  answers[1, [0, 4, 5, 6]] = 3.0
+  answers[2, [4, 5, 6, 7]] = 3.0
+  router = (params['router'] / 6.0).at[:3].set(jnp.asarray(answers))
+  return dict(params, router=router), x.astype(dtype)
+
+
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16],
+                         ids=['float32', 'bfloat16'])
+@pytest.mark.parametrize('live,room', [
+    (50, 256), (255, 256), (256, 256), (257, 512), (400, 512), (512, 512)])
+def test_laddered_buffer_equals_the_worst_case_buffer(live, room, dtype,
+                                                      monkeypatch):
+  """Whatever rung the routed rows take (inside one, at its edge, one row
+  past it, the worst case), the layer under remat and grad is the layer
+  with the worst-case buffer alone: the expert and shared leaves' and the
+  input's gradients to the last bit, the rest within float32 summation
+  order."""
+  cfg = tiny_cfg()            # 128 tokens, holds 0-3 of 16, four chosen
+  params, x = _routed_exactly(cfg, live, dtype)
+  tokens = x.shape[0]
+  assert moe.ladder(tokens, 4, 4, 16) == (256, 512)
+  experts = cfg['num_experts_published']
+  state = {'bias': jnp.zeros((experts,)),
+           'counts': jnp.zeros((experts,), jnp.int32)}
+  weight = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+  layer = _layer_for(cfg).clone(dtype=dtype)
+
+  def run():
+    @jax.jit
+    def program(p, x):
+      @jax.checkpoint
+      def loss(p, x):
+        out, stats = layer.apply({'params': p, moe.MOE_STATE: state}, x)
+        return jnp.sum(out.astype(jnp.float32) * weight), (out, stats)
+      return jax.value_and_grad(loss, (0, 1), has_aux=True)(p, x)
+    return program(params, x)
+
+  (loss, (out, stats)), grads = run()
+  assert int(stats['rows_routed']) == live
+  assert int(stats['rows_room']) == room
+  assert int(stats['rows_dropped']) == 0
+  monkeypatch.setattr(moe, 'ladder', lambda t, k, held, n: (t * min(k, held),))
+  (want_loss, (want, want_stats)), want_grads = run()
+  assert int(want_stats['rows_room']) == 512
+  assert int(want_stats['rows_routed']) == live
+  close(loss, want_loss, 1e-6)
+  close(out, want, 1e-6)
+  for part in ('experts', 'shared'):
+    for name, leaf in grads[0][part].items():
+      np.testing.assert_array_equal(np.asarray(leaf),
+                                    np.asarray(want_grads[0][part][name]))
+  np.testing.assert_array_equal(np.asarray(grads[1]),
+                                np.asarray(want_grads[1]))
+  close(grads[0]['router'], want_grads[0]['router'], 1e-6)
+  assert float(jnp.abs(grads[0]['experts']['gate']).max()) > 0
+
+
+def test_ladder_is_twice_the_balance_and_the_worst_case():
+  # The cell's layer: 8,192 tokens, 8 chosen, 16 of 128 held.
+  assert moe.ladder(8192, 8, 16, 128) == (16384, 65536)
+  # A share of fewer experts than a token chooses.
+  assert moe.ladder(128, 4, 2, 16) == (128, 256)
+  # A layer that holds every expert, or half of them, has the one size.
+  assert moe.ladder(128, 4, 16, 16) == (512,)
+  assert moe.ladder(128, 4, 8, 16) == (512,)
+  assert moe.ladder(100, 3, 2, 7) == (172, 200)
 
 
 def test_bias_update_follows_the_counts():
@@ -407,6 +495,14 @@ def test_two_trainer_steps_match_reference_and_count(tmp_path):
   assert moved['moe/rows_dropped'] == 0
   assert moved['moe/tokens'] == 2 * batches[0].size * len(ref.init_state(cfg))
   assert moved['moe/rows_computed'] >= routed
+  # Each expert layer took, each step, the lowest rung that held its rows.
+  rungs = np.asarray(moe.ladder(batches[0].size, cfg['num_experts_per_tok'],
+                                len(held), cfg['num_experts_published']))
+  live = np.concatenate([np.asarray(s['counts'])[:, held].sum(axis=1)
+                         for s in want])
+  assert moved['moe/rows_room'] == int(
+      rungs[np.searchsorted(rungs, live)].sum())
+  assert moved['moe/rows_room'] < live.size * rungs[-1]
 
 
 def test_trainer_binary_trains_the_token_policy_from_its_gin(tmp_path):

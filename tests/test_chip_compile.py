@@ -204,10 +204,62 @@ def _grouped_product_tiles(chip, caplog):
   assert int(table.group(1)) == rows // moe.GROUPED_ROW_TILE + groups - 1
 
 
+def _expert_layer_ladder(chip, caplog):
+  # The token policy's expert layer at the cell's shapes under ``grad``:
+  # one conditional forward and one backward, a branch a rung of the
+  # routed-row buffer, the grouped products in every branch. No rung's
+  # buffer is kept between the two (each backward branch computes its
+  # own again): the temporaries stay near the single worst-case
+  # buffer's, where keeping them read 3.0x (PERF.md, PR 29).
+  del caplog
+  import re
+
+  from tensor2robot_tpu.layers import moe
+
+  tokens, hidden, published, held, k = 8192, 2048, 128, 16, 8
+  layer = moe.ExpertLayer(
+      num_experts=published, experts_per_token=k, expert_width=1024,
+      experts_held=tuple(range(held)), route_scale=2.826, dtype=jnp.bfloat16)
+  x = chip((tokens, hidden), jnp.bfloat16)
+  weight = chip((tokens, hidden), jnp.float32)
+  variables = jax.tree_util.tree_map(
+      lambda leaf: chip(leaf.shape, leaf.dtype),
+      jax.eval_shape(layer.init, jax.random.PRNGKey(0),
+                     jnp.zeros((tokens, hidden), jnp.bfloat16)))
+
+  def loss(params, x, state, weight):
+    out, _ = layer.apply({'params': params, moe.MOE_STATE: state}, x)
+    return jnp.sum(out.astype(jnp.float32) * weight)
+
+  def compiled():
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        variables['params'], x, variables[moe.MOE_STATE], weight).compile()
+
+  rungs = moe.ladder(tokens, k, held, published)
+  assert rungs == (16384, 65536)
+  laddered = compiled()
+  with mock.patch.object(moe, 'ladder', lambda *shapes: rungs[-1:]):
+    single = compiled()
+  text = laddered.as_text()
+  bodies = dict(re.findall(r'^(%[\w.-]+) [^\n]*\{\n(.*?)^\}', text,
+                           flags=re.M | re.S))
+  conditionals = re.findall(r'conditional\(.*?branch_computations=\{([^}]*)\}',
+                            text)
+  assert len(conditionals) == 2, len(conditionals)   # forward, backward
+  for branches in conditionals:
+    names = [name.strip() for name in branches.split(',')]
+    assert len(names) == len(rungs)
+    for name in names:
+      assert 'tpu_custom_call' in bodies[name], name
+  assert 'conditional(' not in single.as_text()
+  temporaries = laddered.memory_analysis().temp_size_in_bytes
+  assert temporaries < 1.25 * single.memory_analysis().temp_size_in_bytes
+
+
 @pytest.mark.parametrize('case', [
     _photometric, _flash_attention, _pool_qtopt_refused, _pool_small_lowers,
     _conv_s2d_refused, _fused_update, _flash_attention_window_grouped,
-    _grouped_product_tiles,
+    _grouped_product_tiles, _expert_layer_ladder,
 ], ids=lambda fn: fn.__name__.lstrip('_'))
 def test_compiles_for_described_v5e(case, chip, caplog):
   case(chip, caplog)
