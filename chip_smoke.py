@@ -282,15 +282,15 @@ def train_phase(run: Run) -> dict:
       'kernels/refused', 'export/serving_fn_single_platform',
       'resilience/nonfinite_skipped_steps')}
   _require(not any(degraded.values()), f'degraded paths were taken: {degraded}')
-  tpu_branches = {k: metrics.get(k) for k in (
-      'trainer/auto_input_layouts', 'trainer/prefetch/place_stage')}
+  tpu_branches = {'trainer/prefetch/place_stage': metrics.get(
+      'trainer/prefetch/place_stage')}
   if not run.rehearse:
     # The defaults that switch on only on a TPU backend.
     _require(all(v == 1.0 for v in tpu_branches.values()),
              f'TPU-only default branches were not taken: {tpu_branches}')
+  # None where the run ended before the ledger's deferred harvest of the
+  # step (TrainerConfig.program_harvest_delay_seconds).
   program = report.get('programs', {}).get('train/step')
-  _require(program is not None or run.rehearse,
-           "no 'train/step' program in the report's ledger")
   dispatch_wall = metrics.get('trainer/step_wall_ms', {})
   return {
       'device': device,
@@ -310,8 +310,8 @@ def train_phase(run: Run) -> dict:
       'steady_dispatch_wall_ms': {
           k: round(dispatch_wall.get(k, 0.0), 1)
           for k in ('count', 'min', 'mean', 'max')},
-      # Every hand kernel is opt-in (kernel_policy, use_fused_kernel,
-      # fused_update), so the default step has none.
+      # Every hand kernel is opt-in (kernel_policy, use_fused_kernel),
+      # so the default step has none.
       'tpu_custom_calls_in_train_step': (program or {}).get('custom_calls'),
       'tpu_default_branches': tpu_branches,
       'checkpoint': os.path.relpath(checkpoint, run.out_dir),
@@ -486,6 +486,8 @@ def multichip_phase(run: Run) -> dict:
 def multichip_child(rehearse: bool) -> int:
   """Runs IN THE CHILD (imports jax). Prints its result as the last
   line of its output; any failed assertion is a non-zero exit."""
+  import threading
+
   import jax
   import numpy as np
 
@@ -512,13 +514,13 @@ def multichip_child(rehearse: bool) -> int:
   batch = next(generator.create_iterator(ModeKeys.TRAIN))
 
   def config(steps):
-    # auto_input_layouts=True makes the step's compile synchronous and
-    # its ledger record ('train/step') exist when the arm returns, on
-    # the CPU rehearsal as on the chip (where it is the default).
+    # Delay 0: the ledger's harvest of the step ('train/step') starts at
+    # the first dispatch, on its own thread (run_arm joins it).
     return TrainerConfig(
         model_dir='', max_train_steps=steps, seed=SEED,
         steps_per_dispatch=STEPS_PER_DISPATCH, eval_interval_steps=0,
-        log_interval_steps=0, prefetch_batches=0, auto_input_layouts=True)
+        log_interval_steps=0, prefetch_batches=0,
+        program_harvest_delay_seconds=0)
 
   def placement(mesh):
     """Every device of ``mesh`` holds its own shard of a placed batch
@@ -533,8 +535,17 @@ def multichip_child(rehearse: bool) -> int:
                for shape in per_device.values()), (per_device, n)
     return {'batch_shard_shape_by_device': per_device}
 
-  def sharded_arm(mesh, steps):
+  def run_arm(mesh, steps):
     arm = equivalence.run_arm(make_model, mesh, batch, config(steps))
+    # The ledger is the process's: with every arm's harvest joined, the
+    # 'train/step' on record is the step of the arm that ran last.
+    for thread in threading.enumerate():
+      if thread.name == 't2r-program-ledger':
+        thread.join()
+    return arm
+
+  def sharded_arm(mesh, steps):
+    arm = run_arm(mesh, steps)
     record = programs.get('train/step')
     assert record is not None, 'no train/step program was recorded'
     return arm, dict(record.collectives)
@@ -549,7 +560,7 @@ def multichip_child(rehearse: bool) -> int:
   # f32 matmuls at default precision are bf16 passes on the MXU: the
   # strict band needs the full-precision passes in BOTH arms.
   with jax.default_matmul_precision('highest'):
-    reference = equivalence.run_arm(make_model, one_device, batch, config(1))
+    reference = run_arm(one_device, 1)
     for name, mesh in meshes.items():
       arm, collectives = sharded_arm(mesh, 1)
       seen = equivalence.compare_arms(arm, reference, f'{name} one step')
@@ -572,8 +583,7 @@ def multichip_child(rehearse: bool) -> int:
     # says "finite and the same run", no more; the one-step comparisons
     # are the strict ones.
     steps = 3 * STEPS_PER_DISPATCH
-    reference = equivalence.run_arm(make_model, one_device, batch,
-                                    config(steps))
+    reference = run_arm(one_device, steps)
     arm, collectives = sharded_arm(meshes['dp2_fsdp2'], steps)
     comparisons[f'dp2_fsdp2/{steps}_steps'] = dict(
         equivalence.compare_arms(

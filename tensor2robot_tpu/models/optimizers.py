@@ -19,8 +19,6 @@ from typing import Callable, Optional, Union
 
 import optax
 
-from tensor2robot_tpu.ops import fused_update as fused_lib
-
 Schedule = Callable[[int], float]
 LearningRate = Union[float, Schedule]
 
@@ -54,29 +52,14 @@ def create_adam_optimizer(
     beta1: float = 0.9,
     beta2: float = 0.999,
     epsilon: float = 1e-8) -> optax.GradientTransformation:
-  """Mirrors ``create_adam_optimizer`` (optimizers.py:29-50).
-
-  The returned transformation is TAGGED for the fused-update kernel
-  (``ops/fused_update.py``, ``TrainerConfig.fused_update``): a
-  duck-typed ``(init, update, fused_spec)`` NamedTuple optax treats
-  exactly like a plain ``GradientTransformation``. Wrapping it (e.g.
-  ``with_gradient_clipping``) drops the tag and keeps the stock path.
-  """
-  return fused_lib.tag(
-      optax.adam(learning_rate, b1=beta1, b2=beta2, eps=epsilon),
-      fused_lib.FusedSpec(kind='adam', learning_rate=learning_rate,
-                          b1=beta1, b2=beta2, eps=epsilon))
+  """Mirrors ``create_adam_optimizer`` (optimizers.py:29-50)."""
+  return optax.adam(learning_rate, b1=beta1, b2=beta2, eps=epsilon)
 
 
 def create_gradient_descent_optimizer(
     learning_rate: LearningRate = 1e-4) -> optax.GradientTransformation:
-  """Mirrors ``create_gradient_descent_optimizer`` (optimizers.py:53-70).
-
-  Tagged for the fused-update kernel, like :func:`create_adam_optimizer`.
-  """
-  return fused_lib.tag(
-      optax.sgd(learning_rate),
-      fused_lib.FusedSpec(kind='sgd', learning_rate=learning_rate))
+  """Mirrors ``create_gradient_descent_optimizer`` (optimizers.py:53-70)."""
+  return optax.sgd(learning_rate)
 
 
 def create_momentum_optimizer(

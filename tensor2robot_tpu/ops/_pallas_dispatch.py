@@ -24,7 +24,7 @@ contract, first established by ``flash_attention`` and lifted here so
   The gate is consulted at TRACE time: a jitted program bakes in
   whichever path was live when it traced.
 * **Loud refusal** (:func:`refuse`): a kernel the caller asked for
-  (``kernel_policy``, ``fused_update=True``) that the live target cannot
+  (``kernel_policy``) that the live target cannot
   run gives way to its XLA reference with one WARNING and a
   ``kernels/refused`` count — never silently. Each kernel's
   ``is_supported`` answers for the target it would actually lower to:
@@ -106,7 +106,7 @@ def refuse(kernel: str, reason: str) -> None:
   """Records that a REQUESTED hand kernel gave way to its XLA reference.
 
   Called at trace time by the gated entry points when the caller's
-  explicit ask (a ``kernel_policy`` tower, ``fused_update=True``) cannot
+  explicit ask (a ``kernel_policy`` tower) cannot
   be honoured at the shapes in hand. chip_smoke.py fails a run whose
   report shows ``kernels/refused`` > 0.
   """

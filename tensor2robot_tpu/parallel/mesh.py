@@ -26,7 +26,6 @@ from typing import Any, Dict, Optional, Sequence
 
 import jax
 import numpy as np
-from jax.experimental.layout import Format
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 DATA_AXIS = 'data'
@@ -310,8 +309,7 @@ def global_batch_size(per_device_batch: int, mesh: Mesh) -> int:
   return per_device_batch * n
 
 
-def shard_batch(batch: Any, mesh: Mesh, formats: Any = None,
-                stacked: bool = False) -> Any:
+def shard_batch(batch: Any, mesh: Mesh, stacked: bool = False) -> Any:
   """Places a batch onto the mesh, sharded on the batch axes.
 
   Single-process: ``batch`` is the global batch; a plain sharded
@@ -322,18 +320,6 @@ def shard_batch(batch: Any, mesh: Mesh, formats: Any = None,
   per-host feeding from TPUEstimator's per-host ``input_fn``
   (``utils/tfdata.py:43-66``); feeding a host-global batch on every host
   would silently duplicate data across hosts.
-
-  ``formats``: optional pytree matching ``batch`` of
-  ``jax.experimental.layout.Format`` (place the leaf in the COMPILED
-  EXECUTABLE's preferred layout — see ``Trainer`` auto input layouts —
-  so XLA never re-lays it out inside the step) or plain shardings (a
-  plain transfer). A leaf with a ``Format`` is placed in two halves:
-  the host-to-device copy with the ``Format``'s own sharding
-  (:func:`copy_shardings`), then :func:`relayout_batch` on the device.
-  A caller that must not wait on the device's compute queue (the
-  trainer's placement) passes ``copy_shardings(formats)`` here, blocks
-  on what comes back and only then calls ``relayout_batch`` itself.
-  Single-process only; the multi-host assembly path ignores it.
 
   ``stacked``: the batch is a ``[K, batch, ...]`` step-group
   (``steps_per_dispatch``); shard dim 1 instead of dim 0.
@@ -347,40 +333,8 @@ def shard_batch(batch: Any, mesh: Mesh, formats: Any = None,
     return jax.tree_util.tree_map(
         lambda x: jax.make_array_from_process_local_data(
             sharding, np.asarray(x)), batch)
-  if formats is not None:
-    copied = jax.tree_util.tree_map(jax.device_put, batch,
-                                    copy_shardings(formats))
-    return relayout_batch(copied, formats)
   return jax.tree_util.tree_map(
       lambda x: jax.device_put(x, sharding), batch)
-
-
-def copy_shardings(formats: Any) -> Any:
-  """The plain sharding of every placement target: a ``Format``'s own
-  ``.sharding``, a sharding as it is. A ``device_put`` with these is a
-  host-to-device copy and puts no program on the device."""
-  return jax.tree_util.tree_map(
-      lambda f: f.sharding if isinstance(f, Format) else f, formats)
-
-
-def relayout_batch(copied: Any, formats: Any) -> Any:
-  """Second half of a placement in ``formats``: every leaf of ``copied``
-  (device arrays, as ``copy_shardings(formats)`` placed them) whose
-  target is a ``Format`` goes through ``jax.device_put(leaf, format)``,
-  the others come back as they are.
-
-  In jax 0.9.0 that call is ``jit(_identity_fn, out_shardings=format)``:
-  a small program on the device's compute queue, enqueued here and not
-  waited for. Handed a HOST array the same call would copy and re-lay
-  out in one, and the program would sit in the queue until its copy had
-  arrived, holding back whatever was enqueued after it; handed an array
-  that is already there it runs when its turn comes (0.1 ms for a
-  31.5 MB frame leaf on the v5e). An array that already has the format
-  comes back itself, with no program.
-  """
-  return jax.tree_util.tree_map(
-      lambda x, f: jax.device_put(x, f) if isinstance(f, Format) else x,
-      copied, formats)
 
 
 # ------------------------------------------------- parameter sharding rules

@@ -21,20 +21,17 @@ through the callback protocol (the reference's HookBuilder surface).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 import logging
 import os
 import threading
 import time
-import warnings
 from typing import Any, Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.experimental.layout import Format, Layout
 
 from tensor2robot_tpu.modes import ModeKeys
 from tensor2robot_tpu.observability import device as device_lib
@@ -56,13 +53,9 @@ from tensor2robot_tpu.train.train_state import (TrainState,
                                                 init_grad_accumulators)
 
 Batch = Tuple[Any, Any]
-# What the train loop's place() emits and the prefetch queue carries:
-# (placed (features, labels), use_auto_layout_executable).
-PlacedBatch = Tuple[Batch, bool]
-# What place() returns (``_place_batch``): the batch as plain copies, and
-# the second half that re-lays those out (copies -> the batch as the step
-# takes it), or None where the copies are already that.
-Placement = Tuple[PlacedBatch, Optional[Callable[[PlacedBatch], PlacedBatch]]]
+# What the train loop's place() returns and the prefetch queue carries:
+# (features, labels) on the device, as host-to-device copies.
+PlacedBatch = Batch
 MetricDict = Dict[str, float]
 
 
@@ -113,8 +106,8 @@ def _record_restart_to_first_step() -> None:
 # in-flight dispatch drain → forced checkpoint → scheduler restart →
 # python/jax startup → restore → first post-restore dispatch, the number
 # an operator's preemption budget actually pays. Measured across a REAL
-# subprocess restart by tests/test_collect_loop.py; `loop_restart.json`
-# persists the measurement for bench.py's `loop_restart_seconds` line.
+# subprocess restart by tests/test_collect_loop.py, which reads the
+# measurement back from `loop_restart.json`.
 PREEMPT_STATE_FILENAME = 'preempt_state.json'
 LOOP_RESTART_FILENAME = 'loop_restart.json'
 
@@ -143,7 +136,7 @@ def _record_sigterm_to_resumed(model_dir: str, step: int) -> None:
 
   A no-op unless a preemption left its receipt mark; the mark is
   CONSUMED (one measurement per preemption) and the result persisted to
-  ``loop_restart.json`` for bench/test readers.
+  ``loop_restart.json`` for the test that reads it.
   """
   if not model_dir:
     return
@@ -177,56 +170,39 @@ def _record_sigterm_to_resumed(model_dir: str, step: int) -> None:
     logging.warning('Cannot persist loop-restart measurement: %r', e)
 
 
-def _place_batch(place: Callable[[Batch], 'Placement'],
+def _place_batch(place: Callable[[Batch], 'PlacedBatch'],
                  release: Optional[Callable[[], None]],
                  batch: Batch, key: int,
                  wait_transfer: bool = False) -> 'PlacedBatch':
-  """Places batch ``key`` in two halves, waits for the first where that
-  is this thread's to wait for, and returns its ring-buffer lease
+  """Places batch ``key``, waits for the copy where that is this
+  thread's to wait for, and returns its ring-buffer lease
   (data/engine.py) if it holds one.
 
-  ``place(batch)`` is the first half, the COPY: it returns the batch on
-  the device as plain host-to-device copies (no program on the device)
-  and, where leaves are still to be re-laid out into the layout the
-  executable asked for, ``relayout``: ``relayout(copied)`` is the second
-  half and returns the batch as the step takes it. ``None`` where there
-  is no second half (default layouts).
+  ``place(batch)`` returns the batch on the device as plain
+  host-to-device copies (``device_put`` / ``shard_batch`` with the batch
+  shardings): no program runs on the device, so a copy is ready when its
+  bytes have arrived, whatever step the device is running (PERF.md, PR
+  25 and 26: a placement that waited on the compute queue ran the feed
+  in series with the step).
 
   The one placement routine of every path (placement stage, consumer
-  thread, no prefetch), so each path leaves the same spans, keyed by
-  the batch: ``trainer/place_stage`` round the whole of it and, inside,
-  ``trainer/place/put`` round the ``place`` call (layout choice and the
-  ``device_put`` / ``shard_batch`` call: host staging, returns before
-  the bytes have moved), ``trainer/place/transfer`` round the block on
-  the copies (transfer completion only, never compute: a copy is ready
-  when its bytes have arrived, whatever the device is running) and
-  ``trainer/place/relayout`` round the enqueue of the second half.
+  thread, no prefetch), so each leaves the same spans under the batch's
+  key: ``trainer/place_stage`` round the whole and, inside it,
+  ``trainer/place/put`` round the ``place`` call (host staging, returns
+  before the bytes have moved) and ``trainer/place/transfer`` round the
+  block on the copies.
 
-  Who blocks, and on what: on the COPIES only, never on the re-layout's
-  outputs. Those are programs on the device's compute queue, behind the
-  step that is running: a thread that waited for them would hand batch
-  n on only when step n-1 had ended, and the feed would run in series
-  with the step (PERF.md, PR 25 and 26). The placement stage always
-  blocks (``wait_transfer``: it runs off the loop thread, and a queue
-  depth >= 2 keeps a placed batch ahead), so that the moment a batch's
-  bytes are on the device is on record; any thread with a re-layout to
-  enqueue blocks, so that the program reads an array that is already
-  there and never sits in the queue waiting for its copy, holding back
-  the step enqueued after it; any thread that holds a lease blocks,
-  because the release point depends on what placement actually does
-  with the host bytes:
+  Who blocks: the placement stage always (``wait_transfer``: it runs
+  off the loop thread with a placed batch queued ahead), so that the
+  moment a batch's bytes are on the device is on record; and any thread
+  that holds a lease, whose release point depends on the backend:
 
-  * Accelerator backends: ``device_put`` COPIES to device memory, so
-    place, block on the copies, then release: the host bytes are not
-    read again (a re-layout that fails its check falls back to the
-    copies, not to the host batch). This is the ROADMAP PR-3
-    follow-up's transfer-completion release point.
-  * XLA-CPU: ``device_put`` may ZERO-COPY alias the host numpy buffer —
-    "transfer completion" never copies, and releasing would let the
-    engine overwrite the live batch under the step (observed as
-    corrupted training). Take an explicit host copy of the ring views,
-    release, then place the copy — exactly the copy ``np.stack`` paid
-    before ring buffers existed.
+  * Accelerators: ``device_put`` COPIES to device memory, so place,
+    block on the copies, then release: the host bytes are not read again.
+  * XLA-CPU: ``device_put`` may ZERO-COPY alias the host numpy buffer,
+    and releasing would let the engine overwrite the live batch under
+    the step (observed as corrupted training). Take an explicit host
+    copy of the ring views, release, then place the copy.
   """
   clock = time.perf_counter_ns
   t_start = clock()
@@ -236,18 +212,14 @@ def _place_batch(place: Callable[[Batch], 'Placement'],
     release()
     release = None
   t_put = clock()
-  placed, relayout = place(batch)
+  placed = place(batch)
   t_placed = clock()
   tracing.record('trainer/place/put', t_put, t_placed, key)
-  if wait_transfer or release is not None or relayout is not None:
-    jax.block_until_ready(placed[0])
+  if wait_transfer or release is not None:
+    jax.block_until_ready(placed)
     tracing.record('trainer/place/transfer', t_placed, clock(), key)
   if release is not None:
     release()
-  if relayout is not None:
-    t_relayout = clock()
-    placed = relayout(placed)
-    tracing.record('trainer/place/relayout', t_relayout, clock(), key)
   tracing.record('trainer/place_stage', t_start, clock(), key)
   return placed
 
@@ -310,14 +282,6 @@ class TrainerConfig:
   # host, the worker thread CONTENDS with dispatch instead of
   # overlapping it (record-fed grasp2vec: 297 → 663 ms/step median).
   prefetch_batches: Optional[int] = None
-  # Compiler-chosen input layouts for the BATCH arguments: the train
-  # step is additionally lowered with AUTO layouts and batches are
-  # placed in the layout the executable prefers, so XLA never inserts
-  # a re-layout copy at the parameter boundary (the WTL episode batch
-  # paid 2×0.9 ms/step re-laying 507 MB of uint8 input). None = auto:
-  # on for TPU backends, off elsewhere and for multi-host feeding
-  # (the process-local assembly path has no layout control).
-  auto_input_layouts: Optional[bool] = None
   # Non-finite update guard (train/resilience.py). 'off' compiles the
   # historical step (bitwise status quo). 'skip_update' / 'raise' fold a
   # device-side all_finite(loss, grads) check into the jitted step and
@@ -361,20 +325,9 @@ class TrainerConfig:
   # the default feed (same executable on CPU; pinned by
   # tests/test_device_feed.py). Ignored (off) when the mesh spans
   # processes — multi-host feeding assembles per-process shards, which
-  # has no single-put form. Default OFF until BENCH_r06 measures it,
-  # per the round-2 honesty rule.
+  # has no single-put form. Default OFF: no cell of the benchmark has
+  # measured it on the chip (PERF.md §7).
   device_feed: bool = False
-  # Fused optimizer/EMA/guard update (ops/fused_update.py): run the
-  # entire Adam/SGD + EMA + nonfinite-select chain as ONE elementwise
-  # Pallas pass over flattened parameter blocks — each param leaf read
-  # once, written once, instead of XLA's multi-pass op soup. Takes
-  # effect only when the kernel-dispatch gate is on (TPU, or the test
-  # force) AND the model's optimizer is a tagged factory from
-  # models/optimizers.py with a recognized opt-state structure; in
-  # every other case the stock optax path runs, bit for bit. The fused
-  # pass itself is accepted by a documented parity band vs optax
-  # (tests/test_device_feed.py). Default OFF until BENCH_r06.
-  fused_update: bool = False
   # Microbatch gradient accumulation (GPipe-style): the jitted step runs
   # a lax.scan over M slices of the host batch — [B, ...] reshaped to
   # [M, B/M, ...] — accumulating gradients in donated float32 carries,
@@ -431,8 +384,7 @@ class TrainerConfig:
   # jitted step runs on a daemon thread (a disk read when the
   # persistent compilation cache is enabled).
   program_ledger: bool = True
-  # When the auto-layout build did not already record 'train/step', the
-  # AOT harvest of the jitted step is a REAL second backend compile
+  # The AOT harvest of the jitted step is a REAL second backend compile
   # whose tracing contends (GIL) with the dispatch loop. Deferring it
   # keeps short runs and benchmarks unpolluted — the timer is cancelled
   # if the loop ends first (a post-run harvest serves no live gauge),
@@ -522,13 +474,6 @@ class TrainerConfig:
           f'got {self.checkpoint_sharded_payloads!r}')
     return mesh is not None and mesh_lib.mesh_spans_processes(mesh)
 
-  def resolved_auto_input_layouts(self) -> bool:
-    if jax.process_count() > 1:
-      return False
-    if self.auto_input_layouts is not None:
-      return self.auto_input_layouts
-    return jax.default_backend() == 'tpu'
-
   def resolved_prefetch_batches(self) -> int:
     if self.prefetch_batches is not None:
       return self.prefetch_batches
@@ -553,15 +498,14 @@ class _DevicePrefetcher:
     host batches from ``it`` (with the parallel input engine upstream
     this is mostly dequeueing — the engine's own workers do the decode),
     and a DEDICATED placement worker applies ``place`` (the H2D copy of
-    ``shard_batch``, then the auto-layout re-layout), so the decode of
-    batch N+2, the placement of N+1 and the device step of N all overlap
-    across batches instead of serializing behind one thread. They do
-    overlap because the placement worker waits for the batch's COPY
-    alone (``_place_batch``): the re-layout programs run on the device's
-    compute queue behind the running step, so they are enqueued and not
-    waited for, and the batch is handed on while step N still runs. The
-    loop then finds batch N+1 waiting, enqueues step N+1 a whole step
-    early and blocks in ``trainer/device_wait``, as it was written to.
+    ``shard_batch``), so the decode of batch N+2, the placement of N+1
+    and the device step of N all overlap across batches instead of
+    serializing behind one thread. They do overlap because a placement
+    is a copy and nothing on the device's compute queue
+    (``_place_batch``): the batch is handed on when its bytes have
+    arrived, while step N still runs. The loop then finds batch N+1
+    waiting, enqueues step N+1 a whole step early and blocks in
+    ``trainer/device_wait``, as it was written to.
   * On the forced-host CPU platform placement happens on the consumer
     thread and a single fetch worker is the only stage — XLA CPU runs an
     N-device mesh's collectives as N in-process threads, and a
@@ -575,7 +519,7 @@ class _DevicePrefetcher:
   _DONE = object()
 
   def __init__(self, it: Iterator[Batch],
-               place: Callable[[Batch], 'Placement'], depth: int,
+               place: Callable[[Batch], 'PlacedBatch'], depth: int,
                place_stage: Optional[bool] = None,
                release: Optional[Callable[[], None]] = None):
     import queue
@@ -586,12 +530,10 @@ class _DevicePrefetcher:
     self._err: Optional[BaseException] = None
     self._stop = threading.Event()
     # Ring-buffer lease release (data/engine.py reuse_buffers): called
-    # once per batch AFTER its H2D transfer completes, so the engine may
-    # recycle the host buffers the batch's arrays were views of. The
-    # placement stage is the transfer-completion point this closes the
-    # ROADMAP PR-3 follow-up with: place() → block on the copies →
-    # release() → enqueue the re-layout — all on the place/consumer
-    # thread, off the dispatch critical path.
+    # once per batch AFTER its H2D transfer completes (``_place_batch``:
+    # place() → block on the copies → release(), on the place/consumer
+    # thread, off the dispatch critical path), so the engine may recycle
+    # the host buffers the batch's arrays were views of.
     self._release = release
     # Queue telemetry: a depth gauge pinned near 0 plus a climbing
     # starvation counter is the registry's signature of an input-bound
@@ -794,7 +736,7 @@ class _SuperbatchAssembler:
     self._ring_sig = None
     # FIFO of outstanding superbatch leases: ring slot index, or None
     # for fresh buffers (whose release is a no-op entry).
-    self._leases = collections.deque()
+    self._leases = collections.deque()  # GUARDED_BY(self._lease_lock)
     self._lease_lock = threading.Lock()
     self._gen = self._generate()
 
@@ -888,29 +830,6 @@ def _grouped_batches(it: Iterator[Batch], k: int, start_step: int,
   intermediate ``np.stack`` list-of-views copy.
   """
   return _SuperbatchAssembler(it, k, start_step, max_steps, release=release)
-
-
-# Compiler-chosen (AUTO) input layouts are asked for only where the
-# re-layout copy they save would cost something: the 251 MB QT-Opt image
-# superbatch, the 507 MB WTL episode batch. A few-KB action or reward
-# leaf keeps the default layout and a plain transfer — a custom layout
-# saves it nothing and costs a jitted identity program per leaf.
-_AUTO_LAYOUT_MIN_BYTES = 1 << 20
-
-
-def _placed_as_asked(placed, targets) -> bool:
-  """Whether every leaf placed with a ``Format`` has that layout.
-
-  Not a formality: on the TPU v5e (jax 0.9.0, libtpu 0.0.34) the jitted
-  identity that ``jax.device_put(x, Format)`` runs, when it came back
-  from the persistent compilation cache, returned an f32[8,32,3] leaf in
-  another layout than the one it was compiled for (and still claimed),
-  and the dispatch then refused the batch (PR 21, CHANGES.md).
-  """
-  return all(
-      not isinstance(target, Format) or leaf.format.layout == target.layout
-      for leaf, target in zip(jax.tree_util.tree_leaves(placed),
-                              jax.tree_util.tree_leaves(targets)))
 
 
 def _mean_metrics(metric_batches: List[MetricDict]) -> MetricDict:
@@ -1135,18 +1054,11 @@ class Trainer:
     self._state: Optional[TrainState] = None
     self._train_step_fn = None
     self._eval_step_fn = None
-    # Auto (compiler-chosen) input-layout executable; built lazily from
-    # the first host batch's avals (see _maybe_build_auto_step).
-    self._auto_step = None  # GUARDED_BY(self._auto_build_lock)
-    self._batch_formats = None  # GUARDED_BY(self._auto_build_lock)
-    self._auto_batch_avals = None  # GUARDED_BY(self._auto_build_lock)
-    self._auto_disabled = not config.resolved_auto_input_layouts()  # GUARDED_BY(self._auto_build_lock)
-    self._auto_build_lock = threading.Lock()
     # Whether 'train/step' landed in the program ledger (set by the
-    # auto-step build or the off-thread jitted-step harvest, whichever
-    # compiles the dispatched program). Plain bool, single-writer-ish:
-    # a racing reader at worst harvests a duplicate record of the SAME
-    # program, which the ledger de-duplicates by fingerprint.
+    # off-thread harvest of the jitted step). Plain bool,
+    # single-writer-ish: a racing reader at worst harvests a duplicate
+    # record of the SAME program, which the ledger de-duplicates by
+    # fingerprint.
     self._program_recorded = False
     # Step the current dispatch started from; callbacks use crossed() so
     # their interval semantics survive steps_per_dispatch > 1.
@@ -1265,20 +1177,6 @@ class Trainer:
     decay = model.avg_model_params_decay
     guard_nonfinite = self._config.nonfinite_mode != 'off'
     accum_m = self._accum_m
-    # Fused optimizer/EMA/guard update (ops/fused_update.py): decided
-    # at BUILD time — the kernel gate and the optimizer tag are python
-    # facts, so the branch bakes into the traced program. None keeps
-    # the stock optax path bit for bit.
-    fused_plan = None
-    fused_lib = None
-    if self._config.fused_update:
-      from tensor2robot_tpu.ops import fused_update as fused_lib
-
-      # plan_for logs the fallback reason itself when it returns None
-      # (kernel gate off, untagged optimizer, unrecognized opt state).
-      fused_plan = fused_lib.plan_for(
-          optimizer, ema_decay=decay,
-          opt_state=None if self._state is None else self._state.opt_state)
 
     def all_finite(loss, grads):
       # Device-side guard flag: ok == all_finite(loss, grads). With
@@ -1349,32 +1247,6 @@ class Trainer:
         scalars = jax.tree_util.tree_map(
             lambda s: jnp.mean(jnp.asarray(s).astype(jnp.float32), axis=0),
             scalars_m)
-      if fused_plan is not None:
-        # One elementwise Pallas pass over every parameter leaf runs
-        # moments + update + apply + EMA + the guard's old-vs-new
-        # select; opt-state counts select outside (scalars). The
-        # remaining replaced leaves (step, model_state) select below;
-        # rng is kept by reference, exactly like the stock path.
-        ok = all_finite(loss, grads) if guard_nonfinite else None
-        new_params, new_opt_state, new_ema = fused_lib.apply_update(
-            fused_plan, state.params, grads, state.opt_state,
-            state.ema_params, ok=ok)
-        new_state = state.replace(
-            step=state.step + 1,
-            params=new_params,
-            model_state=new_model_state,
-            opt_state=new_opt_state,
-            ema_params=new_ema)
-        scalars = dict(scalars)
-        scalars['loss'] = loss
-        if guard_nonfinite:
-          new_state = new_state.replace(
-              step=jnp.where(ok, new_state.step, state.step),
-              model_state=jax.tree_util.tree_map(
-                  lambda n, o: jnp.where(ok, n, o),
-                  new_model_state, state.model_state))
-          scalars['nonfinite_count'] = jnp.where(ok, 0, 1).astype(jnp.int32)
-        return new_state, scalars
       updates, new_opt_state = optimizer.update(
           grads, state.opt_state, state.params)
       new_params = optax.apply_updates(state.params, updates)
@@ -1485,8 +1357,8 @@ class Trainer:
     loop setup, outside any measured dispatch) by
     ``program_harvest_delay_seconds``, or on an immediate daemon thread
     at delay 0. Bails when the loop already ended (``loop_live_fn``),
-    when another path recorded the program (the auto-layout build), or
-    when the first dispatch never filled ``cell``.
+    when an earlier loop of this trainer recorded the program, or when
+    the first dispatch never filled ``cell``.
     """
     step_fn = self._train_step_fn
 
@@ -1518,116 +1390,6 @@ class Trainer:
     """
     return programs_lib.utilization_scalars(
         'train/step', n_steps, device_seconds, scope='train')
-
-  def _maybe_build_auto_step(self, features, labels) -> bool:
-    """Compiles the train step with compiler-chosen (AUTO) batch layouts.
-
-    ``features``/``labels`` are a HOST batch (avals only). On success
-    the train loop dispatches ``self._auto_step`` and ``place`` uses
-    ``self._batch_formats``; any failure (backend without layout
-    support, exotic batch leaves) permanently falls back to the default
-    jitted step, with a WARNING and ``trainer/auto_input_layouts`` = 0.
-    Thread-safe: the prefetcher's worker may be the first caller.
-    """
-    # Double-checked fast path: both fields are written exactly once,
-    # under the build lock; a racing reader that sees a stale None just
-    # falls through to the locked re-check below.
-    if self._auto_disabled or self._state is None:  # ANALYSIS_OK(lock-discipline): double-checked fast path; locked re-check follows
-      return False
-    if self._auto_step is not None:  # ANALYSIS_OK(lock-discipline): published-once ref; locked re-check follows
-      return True
-    with self._auto_build_lock:
-      if self._auto_disabled:
-        return False
-      if self._auto_step is not None:
-        return True
-      try:
-        state_sharding = self._state_sharding()
-        sharding = self._loop_batch_sharding()
-        auto = Format(Layout.AUTO, sharding)
-        asked = jax.tree_util.tree_map(
-            lambda x: (auto if np.asarray(x).nbytes >= _AUTO_LAYOUT_MIN_BYTES
-                       else sharding), (features, labels))
-        jitted = jax.jit(
-            self._loop_step_body(),
-            in_shardings=(state_sharding,) + asked,
-            out_shardings=(state_sharding, None),
-            donate_argnums=self._donate_argnums())
-        t_compile0 = time.perf_counter()
-        with warnings.catch_warnings(record=True) as caught:
-          warnings.simplefilter('always')
-          lowered = jitted.lower(self._state, features, labels)
-          compiled = lowered.compile()
-        compile_seconds = time.perf_counter() - t_compile0
-        (state_fmt, feat_fmt, label_fmt), _ = compiled.input_formats
-        leaves, treedef = jax.tree_util.tree_flatten((features, labels))
-        self._auto_batch_avals = (
-            treedef, [(tuple(np.shape(x)), np.result_type(x))
-                      for x in leaves])
-        # The executable's expected STATE layouts must match how the
-        # state is actually placed (state keeps its concrete sharding;
-        # only batches are AUTO) — a mismatch would error mid-train, so
-        # verify statically and fall back instead.
-        placed = [getattr(leaf, 'format', None)
-                  for leaf in jax.tree_util.tree_leaves(self._state)]
-        expected = list(jax.tree_util.tree_leaves(state_fmt))
-        if len(placed) != len(expected) or any(
-            p is not None and p != e for p, e in zip(placed, expected)):
-          raise ValueError('state layout mismatch vs compiled step')
-        # place() targets: the executable's own format where the layout
-        # was its choice, the plain sharding (default layout, a plain
-        # transfer) everywhere else.
-        self._batch_formats = jax.tree_util.tree_map(
-            lambda want, fmt: fmt if isinstance(want, Format) else want,
-            asked, (feat_fmt, label_fmt))
-        self._auto_step = compiled
-        metrics_lib.gauge('trainer/auto_input_layouts').set(1.0)
-        if self._config.program_ledger:
-          # This executable IS the program driving steady-state
-          # dispatches, so it owns the 'train/step' ledger entry (the
-          # off-thread jitted-step harvest is skipped — see the
-          # _program_recorded check in _train_loop).
-          self._program_recorded = True
-          programs_lib.record_compiled(
-              'train/step', compiled, lowered=lowered,
-              compile_seconds=compile_seconds,
-              donate_argnums=self._donate_argnums(),
-              donated_params=len(jax.tree_util.tree_leaves(self._state)),
-              captured_warnings=[
-                  str(w.message) for w in caught
-                  if 'donat' in str(w.message).lower()],
-              source='trainer/auto_step',
-              steps_per_execution=self._loop_k)
-        return True
-      except Exception as e:  # pylint: disable=broad-except
-        self._give_up_auto_layouts(f'could not be built: {e!r}')
-        return False
-
-  def _give_up_auto_layouts(self, why: str) -> None:  # HOLDS(self._auto_build_lock)
-    """The run goes on with default layouts, and says so: the gauge is
-    what chip_smoke.py reads to refuse a degraded chip run."""
-    self._auto_disabled = True
-    metrics_lib.gauge('trainer/auto_input_layouts').set(0.0)
-    logging.warning(
-        'Auto input layouts were asked for and %s; the step runs with '
-        'default layouts from here on.', why)
-
-  def _batch_matches_auto(self, batch: Batch) -> bool:
-    """Whether a batch has the avals the AOT auto-layout step expects.
-
-    The compiled executable is shape-specialized; an off-shape batch
-    (e.g. a ragged final batch from an external iterator) must fall
-    back to the jitted step, which retraces transparently.
-    """
-    # ANALYSIS_OK(lock-discipline): immutable tuple once published under
-    # the build lock; a stale None here means "fall back to jitted".
-    if self._auto_batch_avals is None:
-      return False
-    treedef, avals = self._auto_batch_avals  # ANALYSIS_OK(lock-discipline): published-once immutable tuple
-    leaves, td = jax.tree_util.tree_flatten(batch)
-    return td == treedef and all(
-        tuple(np.shape(x)) == shape and np.result_type(x) == dtype
-        for x, (shape, dtype) in zip(leaves, avals))
 
   def _build_eval_step(self):
     model = self._model
@@ -1820,42 +1582,29 @@ class Trainer:
     feed_sharding = self._loop_batch_sharding() if device_feed else None
     # One increment per device-feed placement call: with the dispatch
     # counter, the registry pins "exactly ONE device_put and ONE
-    # dispatch per K steps" (tests/test_device_feed.py; bench.py's
-    # h2d_dispatches_per_step line).
+    # dispatch per K steps" (tests/test_device_feed.py).
     h2d_puts = metrics_lib.counter('trainer/h2d/device_puts')
     # Bytes handed to the put, on every placement path: over the time in
     # ``trainer/place/transfer`` (which ends when the copy does) they
     # give the H2D rate.
     h2d_bytes = metrics_lib.counter('trainer/h2d/bytes')
-    # Leaves handed to the second half of a placement, one a ``Format``
-    # in the target: 0 a batch on the default-layout path. A re-layout
-    # program each, unless the copy already has the format (jax then
-    # hands the copy back).
-    relayout_leaves = metrics_lib.counter('trainer/place/relayout_leaves')
 
-    def put(batch: Batch, formats):
-      """The copy: every leaf with its plain sharding (a ``Format``'s
-      own), so that nothing here reaches the device's compute queue."""
+    def place(batch: Batch) -> PlacedBatch:
+      """The copy: every leaf with its batch sharding, so that nothing
+      here reaches the device's compute queue."""
+      t0 = time.perf_counter()
       h2d_bytes.inc(sum(getattr(leaf, 'nbytes', 0)
                         for leaf in jax.tree_util.tree_leaves(batch)))
-      shardings = mesh_lib.copy_shardings(formats)  # None stays None
       if device_feed:
-        # Device feed: the whole (features, labels) group moves in ONE
-        # device_put call — one H2D burst per dispatch — instead of
-        # shard_batch's per-leaf puts. The target is the sharding tree
-        # of the executable's preferred formats when the auto build
-        # landed, else the loop sharding replicated over the batch's
-        # structure.
-        if shardings is None:
-          shardings = jax.tree_util.tree_map(lambda _: feed_sharding, batch)
+        # The whole (features, labels) group moves in ONE device_put
+        # call (one H2D burst per dispatch, not shard_batch's per-leaf
+        # puts), with the loop sharding replicated over its structure.
         h2d_puts.inc()
-        return jax.device_put(batch, shardings)
-      return mesh_lib.shard_batch(
-          batch, self._mesh, shardings, stacked=self._loop_k > 1)
-
-    def account(t0: float) -> None:
-      # Host time of one half of a placement, to whichever side of the
-      # breakdown the calling thread is on.
+        placed = jax.device_put(
+            batch, jax.tree_util.tree_map(lambda _: feed_sharding, batch))
+      else:
+        placed = mesh_lib.shard_batch(
+            batch, self._mesh, stacked=self._loop_k > 1)
       place_ms = (time.perf_counter() - t0) * 1e3
       if threading.get_ident() == loop_ident:
         # Critical-path placement: carved out of host_wait in the
@@ -1866,54 +1615,7 @@ class Trainer:
         # Prefetch-worker placement overlaps the device step: real H2D
         # cost, but not on the dispatch critical path.
         overlap_place_hist.observe(place_ms)
-
-    def relayout(formats, leaves: int, copied: PlacedBatch) -> PlacedBatch:
-      # The second half, once the copies are on the device: the leaves
-      # with a Format go into the layout the executable was compiled
-      # for. Enqueued, not waited for (``_place_batch``).
-      t0 = time.perf_counter()
-      placed = mesh_lib.relayout_batch(copied[0], formats)
-      relayout_leaves.inc(leaves)
-      use_auto = _placed_as_asked(placed, formats)
-      if not use_auto:
-        # jax handed back another layout than the one asked for:
-        # dispatching that into the layout-specialized executable is a
-        # runtime error. Give the executable up, loudly; this batch goes
-        # on as its copies, which are the default placement (its host
-        # bytes may be gone: the lease is back), and every later one is
-        # placed the default way.
-        with self._auto_build_lock:
-          self._give_up_auto_layouts(
-              'a placed batch came back in another layout than the '
-              'executable was compiled for')
-        placed = copied[0]
-      account(t0)
-      return placed, use_auto
-
-    def place(batch: Batch) -> Placement:
-      # First placement builds the auto-layout executable from this
-      # batch's avals, so every batch (including this one) lands in the
-      # layout the step prefers — no re-layout copy inside the step.
-      # Off-shape batches (ragged tails) place default and the loop
-      # dispatches the jitted step for them. The auto decision travels
-      # WITH the placed batch: dispatching a default-layout batch into
-      # the layout-specialized executable would be a runtime error, so
-      # the choice is made exactly once, here and in ``relayout``.
-      t0 = time.perf_counter()
-      use_auto = (self._maybe_build_auto_step(batch[0], batch[1]) and
-                  self._batch_matches_auto(batch))
-      # ANALYSIS_OK(lock-discipline): use_auto=True implies the build
-      # lock published _batch_formats before _maybe_build_auto_step
-      # returned (happens-before via the lock release).
-      formats = self._batch_formats if use_auto else None
-      copied = put(batch, formats)
-      account(t0)
-      leaves = sum(isinstance(f, Format)
-                   for f in jax.tree_util.tree_leaves(formats))
-      if not leaves:
-        # Default layouts throughout: the copies are the placed batch.
-        return (copied, use_auto), None
-      return (copied, False), functools.partial(relayout, formats, leaves)
+      return placed
 
     if first_batch is not None:
       train_iter = itertools.chain([first_batch], train_iter)
@@ -2059,15 +1761,12 @@ class Trainer:
           tracing.record('trainer/after_dispatch', t_tail, t_wait0, key - 1)
           t_tail = None
         try:
-          (features, labels), use_auto = next(batches)
+          features, labels = next(batches)
         finally:  # an ended stream leaves here: its wait is on record
           t_wait1 = clock()
           tracing.record('trainer/wait_batch', t_wait0, t_wait1, key)
-        # ANALYSIS_OK(lock-discipline): published-once executable; the
-        # use_auto flag travelled with the batch from under the lock.
-        step_fn = (self._auto_step if use_auto and self._auto_step is not None
-                   else self._train_step_fn)
-        self._state, scalars = step_fn(self._state, features, labels)
+        self._state, scalars = self._train_step_fn(
+            self._state, features, labels)
         t_disp = t_boundary = clock()
         tracing.record('trainer/dispatch', t_wait1, t_disp, key)
         if breakdown.enabled and prev_out is not None:
@@ -2107,8 +1806,6 @@ class Trainer:
             else (batch_leaves[0].shape[0] if batch_leaves else 0))
         if program_harvest_pending:
           # First dispatch done: the program (and its avals) are final.
-          # If the auto-layout build already recorded 'train/step', the
-          # AOT harvest of the jitted twin would be a duplicate.
           program_harvest_pending = False
           if not self._program_recorded:
             self._capture_program_avals(

@@ -45,23 +45,3 @@ def forced_place_stage(monkeypatch):
       super().__init__(it, place, depth, place_stage=True, **kwargs)
 
   monkeypatch.setattr(trainer_mod, '_DevicePrefetcher', ForcedPlaceStage)
-
-
-@pytest.fixture
-def column_major_inputs(monkeypatch):
-  """Makes the compiler-chosen input layout of the mock model's one
-  rank-2 batch leaf (``measured_position``, 64 bytes at batch 8) COLUMN
-  major, where XLA-CPU's own choice is the default layout: a run with
-  ``auto_input_layouts=True`` then compiles a step that refuses the
-  default placement, so the second half of the placement (the re-layout
-  on the device) really runs, as it does on the TPU. K = 1 only: the
-  layout is of rank 2."""
-  from jax.experimental.layout import Layout
-
-  import tensor2robot_tpu.train.trainer as trainer_mod
-
-  class ColumnMajor:
-    AUTO = Layout(major_to_minor=(1, 0), tiling=())
-
-  monkeypatch.setattr(trainer_mod, 'Layout', ColumnMajor)
-  monkeypatch.setattr(trainer_mod, '_AUTO_LAYOUT_MIN_BYTES', 64)

@@ -22,12 +22,10 @@ import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
-from tensor2robot_tpu.models import optimizers
 from tensor2robot_tpu.observability import metrics
 from tensor2robot_tpu.ops import _pallas_dispatch as dispatch
-from tensor2robot_tpu.ops import (conv_s2d, flash_attention, fused_update,
-                                  photometric, pool)
-from tensor2robot_tpu.research.qtopt import optimizer_builder
+from tensor2robot_tpu.ops import (conv_s2d, flash_attention, photometric,
+                                  pool)
 
 pytestmark = pytest.mark.kernels
 
@@ -36,9 +34,6 @@ IMAGE = (32, 472, 472, 3)
 CONV1_W = (6, 6, 3, 64)
 POOL1 = (32, 236, 236, 64)  # after conv1 (6x6 stride 2)
 POOL2 = (32, 79, 79, 64)  # after pool1 (3x3 stride 3 SAME)
-# Every distinct parameter shape of GraspingModelWrapper().
-PARAM_SHAPES = ((64,), (3, 3, 64, 64), (5, 5, 64, 64), (256,), (6, 6, 3, 64),
-                (4096, 64), (64, 64), (5, 256), (256, 64), (1,), (64, 1))
 
 
 @pytest.fixture(scope='module')
@@ -140,30 +135,6 @@ def _conv_s2d_refused(chip, caplog):
       chip(IMAGE, jnp.bfloat16), chip(CONV1_W, jnp.bfloat16))
 
 
-def _fused_update(chip, caplog):
-  params = {str(i): chip(shape, jnp.float32)
-            for i, shape in enumerate(PARAM_SHAPES)}
-  scalar = chip((), jnp.bool_)
-  with dispatch.force_kernels(True):
-    adam = optimizers.create_adam_optimizer(1e-4)
-    opt_state = jax.tree_util.tree_map(
-        lambda x: chip(x.shape, x.dtype), jax.eval_shape(adam.init, params))
-    plan = fused_update.plan_for(adam, ema_decay=0.9999, opt_state=opt_state)
-    assert plan is not None
-    assert _custom_calls(
-        lambda p, g, s, e, ok: fused_update.apply_update(
-            plan, p, g, s, e, ok=ok),
-        params, params, opt_state, params, scalar) == len(PARAM_SHAPES)
-    # QT-Opt's own optimizer (momentum, untagged) cannot be fused: the
-    # explicit request is refused loudly, not dropped.
-    before = metrics.counter('kernels/refused').value
-    with caplog.at_level(logging.WARNING):
-      qtopt = optimizer_builder.build_opt(optimizer_builder.default_hparams())
-      assert fused_update.plan_for(qtopt, ema_decay=0.9999) is None
-    assert metrics.counter('kernels/refused').value == before + 1
-    assert 'fused_update kernel was requested' in caplog.text
-
-
 def _flash_attention_window_grouped(chip, caplog):
   # The token policy's attention at its published widths: 8,192 tokens,
   # 32 query heads over 4 key/value heads of 128 in bfloat16, a
@@ -258,7 +229,7 @@ def _expert_layer_ladder(chip, caplog):
 
 @pytest.mark.parametrize('case', [
     _photometric, _flash_attention, _pool_qtopt_refused, _pool_small_lowers,
-    _conv_s2d_refused, _fused_update, _flash_attention_window_grouped,
+    _conv_s2d_refused, _flash_attention_window_grouped,
     _grouped_product_tiles, _expert_layer_ladder,
 ], ids=lambda fn: fn.__name__.lstrip('_'))
 def test_compiles_for_described_v5e(case, chip, caplog):

@@ -45,7 +45,6 @@ _STUB_TRAINER = textwrap.dedent('''
         'programs': {'train/step': {'custom_calls': 0}},
         'metrics': {'trainer/steps': steps, 'trainer/dispatches': steps // 8,
                     'trainer/examples': steps * 32,
-                    'trainer/auto_input_layouts': 1.0,
                     'trainer/prefetch/place_stage': 1.0,
                     'kernels/refused': 1 if mode == 'kernel_refused' else 0},
     })

@@ -1,6 +1,6 @@
-"""Device-resident multi-step (``device_feed``) + fused-update drills.
+"""Device-resident multi-step (``device_feed``) drills.
 
-The two ISSUE-18 knobs, pinned on the CPU backend:
+The knob, pinned on the CPU backend:
 
   (a) ``device_feed=True`` trains BITWISE identically to the
       K-individual-dispatch path over the (K, M) grid, including
@@ -12,12 +12,7 @@ The two ISSUE-18 knobs, pinned on the CPU backend:
       drew the bad batch;
   (c) a SIGTERM mid-dispatch checkpoints at the dispatch boundary and
       a fresh trainer resumes BIT-exactly against an uninterrupted run
-      fed the same stream;
-  (d) ``fused_update=True`` off-gate is bitwise identical to stock
-      optax; force-gated through the Pallas interpreter it matches
-      optax within the documented band (atol 1e-6 / rtol 1e-5, f32) on
-      the qtopt and grasp2vec mocks — EMA and lr-schedule legs
-      included.
+      fed the same stream.
 """
 
 import os
@@ -30,9 +25,8 @@ import pytest
 from tensor2robot_tpu.models import optimizers as opt_lib
 from tensor2robot_tpu.modes import ModeKeys
 from tensor2robot_tpu.observability import metrics as metrics_lib
-from tensor2robot_tpu.ops import _pallas_dispatch as dispatch
 from tensor2robot_tpu.preprocessors import NoOpPreprocessor
-from tensor2robot_tpu.specs import SpecStruct, make_random_numpy
+from tensor2robot_tpu.specs import SpecStruct
 from tensor2robot_tpu.train import (GracefulShutdown, PreemptedError, Trainer,
                                     TrainerConfig, latest_checkpoint_step)
 from tensor2robot_tpu.utils import faults
@@ -78,7 +72,6 @@ def make_trainer(model_dir='', callbacks=(), shutdown=None,
   model = MockT2RModel(device_type='tpu', create_optimizer_fn=fast_adam,
                        preprocessor_cls=preprocessor_cls)
   cfg.setdefault('prefetch_batches', 0)
-  cfg.setdefault('auto_input_layouts', False)
   config = TrainerConfig(
       model_dir=model_dir, eval_interval_steps=0, log_interval_steps=0, **cfg)
   return Trainer(model, config, callbacks=list(callbacks), shutdown=shutdown)
@@ -227,152 +220,3 @@ def test_sigterm_mid_dispatch_resumes_bit_exact(tmp_path):
   assert int(resumed.step) == 9
   assert_state_bitwise(reference.state, resumed.state)
 
-
-# --------------------------------------------- (d) fused-update parity
-
-
-def _qtopt_mock():
-  from tensor2robot_tpu.research.qtopt import GraspingModelWrapper
-
-  # Schedule adam + EMA (use_avg_model_params=True in the wrapper's
-  # hparams): covers the ScaleByScheduleState and EMA legs of the
-  # kernel alongside the moments.
-  return GraspingModelWrapper(
-      device_type='tpu',
-      input_shape=(96, 112, 3), target_shape=(80, 80), num_convs=(2, 2, 1),
-      create_optimizer_fn=lambda: opt_lib.create_adam_optimizer(
-          opt_lib.create_exp_decaying_learning_rate_fn(
-              1e-3, decay_steps=10, staircase=True)))
-
-
-def _grasp2vec_mock():
-  from tensor2robot_tpu.research.grasp2vec import Grasp2VecModel
-  from tensor2robot_tpu.research.grasp2vec.grasp2vec_model import (
-      Grasp2VecPreprocessor)
-
-  class TinyGrasp2Vec(Grasp2VecModel):
-    """472-crop defaults shrunk to 48 (test_memory_scaling idiom) so the
-    raw-jpeg-spec pipeline runs at mock scale. f32 towers
-    (device_type='cpu'): the parity band pins the UPDATE numerics, so it
-    runs where bf16 reduction-ordering noise cannot mask them."""
-
-    @property
-    def default_preprocessor_cls(self):
-
-      class TinyCrop(Grasp2VecPreprocessor):
-
-        def __init__(self, **kwargs):
-          super().__init__(scene_crop=(0, 40, 48, 0, 168, 48),
-                           goal_crop=(0, 40, 48, 0, 168, 48), **kwargs)
-
-      return TinyCrop
-
-  return TinyGrasp2Vec(device_type='cpu', scene_size=(48, 48),
-                       goal_size=(48, 48), resnet_size=18,
-                       create_optimizer_fn=fast_adam)
-
-
-def _train_fused(model_fn, fused, force, steps=2, batch_size=2):
-  model = model_fn()
-  preprocessor = model.preprocessor
-  feature_spec = preprocessor.get_in_feature_specification(ModeKeys.TRAIN)
-  label_spec = preprocessor.get_in_label_specification(ModeKeys.TRAIN)
-  batches = []
-  for seed in range(steps):
-    features = make_random_numpy(feature_spec, batch_size=batch_size,
-                                 seed=seed)
-    labels = (make_random_numpy(label_spec, batch_size=batch_size,
-                                seed=100 + seed)
-              if label_spec is not None and len(label_spec) else None)
-    batches.append((features, labels))
-  trainer = Trainer(model, TrainerConfig(
-      model_dir='', max_train_steps=steps, eval_interval_steps=0,
-      log_interval_steps=0, prefetch_batches=0, auto_input_layouts=False,
-      fused_update=fused))
-  with dispatch.force_kernels(force):
-    trainer.train(iter(batches), None)
-  return trainer.state
-
-
-def _assert_band(s_ref, s_fused, atol=1e-6, rtol=1e-5):
-  """The documented fused-vs-optax band: the kernel evaluates the same
-  f32 expressions but fused in one pass, so bitwise identity vs XLA's
-  fission of the stock graph is not guaranteed — closeness is."""
-  for ref, got in zip(
-      jax.tree_util.tree_leaves(jax.device_get(s_ref.params)),
-      jax.tree_util.tree_leaves(jax.device_get(s_fused.params))):
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               atol=atol, rtol=rtol)
-  assert (s_ref.ema_params is None) == (s_fused.ema_params is None)
-  if s_ref.ema_params is not None:
-    for ref, got in zip(
-        jax.tree_util.tree_leaves(jax.device_get(s_ref.ema_params)),
-        jax.tree_util.tree_leaves(jax.device_get(s_fused.ema_params))):
-      np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                 atol=atol, rtol=rtol)
-
-
-def test_fused_update_off_gate_is_bitwise_stock():
-  """Knob on but gate off (CPU, no force): the plan resolves to None
-  and training is the stock optax path, bit for bit."""
-  batches = make_batches(5)
-
-  def run(fused):
-    trainer = make_trainer(max_train_steps=5, fused_update=fused)
-    with dispatch.force_kernels(False):
-      trainer.train(iter(list(batches)), None)
-    return trainer.state
-
-  assert_state_bitwise(run(False), run(True))
-
-
-@pytest.mark.slow
-def test_fused_update_band_on_qtopt_mock():
-  """Force-gated interpret run on the qtopt mock (adam + lr schedule +
-  EMA): parity with stock optax within the documented band, schedule
-  count advanced, EMA leg exercised."""
-  import optax
-
-  def counts(state):
-    kinds = (optax.ScaleByAdamState, optax.ScaleByScheduleState)
-    found = [np.asarray(s.count) for s in jax.tree_util.tree_leaves(
-        jax.device_get(state.opt_state), is_leaf=lambda x: isinstance(x, kinds))
-             if isinstance(s, kinds)]
-    assert found  # schedule adam: both stateful counts must be present
-    return found
-
-  ref = _train_fused(_qtopt_mock, fused=False, force=False)
-  fused = _train_fused(_qtopt_mock, fused=True, force=True)
-  assert fused.ema_params is not None  # the EMA leg actually ran
-  _assert_band(ref, fused)
-  for a, b in zip(counts(ref), counts(fused)):
-    np.testing.assert_array_equal(a, b)
-
-
-@pytest.mark.slow
-def test_fused_update_band_on_grasp2vec_mock():
-  """Force-gated interpret run on the grasp2vec mock (default tagged
-  adam, no EMA): parity within the documented band (a real conv tower
-  through the interpret-mode kernel is a soak test — tier-1 covers the
-  fused path via the MockT2RModel band/off-gate/compose tests above)."""
-  ref = _train_fused(_grasp2vec_mock, fused=False, force=False)
-  fused = _train_fused(_grasp2vec_mock, fused=True, force=True)
-  _assert_band(ref, fused)
-
-
-def test_fused_update_composes_with_device_feed():
-  """Both knobs on (interpret kernel inside the K-step scan): still
-  bitwise against the stock K=1 path when the gate is off-TPU-forced
-  ONLY for the fused arm comparison, and within band when forced."""
-  batches = make_batches(6)
-
-  def run(feed, fused, force, k):
-    trainer = make_trainer(max_train_steps=6, steps_per_dispatch=k,
-                           device_feed=feed, fused_update=fused)
-    with dispatch.force_kernels(force):
-      trainer.train(iter(list(batches)), None)
-    return trainer.state
-
-  reference = run(False, False, False, 1)
-  both = run(True, True, True, 3)
-  _assert_band(reference, both)

@@ -228,10 +228,9 @@ class TestRingBuffers:
     # place() copies out of the ring slot (what shard_batch's device_put
     # does for real); the prefetcher then releases the lease.
     prefetcher = _DevicePrefetcher(
-        eng, place=lambda b: ((b.copy(), False), None), depth=2,
-        place_stage=True,
+        eng, place=lambda b: b.copy(), depth=2, place_stage=True,
         release=eng.release)
-    out = [placed for placed, _ in prefetcher]
+    out = list(prefetcher)
     prefetcher.close()
     eng.close()
     assert len(allocs) == 3  # exactly ring_depth buffers, ever
@@ -249,8 +248,7 @@ class TestRingBuffers:
         iter(_records(100)), _ring_parse(allocs), 5, num_workers=2,
         ring_depth=3, reuse_buffers=True)
     prefetcher = _DevicePrefetcher(
-        eng, place=lambda b: ((b.copy(), False), None), depth=2,
-        place_stage=False,
+        eng, place=lambda b: b.copy(), depth=2, place_stage=False,
         release=eng.release)
     out = list(prefetcher)
     prefetcher.close()
@@ -931,14 +929,12 @@ class TestPlacementStage:
 
     batches = [np.full((2,), i) for i in range(20)]
     prefetcher = _DevicePrefetcher(
-        iter(batches), lambda b: ((b * 10, False), None), depth=2,
-        place_stage=True)
+        iter(batches), lambda b: b * 10, depth=2, place_stage=True)
     out = [next(prefetcher) for _ in range(20)]
     with pytest.raises(StopIteration):
       next(prefetcher)
     prefetcher.close()
-    for i, (placed, use_auto) in enumerate(out):
-      assert use_auto is False
+    for i, placed in enumerate(out):
       np.testing.assert_array_equal(placed, np.full((2,), i) * 10)
 
   def test_place_stage_propagates_errors(self):
@@ -951,7 +947,7 @@ class TestPlacementStage:
         yield np.full((2,), i)
 
     prefetcher = _DevicePrefetcher(
-        broken(), lambda b: ((b, False), None), depth=2, place_stage=True)
+        broken(), lambda b: b, depth=2, place_stage=True)
     with pytest.raises(RuntimeError, match='reader died'):
       for _ in range(10):
         next(prefetcher)
@@ -963,8 +959,7 @@ class TestPlacementStage:
     from tensor2robot_tpu.train.trainer import _DevicePrefetcher
 
     prefetcher = _DevicePrefetcher(
-        iter(itertools.count()), lambda b: ((b, False), None), depth=1,
-        place_stage=True)
+        iter(itertools.count()), lambda b: b, depth=1, place_stage=True)
     next(iter(prefetcher))
     prefetcher.close()
     for thread in prefetcher._threads:  # pylint: disable=protected-access
@@ -974,17 +969,14 @@ class TestPlacementStage:
   @pytest.mark.parametrize('place_stage', [True, False])
   def test_hands_on_and_releases_at_copy_completion(self, monkeypatch,
                                                     place_stage):
-    """The accelerator order of a placement in two halves (the CPU
-    branch releases before it places, so the backend is a stand-in
-    here): batch n is handed on, and its lease returned exactly once,
-    when the first half's arrays (the copies) are ready and before the
-    second half's handle is. That handle stands for a re-layout program
-    queued behind the running step: nothing may wait for it."""
+    """The accelerator order of a placement (the CPU branch releases
+    before it places, so the backend is a stand-in here): batch n is
+    put, its lease returned exactly once when the copies are ready, and
+    only then handed on."""
     import tensor2robot_tpu.train.trainer as trainer_mod
 
     monkeypatch.setattr(trainer_mod.jax, 'default_backend', lambda: 'tpu')
     events = []
-    step_done = threading.Event()  # set: the re-layouts' outputs are ready
 
     class Copy:
 
@@ -995,56 +987,31 @@ class TestPlacementStage:
         events.append(('copy ready', self.n))
         return self
 
-    class Relaid:
-
-      def __init__(self, n):
-        self.n = n
-
-      def block_until_ready(self):
-        events.append(('waited for the re-layout', self.n))
-        assert step_done.wait(10)
-        return self
-
     def place(n):
       events.append(('put', n))
-
-      def relayout(copied):
-        (features, labels), use_auto = copied
-        assert isinstance(features, Copy) and not use_auto
-        events.append(('relayout', n))
-        return (Relaid(n), Relaid(n)), True
-
-      return ((Copy(n), Copy(n)), False), relayout
+      return Copy(n), Copy(n)
 
     prefetcher = trainer_mod._DevicePrefetcher(
         iter(range(3)), place, depth=1, place_stage=place_stage,
         release=lambda: events.append(('release',)))
     handed_on = []
     for n in range(3):
-      (features, labels), use_auto = next(prefetcher)
+      features, labels = next(prefetcher)
       handed_on.append(len(events))
-      assert not step_done.is_set()  # handed on all the same
-      assert isinstance(features, Relaid) and isinstance(labels, Relaid)
-      assert features.n == n and use_auto
+      assert isinstance(features, Copy) and isinstance(labels, Copy)
+      assert features.n == n
     with pytest.raises(StopIteration):
       next(prefetcher)
     prefetcher.close()
-    step_done.set()
     assert events == [
         event for n in range(3) for event in (
-            ('put', n), ('copy ready', n), ('copy ready', n), ('release',),
-            ('relayout', n))]
+            ('put', n), ('copy ready', n), ('copy ready', n), ('release',))]
     # Batch n was handed on only after its own lease was back.
-    assert all(at >= 5 * (n + 1) for n, at in enumerate(handed_on))
+    assert all(at >= 4 * (n + 1) for n, at in enumerate(handed_on))
 
-  @pytest.mark.parametrize('layouts', ['default', 'column'])
-  def test_place_stage_training_bitwise_identical(self, request, layouts,
-                                                  forced_place_stage):
+  def test_place_stage_training_bitwise_identical(self, forced_place_stage):
     """The three-stage pipeline must not change training — force it on
-    (it is TPU-only by default) and compare against the inline path
-    with default layouts. ``column``: the staged run's step is compiled
-    for a column-major input, so every batch goes through both halves of
-    the placement (copy, then the re-layout on the device)."""
+    (it is TPU-only by default) and compare against the inline path."""
     import jax
 
     import tensor2robot_tpu.train.trainer as trainer_mod
@@ -1052,8 +1019,6 @@ class TestPlacementStage:
     from tensor2robot_tpu.models import optimizers as opt_lib
     from tensor2robot_tpu.utils.mocks import MockInputGenerator, MockT2RModel
 
-    if layouts == 'column':
-      request.getfixturevalue('column_major_inputs')
     results = {}
     for mode in ('inline', 'staged'):
       model = MockT2RModel(
@@ -1062,15 +1027,10 @@ class TestPlacementStage:
       trainer = trainer_mod.Trainer(model, trainer_mod.TrainerConfig(
           model_dir='', max_train_steps=12, eval_interval_steps=0,
           log_interval_steps=0,
-          auto_input_layouts=mode == 'staged' and layouts == 'column',
           prefetch_batches=0 if mode == 'inline' else 2))
       gen = MockInputGenerator(batch_size=8)
       gen.set_specification_from_model(model, ModeKeys.TRAIN)
-      before = metrics_lib.counter('trainer/place/relayout_leaves').value
       trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
-      relaid = metrics_lib.counter(
-          'trainer/place/relayout_leaves').value - before
-      assert (relaid >= 12) == (mode == 'staged' and layouts == 'column')
       results[mode] = jax.device_get(trainer.state.params)
     for a, b in zip(jax.tree_util.tree_leaves(results['inline']),
                     jax.tree_util.tree_leaves(results['staged'])):

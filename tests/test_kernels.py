@@ -226,7 +226,7 @@ def _train_qtopt(kernel_policy='none', matmul_precision=None, steps=3,
       model,
       TrainerConfig(model_dir='', max_train_steps=steps,
                     eval_interval_steps=0, log_interval_steps=1,
-                    prefetch_batches=0, auto_input_layouts=False,
+                    prefetch_batches=0,
                     matmul_precision=matmul_precision, **config_kwargs),
       callbacks=[recorder])
   pre = model.preprocessor

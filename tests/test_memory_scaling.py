@@ -120,7 +120,7 @@ def _train_no_bn(accum_m, steps=6, k=1, batch=8, ema=True):
   gen.set_specification_from_model(model, ModeKeys.TRAIN)
   trainer = Trainer(model, TrainerConfig(
       model_dir='', max_train_steps=steps, eval_interval_steps=0,
-      log_interval_steps=0, prefetch_batches=0, auto_input_layouts=False,
+      log_interval_steps=0, prefetch_batches=0,
       steps_per_dispatch=k, grad_accum_microbatches=accum_m))
   scalars = trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
   return trainer, scalars
@@ -301,7 +301,7 @@ def test_grad_accum_matches_reference_accumulation(workload):
 
   trainer = Trainer(model, TrainerConfig(
       model_dir='', max_train_steps=1, eval_interval_steps=0,
-      log_interval_steps=0, prefetch_batches=0, auto_input_layouts=False,
+      log_interval_steps=0, prefetch_batches=0,
       grad_accum_microbatches=2))
   state0 = trainer.initialize(features)
   state0 = jax.device_get(state0)
@@ -361,7 +361,7 @@ def test_nonfinite_skip_update_over_accumulated_grads():
     model = MockT2RModel(device_type='tpu', create_optimizer_fn=fast_adam)
     trainer = Trainer(model, TrainerConfig(
         model_dir='', max_train_steps=max_steps, eval_interval_steps=0,
-        log_interval_steps=0, prefetch_batches=0, auto_input_layouts=False,
+        log_interval_steps=0, prefetch_batches=0,
         grad_accum_microbatches=2, nonfinite_mode='skip_update'))
     trainer.train(iter(batches), None)
     return trainer
@@ -389,7 +389,7 @@ def test_nonfinite_raise_fires_for_single_bad_microbatch():
   l['valid_position'] = np.ones((8,), np.float32)
   trainer = Trainer(model, TrainerConfig(
       model_dir='', max_train_steps=3, eval_interval_steps=0,
-      log_interval_steps=0, prefetch_batches=0, auto_input_layouts=False,
+      log_interval_steps=0, prefetch_batches=0,
       grad_accum_microbatches=4, nonfinite_mode='raise'))
   with pytest.raises(resilience.NonFiniteError):
     trainer.train(iter([(f, l)] * 3), None)
@@ -419,7 +419,7 @@ def test_graceful_shutdown_checkpoints_on_effective_batch_boundary(tmp_path):
   trainer = Trainer(model, TrainerConfig(
       model_dir=str(tmp_path / 'm'), max_train_steps=20,
       save_interval_steps=100, eval_interval_steps=0, log_interval_steps=0,
-      prefetch_batches=0, auto_input_layouts=False, async_checkpoints=False,
+      prefetch_batches=0, async_checkpoints=False,
       steps_per_dispatch=2, grad_accum_microbatches=2),
       callbacks=[RequestAt()], shutdown=shutdown)
   with pytest.raises(resilience.PreemptedError):
@@ -448,7 +448,7 @@ def test_no_per_microbatch_retrace():
     gen.set_specification_from_model(model, ModeKeys.TRAIN)
     trainer = Trainer(model, TrainerConfig(
         model_dir='', max_train_steps=4, eval_interval_steps=0,
-        log_interval_steps=0, prefetch_batches=0, auto_input_layouts=False,
+        log_interval_steps=0, prefetch_batches=0,
         grad_accum_microbatches=m))
     trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
     counts[m] = calls[0]
@@ -482,7 +482,7 @@ def test_remat_training_step_is_equivalent_qtopt(policy):
     labels = make_random_numpy(lspec, batch_size=4, seed=7)
     trainer = Trainer(model, TrainerConfig(
         model_dir='', max_train_steps=2, eval_interval_steps=0,
-        log_interval_steps=0, prefetch_batches=0, auto_input_layouts=False))
+        log_interval_steps=0, prefetch_batches=0))
     scalars = trainer.train(iter([(features, labels)] * 2), None)
     return trainer, float(scalars['loss'])
 
@@ -621,7 +621,7 @@ def test_trainer_merges_memory_scalars_at_log_crossings(monkeypatch):
   gen.set_specification_from_model(model, ModeKeys.TRAIN)
   trainer = Trainer(model, TrainerConfig(
       model_dir='', max_train_steps=4, eval_interval_steps=0,
-      log_interval_steps=2, prefetch_batches=0, auto_input_layouts=False),
+      log_interval_steps=2, prefetch_batches=0),
       callbacks=[Capture()])
   trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
   assert seen and seen[0][1] == pytest.approx(2.0), seen

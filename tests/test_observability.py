@@ -396,7 +396,7 @@ def test_breakdown_disabled_restores_plain_loop(tmp_path):
 def test_breakdown_with_steps_per_dispatch(tmp_path):
   records = [r for r in train_records(
       tmp_path, max_train_steps=12, steps_per_dispatch=3,
-      prefetch_batches=0, auto_input_layouts=False)
+      prefetch_batches=0)
       if r['kind'] == 'train']
   assert records
   rec = records[-1]

@@ -188,7 +188,7 @@ def test_prefetch_depth1_close_terminates_worker():
   from tensor2robot_tpu.train.trainer import _DevicePrefetcher
 
   src = iter(itertools.count())
-  prefetcher = _DevicePrefetcher(src, lambda b: ((b, False), None), depth=1)
+  prefetcher = _DevicePrefetcher(src, lambda b: b, depth=1)
   next(iter(prefetcher))  # consume one so the worker is mid-stream
   prefetcher.close()
   for thread in prefetcher._threads:  # pylint: disable=protected-access
@@ -453,152 +453,6 @@ def test_tensorboard_callback_writes_events(tmp_path):
                for n in os.listdir(event_dir)), os.listdir(event_dir)
 
 
-def test_auto_input_layouts_matches_default_path():
-  """auto_input_layouts=True dispatches the compiler-chosen-layout
-  executable and trains identically (same batches/seed) to the default
-  path; formats are recorded for the place() path."""
-  def run(auto):
-    model = MockT2RModel(device_type='tpu', create_optimizer_fn=fast_adam)
-    gen = MockInputGenerator(batch_size=16)
-    gen.set_specification_from_model(model, ModeKeys.TRAIN)
-    trainer = Trainer(model, TrainerConfig(
-        model_dir='', max_train_steps=3, eval_interval_steps=0,
-        log_interval_steps=0, prefetch_batches=0,
-        auto_input_layouts=auto))
-    scalars = trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
-    return trainer, float(scalars['loss'])
-
-  trainer_auto, loss_auto = run(True)
-  trainer_def, loss_def = run(False)
-  assert trainer_def._auto_step is None
-  # XLA CPU (this suite's backend) and TPU both support Layout.AUTO, so
-  # the executable MUST have been built — a silent fallback here would
-  # mean the production dispatch path quietly reverted to default
-  # layouts everywhere (e.g. a jax API rename swallowed by the
-  # build-time except). Backends genuinely without layout support fall
-  # back loudly at build time instead.
-  assert trainer_auto._auto_step is not None
-  assert trainer_auto._batch_formats is not None
-  np.testing.assert_allclose(loss_auto, loss_def, rtol=1e-5)
-
-
-def test_auto_input_layouts_give_way_loudly(monkeypatch, caplog):
-  """Compiler-chosen layouts are asked for only on leaves big enough to
-  matter; and a placed batch that comes back in another layout than the
-  executable was compiled for (seen on the TPU after a persistent-cache
-  hit) does not reach the dispatch: the run goes on the default way,
-  with a WARNING and the gauge at 0, and trains the same."""
-  from jax.experimental.layout import Format
-  from tensor2robot_tpu.observability import metrics as metrics_lib
-  from tensor2robot_tpu.train import trainer as trainer_mod
-
-  def run(auto):
-    model = MockT2RModel(device_type='tpu', create_optimizer_fn=fast_adam)
-    gen = MockInputGenerator(batch_size=16)
-    gen.set_specification_from_model(model, ModeKeys.TRAIN)
-    trainer = Trainer(model, TrainerConfig(
-        model_dir='', max_train_steps=3, eval_interval_steps=0,
-        log_interval_steps=0, prefetch_batches=0, auto_input_layouts=auto))
-    scalars = trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
-    return trainer, float(scalars['loss'])
-
-  def formats_of(trainer):
-    return [type(f) for f in
-            jax.tree_util.tree_leaves(trainer._batch_formats)]
-
-  trainer, _ = run(True)
-  assert Format not in formats_of(trainer)  # the mock's leaves are tiny
-
-  monkeypatch.setattr(trainer_mod, '_AUTO_LAYOUT_MIN_BYTES', 0)
-  trainer, loss_auto = run(True)
-  assert set(formats_of(trainer)) == {Format}
-  assert metrics_lib.gauge('trainer/auto_input_layouts').value == 1.0
-
-  monkeypatch.setattr(trainer_mod, '_placed_as_asked', lambda *a: False)
-  with caplog.at_level('WARNING'):
-    trainer, loss_fallback = run(True)
-  assert 'another layout than the executable was compiled for' in caplog.text
-  assert metrics_lib.gauge('trainer/auto_input_layouts').value == 0.0
-  assert trainer.step == 3
-  np.testing.assert_allclose(loss_fallback, loss_auto, rtol=1e-5)
-
-
-@pytest.mark.parametrize('mode', ['inline', 'staged'])
-def test_relayout_in_another_layout_falls_back_to_the_copies(
-    request, monkeypatch, caplog, column_major_inputs, mode):
-  """The second half of a placement comes back in another layout than
-  the executable was compiled for (here: the copies themselves, row
-  major where column major was asked): the real ``_placed_as_asked``
-  sees it, the run gives the executable up with a WARNING and the gauge
-  at 0, and the batch goes on as its COPIES: the host batch is not put a
-  second time (its ring lease is already back), and training is what the
-  default path gives."""
-  from tensor2robot_tpu.observability import metrics as metrics_lib
-  from tensor2robot_tpu.parallel import mesh as mesh_lib
-
-  if mode == 'staged':
-    request.getfixturevalue('forced_place_stage')
-
-  def run(auto):
-    model = MockT2RModel(device_type='cpu', create_optimizer_fn=fast_adam)
-    gen = MockInputGenerator(batch_size=8)
-    gen.set_specification_from_model(model, ModeKeys.TRAIN)
-    trainer = Trainer(model, TrainerConfig(
-        model_dir='', max_train_steps=6, eval_interval_steps=0,
-        log_interval_steps=0, auto_input_layouts=auto,
-        prefetch_batches=0 if mode == 'inline' else 2))
-    before = metrics_lib.counter('trainer/h2d/bytes').value
-    trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
-    sent = metrics_lib.counter('trainer/h2d/bytes').value - before
-    return trainer, jax.device_get(trainer.state.params), sent
-
-  _, params_default, sent_default = run(False)
-  monkeypatch.setattr(mesh_lib, 'relayout_batch',
-                      lambda copied, formats: copied)
-  with caplog.at_level('WARNING'):
-    trainer, params_fallback, sent_fallback = run(True)
-  assert 'another layout than the executable was compiled for' in caplog.text
-  assert metrics_lib.gauge('trainer/auto_input_layouts').value == 0.0
-  assert trainer.step == 6
-  if mode == 'inline':  # the staged feed runs ahead by a varying count
-    assert sent_fallback == sent_default
-  for a, b in zip(jax.tree_util.tree_leaves(params_default),
-                  jax.tree_util.tree_leaves(params_fallback)):
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_shard_batch_places_a_format_in_two_halves():
-  """``shard_batch`` with a ``Format``: the copy goes with the format's
-  own sharding and the default layout (``copy_shardings``), the
-  re-layout then runs on the device array (``relayout_batch``) and
-  touches only the leaves that have a ``Format``."""
-  from jax.experimental.layout import Format, Layout
-
-  from tensor2robot_tpu.parallel import mesh as mesh_lib
-
-  mesh = parallel.create_mesh(data=-1)
-  sharding = mesh_lib.batch_sharding(mesh)
-  column = Format(Layout(major_to_minor=(1, 0), tiling=()), sharding)
-  batch = {'x': np.arange(32, dtype=np.float32).reshape(16, 2),
-           'y': np.arange(16, dtype=np.float32)}
-  formats = {'x': column, 'y': sharding}
-  assert mesh_lib.copy_shardings(formats) == {'x': sharding, 'y': sharding}
-
-  copied = mesh_lib.shard_batch(batch, mesh, mesh_lib.copy_shardings(formats))
-  assert copied['x'].format.layout != column.layout  # a plain copy
-  relaid = mesh_lib.relayout_batch(copied, formats)
-  assert relaid['y'] is copied['y']
-  assert relaid['x'].format == column
-  assert mesh_lib.relayout_batch(relaid, formats)['x'] is relaid['x']
-
-  placed = mesh_lib.shard_batch(batch, mesh, formats)
-  assert placed['x'].format == column
-  assert placed['y'].sharding == sharding
-  for name, value in batch.items():
-    np.testing.assert_array_equal(np.asarray(placed[name]), value)
-    np.testing.assert_array_equal(np.asarray(relaid[name]), value)
-
-
 def test_steps_per_dispatch_matches_single_step_path():
   """K steps folded into one lax.scan dispatch train IDENTICALLY to K
   single dispatches (same batches, same per-step rng fold_in keyed off
@@ -609,7 +463,7 @@ def test_steps_per_dispatch_matches_single_step_path():
     gen.set_specification_from_model(model, ModeKeys.TRAIN)
     trainer = Trainer(model, TrainerConfig(
         model_dir='', max_train_steps=7, eval_interval_steps=0,
-        log_interval_steps=0, prefetch_batches=0, auto_input_layouts=False,
+        log_interval_steps=0, prefetch_batches=0,
         steps_per_dispatch=k))
     scalars = trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
     return trainer, scalars
@@ -635,26 +489,24 @@ def test_steps_per_dispatch_quantizes_intervals(tmp_path):
   trainer = Trainer(model, TrainerConfig(
       model_dir=str(tmp_path / 'm'), max_train_steps=7,
       save_interval_steps=2, eval_interval_steps=0, log_interval_steps=0,
-      prefetch_batches=0, auto_input_layouts=False, async_checkpoints=False,
+      prefetch_batches=0, async_checkpoints=False,
       steps_per_dispatch=3))
   trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
   assert trainer._manager.all_steps() == [3, 6, 7]
 
 
-def test_steps_per_dispatch_with_prefetch_and_auto_layouts():
-  """The grouped path composes with the prefetcher and the auto-layout
-  executable (which compiles the scan body over stacked avals)."""
+def test_steps_per_dispatch_with_prefetch():
+  """The grouped path composes with the prefetcher: stacked groups
+  placed ahead of the loop, the scan body compiled over them."""
   model = MockT2RModel(device_type='tpu', create_optimizer_fn=fast_adam)
   gen = MockInputGenerator(batch_size=8)
   gen.set_specification_from_model(model, ModeKeys.TRAIN)
   trainer = Trainer(model, TrainerConfig(
       model_dir='', max_train_steps=6, eval_interval_steps=0,
-      log_interval_steps=0, prefetch_batches=2, auto_input_layouts=True,
-      steps_per_dispatch=2))
+      log_interval_steps=0, prefetch_batches=2, steps_per_dispatch=2))
   scalars = trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
   assert int(trainer.step) == 6
   assert np.isfinite(float(scalars['loss']))
-  assert trainer._auto_step is not None  # built over the stacked avals
 
 
 def test_steps_per_dispatch_callback_cadence(tmp_path):
@@ -671,7 +523,7 @@ def test_steps_per_dispatch_callback_cadence(tmp_path):
   trainer = Trainer(model, TrainerConfig(
       model_dir=str(tmp_path / 'm'), max_train_steps=9,
       save_interval_steps=0, eval_interval_steps=0, log_interval_steps=2,
-      prefetch_batches=0, auto_input_layouts=False, async_checkpoints=False,
+      prefetch_batches=0, async_checkpoints=False,
       steps_per_dispatch=3), callbacks=[MetricsLoggerCallback()])
   trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
   with open(tmp_path / 'm' / 'metrics.jsonl') as f:
@@ -701,35 +553,28 @@ def test_steps_per_dispatch_handles_ragged_tail():
   model = MockT2RModel(device_type='tpu', create_optimizer_fn=fast_adam)
   trainer = Trainer(model, TrainerConfig(
       model_dir='', max_train_steps=2, eval_interval_steps=0,
-      log_interval_steps=0, prefetch_batches=0, auto_input_layouts=False,
+      log_interval_steps=0, prefetch_batches=0,
       steps_per_dispatch=3))
   trainer.train(iter([make_batch(8), make_batch(5)]), None)
   assert int(trainer.step) == 2
 
 
-@pytest.mark.parametrize('mode,k,layouts', [
-    ('staged', 1, 'default'), ('consumer', 1, 'default'),
-    ('inline', 1, 'default'), ('staged', 2, 'default'),
-    ('staged', 1, 'column'), ('consumer', 1, 'column'),
-    ('inline', 1, 'column')])
-def test_spans_are_keyed_by_dispatch_and_tile_the_loop(request, mode, k,
-                                                       layouts):
+@pytest.mark.parametrize('mode,k', [
+    ('staged', 1), ('consumer', 1), ('inline', 1),
+    ('staged', 2), ('consumer', 2), ('inline', 2)])
+def test_spans_are_keyed_by_dispatch_and_tile_the_loop(request, mode, k):
   """Every stage's spans carry the batch ordinal, which is the dispatch
   ordinal (under K > 1, the group's), on every placement path: the
   dedicated place stage (forced on: it is TPU-only by default), the
   consumer-thread placement behind one fetch thread, and no prefetch.
   The four loop-thread spans leave no time between boundaries outside
-  them. With a layout to re-lay into (``column``), every path records
-  the second half of the placement under the batch's key, inside its
-  stage, and counts its leaf; with default layouts there is none."""
+  them."""
   import time
 
   from tensor2robot_tpu.observability import metrics, tracing
 
   if mode == 'staged':
     request.getfixturevalue('forced_place_stage')
-  if layouts == 'column':
-    request.getfixturevalue('column_major_inputs')
   steps = 12
   dispatches = steps // k
   model = MockT2RModel(device_type='cpu', create_optimizer_fn=fast_adam)
@@ -738,10 +583,8 @@ def test_spans_are_keyed_by_dispatch_and_tile_the_loop(request, mode, k,
   trainer = Trainer(model, TrainerConfig(
       model_dir='', max_train_steps=steps, eval_interval_steps=0,
       log_interval_steps=0, steps_per_dispatch=k,
-      auto_input_layouts=layouts == 'column',
       prefetch_batches=0 if mode == 'inline' else 2))
   bytes_before = metrics.counter('trainer/h2d/bytes').value
-  relaid_before = metrics.counter('trainer/place/relayout_leaves').value
   mark = time.perf_counter_ns()
   trainer.train(gen.create_iterator(ModeKeys.TRAIN), None)
   spans = [s for s in tracing.recent(since_ns=mark) if s[1] >= mark]
@@ -775,28 +618,16 @@ def test_spans_are_keyed_by_dispatch_and_tile_the_loop(request, mode, k,
     assert keys('trainer/place/transfer')[:dispatches] == every
   else:
     assert threads('trainer/place_stage') == {loop_thread}
-    # No lease, loop thread: it waits for a copy only to re-lay it out.
-    assert keys('trainer/place/transfer') == keys('trainer/place/relayout')
+    # No lease, loop thread: nothing to wait for.
+    assert not keys('trainer/place/transfer')
     if mode == 'consumer':
       assert threads('trainer/fetch') == {'t2r-prefetch'}
     else:
       assert not keys('trainer/fetch')
-  # The second half: once a batch where there is a layout to re-lay
-  # into (one leaf a batch), after the put and the wait for the copy;
-  # never on the default-layout path.
-  relaid = metrics.counter('trainer/place/relayout_leaves').value
-  if layouts == 'column':
-    assert keys('trainer/place/relayout') == keys('trainer/place_stage')
-    assert relaid - relaid_before == len(keys('trainer/place_stage'))
-    assert metrics.gauge('trainer/auto_input_layouts').value == 1.0
-  else:
-    assert not keys('trainer/place/relayout')
-    assert relaid == relaid_before
   # Children lie inside their parent, on its thread, under its key, in
-  # the order put, transfer, relayout.
+  # the order put, transfer.
   stages = {s[4]: s for s in spans if s[0] == 'trainer/place_stage'}
-  order = ['trainer/place/put', 'trainer/place/transfer',
-           'trainer/place/relayout']
+  order = ['trainer/place/put', 'trainer/place/transfer']
   children = {}
   for s in spans:
     if s[0] in order:
@@ -827,6 +658,77 @@ def test_spans_are_keyed_by_dispatch_and_tile_the_loop(request, mode, k,
           next(gen.create_iterator(ModeKeys.TRAIN))))
   sent = metrics.counter('trainer/h2d/bytes').value - bytes_before
   assert sent >= steps * batch_bytes and sent % batch_bytes == 0
+
+
+@pytest.mark.parametrize('mode', ['inline', 'consumer', 'staged'])
+def test_one_step_program_a_run(request, monkeypatch, mode):
+  """A run has one step program: the step body is traced once (ledger
+  off: no harvest), every dispatch calls the one jitted function that
+  ``initialize`` built, and a placement leaves its put, its transfer
+  and its stage on record and no other span."""
+  import time
+
+  from tensor2robot_tpu.observability import tracing
+
+  if mode == 'staged':
+    request.getfixturevalue('forced_place_stage')
+  traces = []
+  build_body = Trainer._train_step_body
+
+  def counted_body(self):
+    step = build_body(self)
+
+    def train_step(*args):
+      traces.append(1)
+      return step(*args)
+
+    return train_step
+
+  monkeypatch.setattr(Trainer, '_train_step_body', counted_body)
+  model = MockT2RModel(device_type='cpu', create_optimizer_fn=fast_adam)
+  gen = MockInputGenerator(batch_size=8)
+  gen.set_specification_from_model(model, ModeKeys.TRAIN)
+  trainer = Trainer(model, TrainerConfig(
+      model_dir='', max_train_steps=12, eval_interval_steps=0,
+      log_interval_steps=0, program_ledger=False,
+      prefetch_batches=0 if mode == 'inline' else 2))
+  batches = gen.create_iterator(ModeKeys.TRAIN)
+  trainer.initialize(next(batches)[0])
+  step_fn = trainer._train_step_fn
+  dispatched = []
+
+  def counted_step(*args):
+    dispatched.append(1)
+    return step_fn(*args)
+
+  trainer._train_step_fn = counted_step
+  mark = time.perf_counter_ns()
+  trainer.train(batches, None)
+  assert trainer.step == 12
+  assert len(traces) == 1
+  assert len(dispatched) == 12
+  placement = {s[0] for s in tracing.recent(since_ns=mark)
+               if s[1] >= mark and s[0].startswith('trainer/place')}
+  assert placement == {'trainer/place_stage', 'trainer/place/put'} | (
+      {'trainer/place/transfer'} if mode == 'staged' else set())
+
+
+@pytest.mark.parametrize('name', ['auto_input_layouts', 'fused_update'])
+def test_removed_step_knobs_are_refused(name):
+  """A config in the wild that still sets a deleted knob is told so, by
+  name, and is not silently ignored."""
+  from tensor2robot_tpu import config as t2r_config
+
+  with pytest.raises(TypeError, match=name):
+    TrainerConfig(**{name: False})
+  t2r_config.register_framework_configurables()
+  t2r_config.parse_config(f'train_eval_model.{name} = False')
+  try:
+    with pytest.raises(t2r_config.ConfigError, match=name):
+      t2r_config.get_configurable('train_eval_model')(
+          model=MockT2RModel(device_type='cpu'))
+  finally:
+    t2r_config.clear_config()
 
 
 def test_profiler_callback_window_at_k_dispatch(monkeypatch):
@@ -888,7 +790,7 @@ def test_input_state_resume_is_exact(tmp_path):
     trainer = Trainer(model, TrainerConfig(
         model_dir=model_dir, max_train_steps=max_steps,
         save_interval_steps=4, eval_interval_steps=0, log_interval_steps=0,
-        prefetch_batches=0, auto_input_layouts=False,
+        prefetch_batches=0,
         async_checkpoints=False), callbacks=[InputStateCallback(it)])
     trainer.train(it, None)
     return jax.device_get(trainer.state.params)
@@ -966,7 +868,7 @@ def test_input_state_missing_falls_back_to_fresh_stream(tmp_path, caplog):
     trainer = Trainer(model, TrainerConfig(
         model_dir=str(tmp_path / 'm'), max_train_steps=max_steps,
         save_interval_steps=2, eval_interval_steps=0, log_interval_steps=0,
-        prefetch_batches=0, auto_input_layouts=False,
+        prefetch_batches=0,
         async_checkpoints=False), callbacks=callbacks)
     trainer.train(it, None)
     return trainer
@@ -1086,7 +988,7 @@ def test_prefetcher_delivers_worker_error_promptly():
     raise IOError('pipeline died')
 
   prefetcher = _DevicePrefetcher(
-      source(), place=lambda b: ((b, False), None), depth=4)
+      source(), place=lambda b: b, depth=4)
   for thread in prefetcher._threads:  # pylint: disable=protected-access
     thread.join(timeout=5)
     assert not thread.is_alive()
