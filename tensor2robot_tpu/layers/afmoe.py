@@ -32,12 +32,23 @@ from typing import Any, Dict, Optional, Sequence
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from tensor2robot_tpu.layers import moe
 from tensor2robot_tpu.ops import flash_attention as fa
 
 SLIDING = 'sliding_attention'
 FULL = 'full_attention'
+
+# What a decoder layer's remat keeps besides the layer's input, by the
+# names the values are given where they are made: the kernel's two
+# results, q, k, v and the gate as ``Attention`` hands them on, the
+# experts' choice and output. ``Trunk`` says what each weighs; each is
+# kept because the chip read it faster (PERF.md, PR 31).
+Q_NAME, K_NAME, V_NAME, GATE_NAME = 'attn_q', 'attn_k', 'attn_v', 'attn_gate'
+KEPT_NAMES = (fa.OUT_NAME, fa.LSE_NAME, Q_NAME, K_NAME, V_NAME, GATE_NAME,
+              moe.CHOSEN_NAME, moe.PICKED_NAME)
+KEPT_IN_LAYER = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
 
 
 def rms_norm(x, scale, eps: float, dtype):
@@ -96,6 +107,11 @@ class Attention(nn.Module):
       q, k = rope(q, self.rope_theta).astype(dt), rope(
           k, self.rope_theta).astype(dt)
       window = self.sliding_window
+    # As the kernel and the gate take them, by name (``KEPT_NAMES``).
+    q = checkpoint_name(q, Q_NAME)
+    k = checkpoint_name(k, K_NAME)
+    v = checkpoint_name(v, V_NAME)
+    gate = checkpoint_name(gate, GATE_NAME)
     scope = 'afmoe/attn/window' if window else 'afmoe/attn/full'
     with jax.named_scope(scope):
       o = fa.flash_attention(q, k, v, True, None, None, window)
@@ -172,9 +188,20 @@ class Trunk(nn.Module):
     if self.mup_enabled:
       h = h * self.hidden_size ** 0.5
     h = h.astype(self.dtype)
-    # One layer's activations at a time: the backward pass recomputes a
-    # layer from its input (16 bytes a parameter leave room for no more).
-    layer_cls = nn.remat(DecoderLayer, static_argnums=(2,))
+    # The backward pass computes a layer again from its input (bf16 [B, S,
+    # hidden]: 32 MiB at 8,192 x 2,048), all but what ``KEPT_NAMES`` keeps.
+    # At those sizes, 32 / 4 heads of 128 and 8 experts a token: the
+    # attention kernel's output 64 MiB and log-sum-exp 1 MiB (a kernel
+    # pass to rebuild: a fifth of a step's attention time), q and the gate
+    # 64 MiB each, k and v 8 MiB each (the gate's and v's projections and
+    # the float32 norm and RoPE passes), the experts' output 256 MiB (a
+    # trip through the routed-row buffer: a gather, three grouped
+    # products, a gather back) with the choice that laid it out, 256 KiB.
+    # Computed again: the norms, the q and k projections (the head norm's
+    # way back needs them before the norm), the MLP or the router's
+    # scores, the sorts on the choice and the shared expert.
+    layer_cls = nn.remat(DecoderLayer, static_argnums=(2,),
+                         policy=KEPT_IN_LAYER)
     all_stats = []
     for j, kind in enumerate(self.layer_types):
       sparse = j >= self.num_dense_layers

@@ -53,6 +53,7 @@ from typing import Any, Dict, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 # Rows a tile of the TPU's grouped product holds: ``ragged_dot`` lowers
 # there to a kernel whose tile table has rows/512 + groups - 1 entries
@@ -62,6 +63,14 @@ import jax.numpy as jnp
 GROUPED_ROW_TILE = 512
 
 MOE_STATE = 'moe_state'
+# ``jax.ad_checkpoint.checkpoint_name``s of ``route``'s top-k choice and
+# of the experts' output [T, k, hidden], for a remat policy that keeps
+# them (layers/afmoe.py does), and then both: the output is a trip through
+# the buffer to rebuild and the weighted sum's way back needs it, and the
+# choice is what laid it out. A near-tie that falls the other way when
+# the scores are computed again would send a pair's gradient to another
+# expert than its kept output came from.
+CHOSEN_NAME, PICKED_NAME = 'moe_chosen', 'moe_picked'
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -120,6 +129,7 @@ def route(scores, bias, experts_per_token: int, route_norm: bool,
   """(chosen ids [T, k], weights [T, k], counts [experts]) from float32
   scores [T, experts]."""
   _, chosen = jax.lax.top_k(scores + bias, experts_per_token)
+  chosen = checkpoint_name(chosen, CHOSEN_NAME)
   weights = jnp.take_along_axis(scores, chosen, axis=-1)
   if route_norm:
     weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
@@ -329,6 +339,7 @@ class ExpertLayer(nn.Module):
                                aux)
     else:
       picked = _on_rung(k, rungs, rung, x, experts, aux)
+    picked = checkpoint_name(picked, PICKED_NAME)
     with jax.named_scope('afmoe/moe/route'):
       routed = jnp.sum(picked.astype(jnp.float32) *
                        jnp.where(placed, weights, 0.0)[..., None], axis=1)

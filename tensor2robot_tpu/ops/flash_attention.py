@@ -34,6 +34,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -41,6 +42,12 @@ from tensor2robot_tpu.ops import _pallas_dispatch as dispatch
 
 _NEG_INF = -1e30  # large-negative instead of -inf: keeps exp/corr math
                   # finite without isfinite guards in the inner loop
+
+# ``jax.ad_checkpoint.checkpoint_name``s of the forward kernel's folded
+# output and log-sum-exp, the two residuals that cost a kernel pass to
+# rebuild: a remat policy that names them keeps them (layers/afmoe.py
+# does), and under any other the tags are identities.
+OUT_NAME, LSE_NAME = 'attn_out', 'attn_lse'
 
 
 def _use_interpret() -> bool:
@@ -546,6 +553,8 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, window=None):
   qr, kr, vr = _fold_heads(q), _fold_heads(k), _fold_heads(v)
   out, lse = _flash_call(qr, kr, vr, causal, bq, bk, group, streamed,
                          window)
+  out = checkpoint_name(out, OUT_NAME)
+  lse = checkpoint_name(lse, LSE_NAME)
   return _unfold_heads(out, b, h), (qr, kr, vr, out, lse, (b, t, h, d))
 
 

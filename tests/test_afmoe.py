@@ -375,6 +375,60 @@ def test_model_loss_and_gradients_match_reference():
   assert int(out['moe/tokens']) == tokens.size * len(ref.init_state(cfg))
 
 
+@pytest.mark.parametrize('sparse', [False, True], ids=['dense', 'sparse'])
+@pytest.mark.parametrize('kind', [afmoe.SLIDING, afmoe.FULL])
+def test_remat_keeps_named_values_and_runs_forward_kernel_once(
+    kind, sparse, monkeypatch):
+  """A trunk under its remat policy is the trunk under no remat at all,
+  to the last bit, and its gradient runs the attention forward kernel
+  once a layer, not twice: the kernel's output and log-sum-exp are kept
+  by name. Every name the policy keeps is a name some value has."""
+  layers = 2
+  cfg = tiny_cfg(layer_types=[kind] * layers, layers_kept=list(range(layers)),
+                 num_dense_layers=0 if sparse else layers)
+  tokens = tokens_for(cfg, 2).astype(np.int32)
+
+  def traced():
+    """(loss and gradients as a function, its parameters, its jaxpr)."""
+    model = model_for(cfg)
+    variables = model.init_variables(jax.random.PRNGKey(4),
+                                     {'tokens': tokens})
+
+    def program(p):
+      out, _ = model.inference_network_fn(
+          {**variables, 'params': p}, {'tokens': tokens}, None, 'train')
+      return out['loss']
+
+    fn, params = jax.value_and_grad(program), variables['params']
+    return fn, params, str(jax.make_jaxpr(fn)(params))
+
+  fn, params, text = traced()
+  loss, grads = fn(params)
+  assert text.count('name=flash_attention_fwd') == layers
+  assert text.count('name=flash_attention_dq') == layers
+  for name in afmoe.KEPT_NAMES:
+    assert (f'name={name}' in text) == (sparse or name[:4] != 'moe_'), name
+  # The experts are chosen once a layer: the way back takes the kept
+  # choice, the one the kept output was laid out by.
+  assert text.count(' top_k[') == (layers if sparse else 0)
+  monkeypatch.setattr(afmoe, 'KEPT_IN_LAYER', None)
+  nothing_kept = traced()[2]
+  assert nothing_kept.count('name=flash_attention_fwd') == 2 * layers
+  assert nothing_kept.count(' top_k[') == (2 * layers if sparse else 0)
+  monkeypatch.setattr(afmoe.nn, 'remat', lambda cls, **kwargs: cls)
+  fn, params, text = traced()
+  assert text.count('name=flash_attention_fwd') == layers
+  want_loss, want_grads = fn(params)
+  assert float(loss) == float(want_loss)
+  got, want = (jax.tree_util.tree_leaves_with_path(g)
+               for g in (grads, want_grads))
+  assert len(got) == len(want)
+  for (path, a), (want_path, b) in zip(got, want):
+    assert path == want_path
+    assert float(jnp.abs(b).max()) > 0, path
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 # ------------------------------------- records, the Trainer and the counters
 
 def _write_shards(tmp_path, cfg, seed=7, examples=8, shards=2):
@@ -482,8 +536,22 @@ def test_two_trainer_steps_match_reference_and_count(tmp_path):
     leaf = functools.reduce(lambda n, k: n[k], ref.program_path(name, cfg),
                             state.params)
     moved_by = np.asarray(want[-1]['params'][name] - params[name])
-    # Adam's first steps move every weight by about the learning rate.
-    close(np.asarray(leaf) - np.asarray(params[name]), moved_by, 2e-2)
+    # Adam's first steps move every weight by about the learning rate: by
+    # g / (|g| + eps) at the first. Where a gradient of the reference is
+    # under float32's rounding of its leaf's largest (and not nothing
+    # itself) that ratio follows the program's rounding, not its
+    # arithmetic, so those elements are left out: three of a leaf's 2,048
+    # at most. ``layer0/attn/q[13, 20]``, a first gradient of 8.4e-9 where
+    # the leaf's largest is 0.037 and eps 1e-8, read 0.1% with nothing
+    # kept across the layer's remat, 2.2% with the kernel's output and
+    # log-sum-exp kept (and with the experts' names besides), 0.5% with
+    # q, k, v and the gate kept too; every other element under 0.3%.
+    sound = np.all([(g == 0) | (np.abs(g) >= 1e-6 * np.abs(g).max())
+                    for g in (np.asarray(step['grads'][name])
+                              for step in want)], axis=0)
+    assert sound.mean() >= 0.998, name
+    close(np.where(sound, np.asarray(leaf) - np.asarray(params[name]), 0),
+          np.where(sound, moved_by, 0), 2e-2)
   for name, bias in want[-1]['state'].items():
     node = functools.reduce(lambda n, k: n[k],
                             ref.program_state_path(name, cfg)[:-1],

@@ -228,3 +228,46 @@ def test_window_needs_causal_and_heads_must_divide():
     flash_attention(q, k, v, False, 64, 64, 32)
   with pytest.raises(ValueError, match='divide'):
     flash_attention(q, k[:, :, :3], v[:, :, :3], True, 64, 64)
+
+
+# --------------------------------------- the residuals' names under remat
+
+
+@pytest.mark.parametrize('streamed,window', [
+    (False, None), (True, None), (True, 96)],
+    ids=['staged', 'streamed', 'streamed-window'])
+def test_residual_names_engage_under_a_policy_that_names_them_only(
+    streamed, window, monkeypatch):
+  """``attn_out`` / ``attn_lse`` are identities: values and gradients
+  under ``jax.grad``, under ``jax.checkpoint`` with no policy and under
+  the policy that names them are the same bits, and only that policy
+  spares the backward pass the second forward kernel."""
+  from tensor2robot_tpu.ops import flash_attention as fa
+
+  if streamed:
+    monkeypatch.setattr(fa, '_MAX_STAGED_KV_BYTES', 1)
+  q, k, v = _qkv((2, 256, 2, 32), seed=9)
+  ct = jnp.asarray(np.random.RandomState(10).randn(2, 256, 2, 32),
+                   jnp.float32)
+
+  def loss(q, k, v):
+    return jnp.sum(fa.flash_attention(q, k, v, True, 64, 128, window) * ct)
+
+  names = jax.checkpoint_policies.save_only_these_names(
+      fa.OUT_NAME, fa.LSE_NAME)
+  # The forward kernel, dq and dk/dv; remat's second forward where it runs.
+  variants = [(loss, 3), (jax.checkpoint(loss), 4),
+              (jax.checkpoint(loss, policy=names), 3)]
+  results = []
+  for fn, kernel_calls in variants:
+    fn = jax.value_and_grad(fn, (0, 1, 2))
+    text = str(jax.make_jaxpr(fn)(q, k, v))
+    assert text.count('pallas_call[') == kernel_calls
+    if streamed:
+      assert text.count('name=flash_attention_fwd') == kernel_calls - 2
+    results.append(fn(q, k, v))
+  (want, want_grads) = results[0]
+  for value, grads in results[1:]:
+    assert float(value) == float(want)
+    for g, w in zip(grads, want_grads):
+      np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
