@@ -106,6 +106,8 @@ def register() -> None:
   from tensor2robot_tpu.research import qtopt as qtopt_lib
   from tensor2robot_tpu.research.token_policy import (
       afmoe_model as token_policy_lib)
+  from tensor2robot_tpu.research.token_policy import (
+      zaya_model as zaya_policy_lib)
   from tensor2robot_tpu.research import vrgripper as vrgripper_lib
 
   reg(maml_model_lib.MAMLModel, 'MAMLModel')
@@ -123,6 +125,7 @@ def register() -> None:
       'Grasping44E2EOpenCloseTerminateGripperStatusHeightToBottom')
   reg(grasp2vec_lib.Grasp2VecModel, 'Grasp2VecModel')
   reg(token_policy_lib.AfmoeTokenPolicyModel, 'AfmoeTokenPolicyModel')
+  reg(zaya_policy_lib.ZayaTokenPolicyModel, 'ZayaTokenPolicyModel')
   reg(vrgripper_lib.VRGripperRegressionModel, 'VRGripperRegressionModel')
   reg(vrgripper_lib.VRGripperDomainAdaptiveModel,
       'VRGripperDomainAdaptiveModel')

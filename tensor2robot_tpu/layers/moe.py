@@ -8,11 +8,16 @@ experts would add: under expert parallelism that partial sum is this
 chip's part of the layer's result. On one chip it runs without an
 exchange.
 
-Routing (afmoe / torchtitan's MoE): ``s = sigmoid(Wr x)`` in float32;
-chosen = top-k of ``s + b`` (``b``: a non-gradient bias an expert, used
-for the choice only); ``w = s_chosen / (sum + 1e-20)`` where
-``route_norm``, times ``route_scale``; weights are applied after the
-expert. After a training step's forward pass, outside the gradient,
+Routing: float32 scores ``s`` over all experts; chosen = top-k of
+``s + b`` (``b``: a non-gradient bias an expert, used for the choice
+only); ``w = s_chosen / (sum + 1e-20)`` where ``route_norm``, times
+``route_scale``; weights are applied after the expert. The scores are
+the caller's (``__call__``'s ``scores``: layers/zaya.py hands over the
+softmax of its router MLP, chooses one expert and weighs it by its
+probability) or, where none are given, afmoe's / torchtitan's:
+``sigmoid(Wr x)`` with ``Wr`` the layer's own (``sigmoid_scores``).
+``shared_expert`` says whether a SwiGLU that every token passes is
+added. After a training step's forward pass, outside the gradient,
 ``b += load_balance_coeff * sign(mean(count) - count)``, minus its mean;
 ``b`` and the step's ``count`` live in the mutable collection
 ``moe_state``, which the trainer carries as ``model_state``.
@@ -138,6 +143,15 @@ def route(scores, bias, experts_per_token: int, route_norm: bool,
   counts = jnp.sum(chosen[..., None] == jnp.arange(experts), axis=(0, 1),
                    dtype=jnp.int32)
   return chosen, weights, counts
+
+
+def sigmoid_scores(layer: 'ExpertLayer', x):
+  """afmoe's router, a matrix of ``layer``'s own (``router``):
+  ``sigmoid(x Wr)`` in float32."""
+  router = layer.param('router', normal_init(layer.init_std),
+                       (x.shape[-1], layer.num_experts))
+  return jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32), router,
+                                   precision=HIGHEST))
 
 
 def updated_bias(bias, counts, coeff: float):
@@ -273,12 +287,15 @@ def _backward(k, rungs, rung, x, cast, aux, g):
 
 
 class ExpertLayer(nn.Module):
-  """See the module docstring. ``__call__`` takes [..., hidden] and
-  returns the same shape and a dict of this call's counts (int32
+  """See the module docstring. ``__call__`` takes [..., hidden] and,
+  where the router is the caller's, its float32 ``scores`` [..., experts];
+  it returns the input's shape and a dict of this call's counts (int32
   scalars: ``tokens``, ``rows_routed``, ``rows_computed``,
   ``rows_max_expert``, ``rows_dropped``, and ``rows_room``, the rows of
   the buffer's rung this call took: over ``tokens * min(k, held)`` it is
-  the share of the worst case that was moved)."""
+  the share of the worst case that was moved; with the caller's scores
+  also ``weight_e6``, the mean weight of a (token, choice) pair in
+  millionths: how decided a softmax router is)."""
 
   num_experts: int                 # the router's width: all published
   experts_per_token: int
@@ -289,9 +306,10 @@ class ExpertLayer(nn.Module):
   load_balance_coeff: float = 0.0
   dtype: Any = jnp.float32
   init_std: float = 0.02
+  shared_expert: bool = True
 
   @nn.compact
-  def __call__(self, x, train: bool = False):
+  def __call__(self, x, train: bool = False, scores=None):
     shape = x.shape
     x = x.reshape((-1, shape[-1])).astype(self.dtype)
     tokens, k = x.shape[0], self.experts_per_token
@@ -301,18 +319,19 @@ class ExpertLayer(nn.Module):
     pairs = tokens * k
     rungs = ladder(tokens, k, held, self.num_experts)
 
-    router = self.param('router', normal_init(self.init_std),
-                        (shape[-1], self.num_experts))
+    given = scores is not None
+    if not given:
+      with jax.named_scope('afmoe/moe/route'):
+        scores = sigmoid_scores(self, x)
     bias = self.variable(MOE_STATE, 'bias', jnp.zeros, (self.num_experts,),
                          jnp.float32)
     last_counts = self.variable(MOE_STATE, 'counts', jnp.zeros,
                                 (self.num_experts,), jnp.int32)
 
     with jax.named_scope('afmoe/moe/route'):
-      scores = jax.nn.sigmoid(jnp.matmul(
-          x.astype(jnp.float32), router, precision=HIGHEST))
       chosen, weights, counts = route(
-          scores, jax.lax.stop_gradient(bias.value), k, self.route_norm,
+          scores.reshape((tokens, self.num_experts)),
+          jax.lax.stop_gradient(bias.value), k, self.route_norm,
           self.route_scale)
       # Slot of each chosen expert among the held ones; ``held`` = absent.
       slot_of = jnp.full((self.num_experts,), held, jnp.int32).at[
@@ -341,13 +360,15 @@ class ExpertLayer(nn.Module):
       picked = _on_rung(k, rungs, rung, x, experts, aux)
     picked = checkpoint_name(picked, PICKED_NAME)
     with jax.named_scope('afmoe/moe/route'):
-      routed = jnp.sum(picked.astype(jnp.float32) *
-                       jnp.where(placed, weights, 0.0)[..., None], axis=1)
+      out = jnp.sum(picked.astype(jnp.float32) *
+                    jnp.where(placed, weights, 0.0)[..., None], axis=1)
 
-    with jax.named_scope('afmoe/moe/shared'):
-      shared = SwiGLU(self.expert_width, self.dtype, self.init_std,
-                      name='shared')(x)
-    out = (shared.astype(jnp.float32) + routed).astype(self.dtype)
+    if self.shared_expert:
+      with jax.named_scope('afmoe/moe/shared'):
+        shared = SwiGLU(self.expert_width, self.dtype, self.init_std,
+                        name='shared')(x)
+      out = shared.astype(jnp.float32) + out
+    out = out.astype(self.dtype)
 
     if train and not self.is_initializing():
       # In a deployment the counts are summed over the data-parallel
@@ -355,6 +376,9 @@ class ExpertLayer(nn.Module):
       bias.value = updated_bias(bias.value, counts, self.load_balance_coeff)
       last_counts.value = counts
     stats = _stats(tokens, is_held, placed, group_sizes, room)
+    if given:
+      stats['weight_e6'] = jnp.round(1e6 * jnp.mean(weights)).astype(
+          jnp.int32)
     return out.reshape(shape), stats
 
 

@@ -346,12 +346,84 @@ def _token_step_holds_what_the_layers_keep(chip, caplog):
           memory.temp_size_in_bytes) <= 13.5e9
 
 
+def _zaya_step_fits_and_runs_attention_once(chip, caplog):
+  # The whole step of ``zaya1-8b.train-packed-8k`` (the trunk from the
+  # cell's own configuration at 2 x 8,192 tokens, loss, gradient, Adam,
+  # the state donated): the attention forward kernel once a layer inside
+  # the CCA latent (8 query heads over 2 of 128, no window), no
+  # conditional (half the experts held: the routed-row buffer has one
+  # rung), and arguments + temporaries at or under 13.5 GB of the chip's
+  # 16 (11.15 GB as of PR 32: 8.50 of state, 2.65 of temporaries).
+  del caplog
+  import json
+  import re
+
+  import optax
+
+  from tensor2robot_tpu.layers import zaya
+
+  with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                         'benchmark/configs/zaya1-8b-ep2.json')) as f:
+    cfg = json.load(f)
+  trunk = zaya.Trunk(
+      vocab_size=cfg['vocab_size'], hidden_size=cfg['hidden_size'],
+      num_layers=cfg['num_hidden_layers'],
+      num_heads=cfg['num_attention_heads'],
+      num_kv_heads=cfg['num_key_value_heads'], head_dim=cfg['head_dim'],
+      conv_taps=cfg['cca_time0'],
+      rotary_dim=int(cfg['head_dim'] * cfg['partial_rotary_factor']),
+      rope_theta=float(cfg['rope_parameters']['hybrid']['rope_theta']),
+      eps=cfg['rms_norm_eps'], router_hidden=cfg['router_hidden_size'],
+      expert_kwargs=dict(
+          num_experts=cfg['num_experts_published'],
+          experts_held=tuple(cfg['experts_held']),
+          experts_per_token=cfg['num_experts_per_tok'],
+          expert_width=cfg['moe_intermediate_size'],
+          load_balance_coeff=cfg['load_balance_coeff']),
+      branch_scale=cfg['branch_scale_init'],
+      router_init_gain=cfg['router_init_gain'], loss_chunk=cfg['loss_chunk'],
+      dtype=jnp.bfloat16, init_std=cfg['init_std'])
+  tokens = jnp.zeros((cfg['batch_size'], cfg['sequence_length']), jnp.int32)
+  optimizer = optax.adam(cfg['learning_rate'])
+
+  def described(tree):
+    return jax.tree_util.tree_map(
+        lambda leaf: chip(leaf.shape, leaf.dtype), tree)
+
+  state = described(jax.eval_shape(
+      lambda key: trunk.init(key, {'tokens': tokens}, False),
+      jax.random.PRNGKey(0)))
+  params = state.pop('params')
+  moments = described(jax.eval_shape(optimizer.init, params))
+
+  def step(params, moments, state, tokens):
+    def loss(p):
+      out, new = trunk.apply({'params': p, **state}, {'tokens': tokens},
+                             True, mutable=list(state))
+      return out['loss'], new
+
+    (value, state), grads = jax.value_and_grad(loss, has_aux=True)(params)
+    updates, moments = optimizer.update(grads, moments, params)
+    return optax.apply_updates(params, updates), moments, state, value
+
+  program = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+      params, moments, state, chip(tokens.shape, jnp.int32)).compile()
+  text = program.as_text()
+  assert len(re.findall(r'custom-call\([^\n]*flash_attention_fwd',
+                        text)) == cfg['num_hidden_layers'] == 6
+  assert ' conditional(' not in text
+  memory = program.memory_analysis()
+  assert (memory.argument_size_in_bytes +
+          memory.temp_size_in_bytes) <= 13.5e9
+
+
 @pytest.mark.parametrize('case', [
     _photometric, _flash_attention, _pool_qtopt_refused, _pool_small_lowers,
     _conv_s2d_refused, _flash_attention_window_grouped,
     _grouped_product_tiles, _expert_layer_ladder,
     _decoder_layer_keeps_attention_residuals,
     _token_step_holds_what_the_layers_keep,
+    _zaya_step_fits_and_runs_attention_once,
 ], ids=lambda fn: fn.__name__.lstrip('_'))
 def test_compiles_for_described_v5e(case, chip, caplog):
   case(chip, caplog)

@@ -154,6 +154,7 @@ def _masked_softmax_attention(q, k, v, window):
     (256, 4, 1, None, 64, 128),    # grouped heads, no window
     (256, 4, 4, 100, None, None),  # default blocks, cut to the window
     (128, 2, 2, 128, 64, 64),      # a window as long as the sequence
+    (256, 8, 2, None, None, None),  # as layers/zaya.py calls it: 8 / 2 heads
 ])
 def test_window_and_grouped_heads_match_masked_softmax(t, heads, kv_heads,
                                                        window, bq, bk):
