@@ -11,9 +11,18 @@ back-to-back ``q·kᵀ`` / ``p·v`` matmuls. Trace-measured on a v5e chip at
 (3.7×), with the gap growing quadratically in T.
 
 Backward follows FlashAttention-2: the forward additionally saves the
-per-row logsumexp ``L``; backward recomputes probabilities blockwise and
-produces dq in a q-block grid and dk/dv in a k-block grid, with
-``D = rowsum(dO ⊙ O)`` precomputed.
+per-row logsumexp ``L``; backward recomputes probabilities blockwise,
+with ``D = rowsum(dO ⊙ O)`` precomputed. The staged kernels produce dq in
+a q-block grid and dk/dv in a k-block grid. The streamed backward is ONE
+kernel (``flash_attention_bwd``) that walks the block pairs once, key
+block innermost: each live pair computes its scores, probabilities, dP
+and dS once and feeds dq (summed over the key blocks in a block of
+scratch) and dk and dv (summed into float32 scratch that holds a whole
+key/value head, written out when the head ends): 5 matrix products a
+pair where a kernel each for dq and dk/dv run 7. That holds while a
+head's dk and dv fit VMEM (``_fuses_backward``: 16,384 tokens at D=128 in
+bfloat16); past it the two streamed kernels (``flash_attention_dq``,
+``flash_attention_dkv``) remain, O(block) in VMEM both.
 
 Constraints (see :func:`is_supported`): ``T`` divisible by the
 (8-aligned) block sizes; head dim ≤ 128. Two implementations behind one
@@ -272,6 +281,60 @@ def _dkv_kernel_streamed(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
+def _bwd_kernel_fused(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
+                      causal, scale, nq, nk, group, window):
+  # Grid (kv head, group x query blocks, key blocks), key block innermost:
+  # dq's order, with the ``group`` query heads of one key/value head
+  # folded into the middle dimension as ``_dkv_kernel_streamed`` walks
+  # them. A live block pair computes s, p, dp, ds once and feeds three
+  # sums: dq over its key blocks, and the key block's rows of the WHOLE
+  # key/value head's dk and dv, which stay in VMEM until the head ends.
+  # For one key block the pairs come in ``_dkv_kernel_streamed``'s order,
+  # for one query block in ``_dq_kernel_streamed``'s: the same sums.
+  r, kb = pl.program_id(1), pl.program_id(2)
+  qb = r % nq
+  bq, bk = q_ref.shape[1], k_ref.shape[1]
+
+  @pl.when(jnp.logical_and(r == 0, kb == 0))
+  def _():
+    dk_scr[...] = jnp.zeros_like(dk_scr)
+    dv_scr[...] = jnp.zeros_like(dv_scr)
+
+  @pl.when(kb == 0)
+  def _():
+    dq_scr[...] = jnp.zeros_like(dq_scr)
+
+  live = _block_live(qb * bq, bq, kb * bk, bk, window) if causal else True
+
+  @pl.when(live)
+  def _():
+    q = q_ref[0]
+    k, v, do = (x[0].astype(q.dtype) for x in (k_ref, v_ref, do_ref))
+    lse = lse_ref[0, 0][:, None]
+    delta = delta_ref[0, 0][:, None]
+    s = _scores(q, k, qb * bq, kb * bk, causal, scale, window)
+    p, ds = _ds_block(s, lse, do, v, delta)
+    ds = ds.astype(q.dtype)
+    dq_scr[...] = dq_scr[...] + jax.lax.dot_general(
+        ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    rows = pl.ds(pl.multiple_of(kb * bk, bk), bk)
+    dv_scr[rows, :] = dv_scr[rows, :] + jax.lax.dot_general(
+        p.astype(q.dtype), do, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    dk_scr[rows, :] = dk_scr[rows, :] + jax.lax.dot_general(
+        ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+
+  @pl.when(kb == nk - 1)
+  def _():
+    dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+
+  @pl.when(jnp.logical_and(r == group * nq - 1, kb == nk - 1))
+  def _():
+    dk_ref[0] = (dk_scr[...] * scale).astype(dk_ref.dtype)
+    dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
 # ---------------------------------------------------------------- backward
 
 
@@ -370,7 +433,12 @@ def _use_streamed(t: int, d: int, itemsize: int = 2) -> bool:
 # 256/512 → 187.6 ms, 512/512 → 146.0, 512/1024 → 91.3, 1024/1024 →
 # 75.5 ms (2.5×); 2048/2048 fails Mosaic compile (VMEM). The staged
 # kernels keep the smaller q blocks so whole-KV staging + accumulators
-# fit VMEM.
+# fit VMEM. The streamed backward shares these blocks, as one kernel
+# where ``_fuses_backward`` says so and as dq + dk/dv beyond: at
+# [1, 8192, 32/4, 128] bf16 causal the fused kernel read 8.10 us a live
+# 1024/1024 pair against the two kernels' 10.82, and 8.11 at 512/1024,
+# 7.88 at 1024/512 (where the forward reads 7.09 ms for 4.87) and 8.57 at
+# 512/512, each for the same area (v5e, PERF.md PR 33).
 _STREAMED_BLOCKS = (1024, 512, 256, 128, 64, 32, 16, 8)
 
 
@@ -378,6 +446,23 @@ def _streams(t: int, d: int, itemsize: int, group: int = 1,
              window: Optional[int] = None) -> bool:
   """Grouped heads and a window exist in the streamed kernels only."""
   return _use_streamed(t, d, itemsize) or group > 1 or window is not None
+
+
+# The streamed backward is one kernel while a key/value head's dk and dv
+# stay in VMEM for the head's whole walk: their float32 sums [t, d] and
+# the two out blocks they are written to at its end (double-buffered).
+# 16 MiB at 8,192 x 128 in bfloat16; a v5e core holds 128 MiB, of which
+# the [block, block] float32 intermediates take some 30. Past the budget
+# (65,536 x 64: 64 MiB) dq and dk/dv keep a kernel each, O(block) both.
+_MAX_RESIDENT_DKV_BYTES = 32 * 1024 * 1024
+
+
+def _resident_dkv_bytes(t: int, d: int, itemsize: int) -> int:
+  return 2 * t * d * (4 + 2 * itemsize)
+
+
+def _fuses_backward(t: int, d: int, itemsize: int = 2) -> bool:
+  return _resident_dkv_bytes(t, d, itemsize) <= _MAX_RESIDENT_DKV_BYTES
 
 
 def _resolve_blocks(t: int, d: int, block_q: Optional[int],
@@ -558,6 +643,65 @@ def _flash_fwd(q, k, v, causal, block_q, block_k, window=None):
   return _unfold_heads(out, b, h), (qr, kr, vr, out, lse, (b, t, h, d))
 
 
+def _fused_bwd_call(q, k, v, do, lse, delta, causal, bq, bk, group, window):
+  """(dq, dk, dv), folded, from ``_bwd_kernel_fused``."""
+  bh, t, d = q.shape
+  bhkv, itemsize = k.shape[0], q.dtype.itemsize
+  nq, nk = t // bq, t // bk
+
+  def q_map(i, r, g):
+    return (i * group + r // nq, r % nq, 0)
+
+  def row_map(i, r, g):
+    return (i * group + r // nq, 0, r % nq)
+
+  def kv_map(i, r, g):
+    if causal:  # a dead block re-reads a live one: no copy is made
+      first, last = _live_key_blocks(r % nq, bq, bk, nk, window)
+      g = jnp.clip(g, first, last)
+    return (i, g, 0)
+
+  def head_map(i, r, g):
+    return (i, 0, 0)
+
+  kern = functools.partial(_bwd_kernel_fused, causal=causal,
+                           scale=1.0 / np.sqrt(d), nq=nq, nk=nk, group=group,
+                           window=window)
+  # What the kernel holds: dk and dv resident, the [bq, bk] float32
+  # intermediates (s, p, dp, ds, their casts and transposes), the
+  # double-buffered blocks and dq's sum.
+  vmem = (_resident_dkv_bytes(t, d, itemsize) + 8 * bq * bk * 4 +
+          (6 * bq + 4 * bk) * d * itemsize + bq * d * 4 + 4 * 8 * bq * 4)
+  return pl.pallas_call(
+      kern,
+      grid=(bhkv, group * nq, nk),
+      in_specs=[
+          pl.BlockSpec((1, bq, d), q_map),
+          pl.BlockSpec((1, bk, d), kv_map),
+          pl.BlockSpec((1, bk, d), kv_map),
+          pl.BlockSpec((1, bq, d), q_map),
+          pl.BlockSpec((1, 1, bq), row_map),
+          pl.BlockSpec((1, 1, bq), row_map),
+      ],
+      out_specs=[
+          pl.BlockSpec((1, bq, d), q_map),
+          pl.BlockSpec((1, t, d), head_map),
+          pl.BlockSpec((1, t, d), head_map),
+      ],
+      out_shape=[
+          jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+          jax.ShapeDtypeStruct((bhkv, t, d), k.dtype),
+          jax.ShapeDtypeStruct((bhkv, t, d), v.dtype),
+      ],
+      scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                      pltpu.VMEM((t, d), jnp.float32),
+                      pltpu.VMEM((t, d), jnp.float32)],
+      compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
+      interpret=_use_interpret(),
+      name='flash_attention_bwd',
+  )(q, k, v, do, lse, delta)
+
+
 def _flash_bwd(causal, block_q, block_k, window, res, g):
   qr, kr, vr, out, lse, (b, t, h, d) = res
   group = qr.shape[0] // kr.shape[0]
@@ -569,6 +713,12 @@ def _flash_bwd(causal, block_q, block_k, window, res, g):
   bhkv = kr.shape[0]
   delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                   axis=-1)[:, None, :]  # [bh, 1, t]
+
+  if streamed and _fuses_backward(t, d, qr.dtype.itemsize):
+    dq, dk, dv = _fused_bwd_call(qr, kr, vr, do, lse, delta, causal, bq, bk,
+                                 group, window)
+    return (_unfold_heads(dq, b, h), _unfold_heads(dk, b, h // group),
+            _unfold_heads(dv, b, h // group))
 
   if streamed:
     nk, nq = t // bk, t // bq

@@ -135,21 +135,37 @@ def _conv_s2d_refused(chip, caplog):
       chip(IMAGE, jnp.bfloat16), chip(CONV1_W, jnp.bfloat16))
 
 
+def _attention_kernels(text):
+  """Custom calls of the compiled program by attention kernel."""
+  import re
+
+  return {name: len(re.findall(
+      rf'custom-call\([^\n]*flash_attention_{name}\b', text))
+          for name in ('fwd', 'bwd', 'dq', 'dkv')}
+
+
 def _flash_attention_window_grouped(chip, caplog):
-  # The token policy's attention at its published widths: 8,192 tokens,
-  # 32 query heads over 4 key/value heads of 128 in bfloat16, a
-  # 2,048 window and the full causal layer.
+  # Both token policies' attention at their published widths, 8,192
+  # tokens of bfloat16 heads of 128: 32 query heads over 4 key/value
+  # heads under a 2,048 window and full causal (afmoe), two sequences of
+  # 8 over 2 full causal (the CCA latent). The way back is ONE kernel,
+  # under the ``vmem_limit_bytes`` it asks for (a head's float32 dk and
+  # dv stay in VMEM: 8 MiB, with out blocks as large again).
   del caplog
-  q = chip((1, 8192, 32, 128), jnp.bfloat16)
-  kv = chip((1, 8192, 4, 128), jnp.bfloat16)
-  for window in (2048, None):
+  for batch, heads, kv_heads, window in ((1, 32, 4, 2048), (1, 32, 4, None),
+                                         (2, 8, 2, None)):
+    q = chip((batch, 8192, heads, 128), jnp.bfloat16)
+    kv = chip((batch, 8192, kv_heads, 128), jnp.bfloat16)
 
     def loss(q, k, v, window=window):
       out = flash_attention.flash_attention(q, k, v, True, None, None,
                                             window)
       return out.astype(jnp.float32).sum()
 
-    assert _custom_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    assert text.count('tpu_custom_call') == 2
+    assert _attention_kernels(text) == dict(fwd=1, bwd=1, dq=0, dkv=0)
 
 
 def _grouped_product_tiles(chip, caplog):
@@ -237,8 +253,6 @@ def _decoder_layer_keeps_attention_residuals(chip, caplog):
   # keeping nothing: their peak is in the dense MLP's way back, after
   # attention's part is spent.
   del caplog
-  import re
-
   import flax.linen as nn
 
   from tensor2robot_tpu.layers import afmoe
@@ -261,9 +275,8 @@ def _decoder_layer_keeps_attention_residuals(chip, caplog):
 
     program = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
         params, h, weight).compile()
-    forward = re.findall(r'custom-call\([^\n]*flash_attention_fwd',
-                         program.as_text())
-    return len(forward), program.memory_analysis().temp_size_in_bytes
+    return (_attention_kernels(program.as_text())['fwd'],
+            program.memory_analysis().temp_size_in_bytes)
 
   for kind in (afmoe.SLIDING, afmoe.FULL):
     kept_calls, kept_bytes = compiled(kind, afmoe.KEPT_IN_LAYER)
@@ -275,7 +288,8 @@ def _decoder_layer_keeps_attention_residuals(chip, caplog):
 def _token_step_holds_what_the_layers_keep(chip, caplog):
   # The whole step of ``trinity-mini.train-packed-8k`` (the trunk from
   # the cell's own configuration, loss, gradient, Adam, the state
-  # donated): the forward kernel once a layer and each expert layer's
+  # donated): the forward kernel and the one backward kernel once a layer
+  # (no ``flash_attention_dq`` / ``_dkv``) and each expert layer's
   # two conditionals, no recomputed forward of either, and arguments +
   # temporaries at or under 13.5 GB of the chip's 16. What the layers'
   # remat keeps lives in the temporaries (12.85 GB in all with
@@ -338,8 +352,8 @@ def _token_step_holds_what_the_layers_keep(chip, caplog):
       params, moments, state, chip(tokens.shape, jnp.int32)).compile()
   text = program.as_text()
   sparse = len(kinds) - cfg['num_dense_layers']
-  assert len(re.findall(r'custom-call\([^\n]*flash_attention_fwd',
-                        text)) == len(kinds)
+  assert _attention_kernels(text) == dict(
+      fwd=len(kinds), bwd=len(kinds), dq=0, dkv=0)
   assert len(re.findall(r' conditional\(', text)) == 2 * sparse
   memory = program.memory_analysis()
   assert (memory.argument_size_in_bytes +
@@ -349,14 +363,14 @@ def _token_step_holds_what_the_layers_keep(chip, caplog):
 def _zaya_step_fits_and_runs_attention_once(chip, caplog):
   # The whole step of ``zaya1-8b.train-packed-8k`` (the trunk from the
   # cell's own configuration at 2 x 8,192 tokens, loss, gradient, Adam,
-  # the state donated): the attention forward kernel once a layer inside
-  # the CCA latent (8 query heads over 2 of 128, no window), no
+  # the state donated): the attention forward kernel and the one backward
+  # kernel once a layer inside the CCA latent (8 query heads over 2 of
+  # 128, no window), no
   # conditional (half the experts held: the routed-row buffer has one
   # rung), and arguments + temporaries at or under 13.5 GB of the chip's
   # 16 (11.15 GB as of PR 32: 8.50 of state, 2.65 of temporaries).
   del caplog
   import json
-  import re
 
   import optax
 
@@ -409,8 +423,8 @@ def _zaya_step_fits_and_runs_attention_once(chip, caplog):
   program = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
       params, moments, state, chip(tokens.shape, jnp.int32)).compile()
   text = program.as_text()
-  assert len(re.findall(r'custom-call\([^\n]*flash_attention_fwd',
-                        text)) == cfg['num_hidden_layers'] == 6
+  assert cfg['num_hidden_layers'] == 6
+  assert _attention_kernels(text) == dict(fwd=6, bwd=6, dq=0, dkv=0)
   assert ' conditional(' not in text
   memory = program.memory_analysis()
   assert (memory.argument_size_in_bytes +

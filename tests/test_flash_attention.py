@@ -256,19 +256,102 @@ def test_residual_names_engage_under_a_policy_that_names_them_only(
 
   names = jax.checkpoint_policies.save_only_these_names(
       fa.OUT_NAME, fa.LSE_NAME)
-  # The forward kernel, dq and dk/dv; remat's second forward where it runs.
-  variants = [(loss, 3), (jax.checkpoint(loss), 4),
-              (jax.checkpoint(loss, policy=names), 3)]
+  # The way back: the staged dq and dk/dv, or the streamed kernels' one.
+  backward = 1 if streamed else 2
+  # The forward kernel once; remat's second forward where it runs.
+  variants = [(loss, 1), (jax.checkpoint(loss), 2),
+              (jax.checkpoint(loss, policy=names), 1)]
   results = []
-  for fn, kernel_calls in variants:
+  for fn, forward in variants:
     fn = jax.value_and_grad(fn, (0, 1, 2))
     text = str(jax.make_jaxpr(fn)(q, k, v))
-    assert text.count('pallas_call[') == kernel_calls
+    assert text.count('pallas_call[') == forward + backward
     if streamed:
-      assert text.count('name=flash_attention_fwd') == kernel_calls - 2
+      assert text.count('name=flash_attention_fwd') == forward
+      assert text.count('name=flash_attention_bwd') == 1
     results.append(fn(q, k, v))
   (want, want_grads) = results[0]
   for value, grads in results[1:]:
     assert float(value) == float(want)
     for g, w in zip(grads, want_grads):
       np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+# ------------------------------------- the streamed backward in one kernel
+
+
+def _streamed_grads(fa, q, k, v, ct, window, bq, bk):
+  fn = jax.grad(lambda *a: jnp.sum(
+      fa.flash_attention(*a, True, bq, bk, window).astype(jnp.float32) * ct),
+                (0, 1, 2))
+  return fn(q, k, v), str(jax.make_jaxpr(fn)(q, k, v))
+
+
+@pytest.mark.parametrize('batch,t,heads,kv_heads,d,window,bq,bk,dtype', [
+    (2, 256, 8, 2, 16, None, 64, 64, jnp.float32),     # full causal, 8:2
+    (1, 256, 32, 4, 16, None, 64, 64, jnp.bfloat16),   # 32:4 as afmoe's
+    (2, 256, 8, 2, 16, 64, 64, 64, jnp.bfloat16),      # window < t, a block
+    (1, 512, 8, 2, 16, 128, 64, 64, jnp.float32),      # window = 2 blocks
+    (2, 256, 4, 2, 32, 100, 64, 64, jnp.bfloat16),     # no block multiple
+    (1, 256, 8, 2, 16, None, 32, 64, jnp.float32),     # bq < bk
+    (1, 256, 4, 1, 16, 96, 128, 64, jnp.bfloat16),     # bq > bk, a window
+    # Query blocks in the middle have dead key blocks behind (the window)
+    # and ahead (the diagonal): both ends of the clipped index maps.
+    (2, 512, 4, 2, 16, 96, 32, 64, jnp.float32),
+    (1, 256, 2, 2, 16, 128, 64, 64, jnp.float32),      # a window, no groups
+    (1, 256, 8, 2, 16, None, None, None, jnp.bfloat16),  # default blocks
+])
+def test_fused_backward_is_the_two_kernels_and_the_reference(
+    batch, t, heads, kv_heads, d, window, bq, bk, dtype, monkeypatch):
+  """One kernel gives dq, dk and dv: the two streamed kernels' bits (the
+  same sums in the same order, interpret mode) and the masked-softmax
+  reference's gradients to the dtype's rounding."""
+  from tensor2robot_tpu.ops import flash_attention as fa
+
+  rng = np.random.RandomState(11)
+  q, k, v = (jnp.asarray(rng.randn(batch, t, h, d), dtype)
+             for h in (heads, kv_heads, kv_heads))
+  ct = jnp.asarray(rng.randn(batch, t, heads, d), jnp.float32)
+  got, text = _streamed_grads(fa, q, k, v, ct, window, bq, bk)
+  assert text.count('name=flash_attention_bwd') == 1
+  assert 'name=flash_attention_dq' not in text
+  monkeypatch.setattr(fa, '_MAX_RESIDENT_DKV_BYTES', 0)
+  two, text = _streamed_grads(fa, q, k, v, ct, window, bq, bk)
+  assert 'name=flash_attention_bwd' not in text
+  assert (text.count('name=flash_attention_dq'),
+          text.count('name=flash_attention_dkv')) == (1, 1)
+  want = jax.grad(lambda *a: jnp.sum(_masked_softmax_attention(
+      *(x.astype(jnp.float32) for x in a), window) * ct), (0, 1, 2))(q, k, v)
+  atol = 5e-4 if dtype == jnp.float32 else 0.15
+  for g, w, r in zip(got, two, want):
+    assert g.dtype == dtype and g.shape == r.shape
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(r),
+                               atol=atol)
+
+
+@pytest.mark.parametrize('t,d,itemsize,fused', [
+    (8192, 128, 2, True),     # both token cells' sequences
+    (8192, 128, 4, True),
+    (16384, 128, 2, True),
+    (65536, 64, 2, False),    # dk and dv of a head would be 64 MiB
+    (32768, 128, 2, False),
+])
+def test_backward_fuses_while_a_heads_dk_and_dv_fit(t, d, itemsize, fused):
+  from tensor2robot_tpu.ops import flash_attention as fa
+
+  assert fa._streams(t, d, itemsize, group=4)
+  assert fa._fuses_backward(t, d, itemsize) == fused
+
+
+def test_staged_backward_keeps_its_two_kernels():
+  """Small ``t x d`` with one head count and no window stages K/V whole:
+  no streamed kernel, fused or not, is on that path."""
+  from tensor2robot_tpu.ops import flash_attention as fa
+
+  assert not fa._streams(4096, 64, 2)
+  q, k, v = _qkv((1, 256, 2, 16), seed=12)
+  ct = jnp.ones((1, 256, 2, 16), jnp.float32)
+  _, text = _streamed_grads(fa, q, k, v, ct, None, 64, 64)
+  assert text.count('pallas_call[') == 3
+  assert 'name=flash_attention_' not in text
