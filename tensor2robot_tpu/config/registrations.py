@@ -107,6 +107,8 @@ def register() -> None:
   from tensor2robot_tpu.research.token_policy import (
       afmoe_model as token_policy_lib)
   from tensor2robot_tpu.research.token_policy import (
+      glm_model as glm_policy_lib)
+  from tensor2robot_tpu.research.token_policy import (
       zaya_model as zaya_policy_lib)
   from tensor2robot_tpu.research import vrgripper as vrgripper_lib
 
@@ -126,6 +128,7 @@ def register() -> None:
   reg(grasp2vec_lib.Grasp2VecModel, 'Grasp2VecModel')
   reg(token_policy_lib.AfmoeTokenPolicyModel, 'AfmoeTokenPolicyModel')
   reg(zaya_policy_lib.ZayaTokenPolicyModel, 'ZayaTokenPolicyModel')
+  reg(glm_policy_lib.GlmTokenPolicyModel, 'GlmTokenPolicyModel')
   reg(vrgripper_lib.VRGripperRegressionModel, 'VRGripperRegressionModel')
   reg(vrgripper_lib.VRGripperDomainAdaptiveModel,
       'VRGripperDomainAdaptiveModel')

@@ -229,13 +229,15 @@ class Trunk(nn.Module):
     return outputs
 
 
-def next_token_loss(h, head, tokens, chunk: int, dtype):
-  """Mean cross-entropy of position i's prediction of token i+1 over
-  each sequence's first S-1 positions, a ``chunk`` of positions at a
+def next_token_loss(h, head, tokens, chunk: int, dtype, shift: int = 1):
+  """Mean cross-entropy of position i's prediction of token i + ``shift``
+  over each sequence's first S - ``shift`` positions (``shift`` 2: a
+  multi-token-prediction module's pass), a ``chunk`` of positions at a
   time; log-softmax in float32."""
   b, s, d = h.shape
-  labels = jnp.roll(tokens, -1, axis=1).reshape(b * s)
-  counted = jnp.broadcast_to(jnp.arange(s) < s - 1, (b, s)).reshape(b * s)
+  labels = jnp.roll(tokens, -shift, axis=1).reshape(b * s)
+  counted = jnp.broadcast_to(jnp.arange(s) < s - shift,
+                             (b, s)).reshape(b * s)
   rows = b * s
   chunk = min(chunk, rows)
   if rows % chunk:
@@ -253,4 +255,4 @@ def next_token_loss(h, head, tokens, chunk: int, dtype):
   parts = jax.lax.map(one_chunk, (
       h.reshape(rows // chunk, chunk, d), labels.reshape(rows // chunk, chunk),
       counted.reshape(rows // chunk, chunk)))
-  return jnp.sum(parts) / (b * (s - 1))
+  return jnp.sum(parts) / (b * (s - shift))

@@ -148,7 +148,8 @@ def route(scores, bias, experts_per_token: int, route_norm: bool,
 def sigmoid_scores(layer: 'ExpertLayer', x):
   """afmoe's router, a matrix of ``layer``'s own (``router``):
   ``sigmoid(x Wr)`` in float32."""
-  router = layer.param('router', normal_init(layer.init_std),
+  std = layer.init_std if layer.router_std is None else layer.router_std
+  router = layer.param('router', normal_init(std),
                        (x.shape[-1], layer.num_experts))
   return jax.nn.sigmoid(jnp.matmul(x.astype(jnp.float32), router,
                                    precision=HIGHEST))
@@ -307,6 +308,7 @@ class ExpertLayer(nn.Module):
   dtype: Any = jnp.float32
   init_std: float = 0.02
   shared_expert: bool = True
+  router_std: Optional[float] = None   # of ``sigmoid_scores``' matrix
 
   @nn.compact
   def __call__(self, x, train: bool = False, scores=None):

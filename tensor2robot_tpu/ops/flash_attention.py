@@ -20,15 +20,19 @@ and dS once and feeds dq (summed over the key blocks in a block of
 scratch) and dk and dv (summed into float32 scratch that holds a whole
 key/value head, written out when the head ends): 5 matrix products a
 pair where a kernel each for dq and dk/dv run 7. That holds while a
-head's dk and dv fit VMEM (``_fuses_backward``: 16,384 tokens at D=128 in
-bfloat16); past it the two streamed kernels (``flash_attention_dq``,
-``flash_attention_dkv``) remain, O(block) in VMEM both.
+head's dk and dv fit VMEM (``_fuses_backward``: 16,384 tokens at D=128,
+8,192 at D=256, in bfloat16); past it the two streamed kernels
+(``flash_attention_dq``, ``flash_attention_dkv``) remain, O(block) in
+VMEM both.
 
 Constraints (see :func:`is_supported`): ``T`` divisible by the
-(8-aligned) block sizes; head dim ≤ 128. Two implementations behind one
-API: up to ``T·D ≤ 2M`` elements (~32k tokens at D=64) the per-sequence
-K/V are staged into VMEM wholesale (fewer DMAs, dynamic causal
-early-exit); past that the streamed kernels take over — K/V blocks
+(8-aligned) block sizes; head dim ≤ 256, whole lane tiles (a multiple
+of 128) past 128: latent attention's heads are 192 content + 64 rotary
+dimensions wide. Two implementations behind one API: up to
+``T·D ≤ 2M`` elements (~32k tokens at D=64; half of that past one lane
+tile a head, ``_use_streamed``) the per-sequence K/V are staged
+into VMEM wholesale (fewer DMAs, dynamic causal early-exit); past that
+the streamed kernels take over — K/V blocks
 become an inner sequential grid dimension with the flash accumulators in
 VMEM scratch, so memory is O(block) and T is bounded only by HBM. Runs
 in interpret mode off-TPU so the CPU-mesh test suite exercises the same
@@ -423,9 +427,15 @@ DEFAULT_BLOCK_K = 512
 # element) budget: float32 q/k/v halves the staged-T range vs bfloat16.
 _MAX_STAGED_KV_BYTES = 8 * 1024 * 1024
 
+# Heads are at most two lane tiles wide, whole tiles past one.
+MAX_HEAD_DIM = 256
+
 
 def _use_streamed(t: int, d: int, itemsize: int = 2) -> bool:
-  return 2 * t * d * itemsize > _MAX_STAGED_KV_BYTES
+  # The staged kernels compute in float32 throughout: past one lane tile
+  # a head their [block, d] float32 blocks and accumulators double
+  # beside the staged K/V, whose budget halves.
+  return 2 * t * d * itemsize > _MAX_STAGED_KV_BYTES // max(1, d // 128)
 
 
 # Streamed-regime default tile: much larger than the staged default.
@@ -497,7 +507,9 @@ def is_supported(t: int, d: int, block_q: Optional[int] = None,
   ``dtype.itemsize`` so the staged/streamed regime (a VMEM *byte*
   budget) resolves exactly as the kernel will — the default 2 models
   bfloat16, and float32 inputs with T·D in the (1M, 2M] band stream
-  where bf16 would stage.
+  where bf16 would stage. The head dim is a multiple of 8 up to one lane
+  tile (128), or two whole tiles (``MAX_HEAD_DIM`` 256: a latent-attention
+  head of 192 content + 64 rotary dimensions).
 
   On a real TPU the blocks must additionally be at least a lane tile
   (128): the logsumexp output places the q-block dim in lanes, and
@@ -511,15 +523,16 @@ def is_supported(t: int, d: int, block_q: Optional[int] = None,
   block_q, block_k = _resolve_blocks(t, d, block_q, block_k, itemsize)
   bq, bk = min(block_q, t), min(block_k, t)
   min_block = dispatch.min_lane_block(interpret)
-  return (0 < d <= 128 and d % 8 == 0 and
+  return (0 < d <= MAX_HEAD_DIM and d % (8 if d <= 128 else 128) == 0 and
           t % bq == 0 and t % bk == 0 and
           bq % min_block == 0 and bk % min_block == 0)
 
 
 def _check(q, block_q, block_k):
   b, t, h, d = q.shape
-  if d > 128:
-    raise ValueError(f'flash_attention requires head dim <= 128, got {d}')
+  if d > MAX_HEAD_DIM:
+    raise ValueError(
+        f'flash_attention requires head dim <= {MAX_HEAD_DIM}, got {d}')
   block_q, block_k = _resolve_blocks(t, d, block_q, block_k,
                                      q.dtype.itemsize)
   bq, bk = min(block_q, t), min(block_k, t)
@@ -578,6 +591,12 @@ def _flash_call(q, k, v, causal, bq, bk, group, streamed, window):
 
     kern = functools.partial(_fwd_kernel_streamed, causal=causal,
                              scale=scale, nk=nk, window=window)
+    # Two lane tiles a head outgrow the compiler's own VMEM bound: the
+    # [bq, bk] float32 intermediates, the double-buffered blocks and the
+    # accumulator. (One tile asks for nothing and lowers as it always did.)
+    vmem = None if d <= 128 else pltpu.CompilerParams(vmem_limit_bytes=(
+        6 * bq * bk * 4 + 4 * (bq + bk) * d * q.dtype.itemsize +
+        2 * bq * d * 4))
     return pl.pallas_call(
         kern,
         grid=(bh, t // bq, nk),
@@ -601,6 +620,7 @@ def _flash_call(q, k, v, causal, bq, bk, group, streamed, window):
         ],
         interpret=_use_interpret(),
         name='flash_attention_fwd',
+        compiler_params=vmem,
     )(q, k, v)
   kern = functools.partial(_fwd_kernel, bk=bk, causal=causal, scale=scale)
   return pl.pallas_call(

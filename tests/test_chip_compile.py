@@ -285,46 +285,12 @@ def _decoder_layer_keeps_attention_residuals(chip, caplog):
     assert kept_bytes <= nothing_kept_bytes + 80 * 2**20, kind
 
 
-def _token_step_holds_what_the_layers_keep(chip, caplog):
-  # The whole step of ``trinity-mini.train-packed-8k`` (the trunk from
-  # the cell's own configuration, loss, gradient, Adam, the state
-  # donated): the forward kernel and the one backward kernel once a layer
-  # (no ``flash_attention_dq`` / ``_dkv``) and each expert layer's
-  # two conditionals, no recomputed forward of either, and arguments +
-  # temporaries at or under 13.5 GB of the chip's 16. What the layers'
-  # remat keeps lives in the temporaries (12.85 GB in all with
-  # ``KEPT_NAMES`` as of PR 31, 11.04 with nothing kept), which the
-  # chip's ``memory_stats()`` peak does not show: this is the place a
-  # further kept name meets its budget.
-  del caplog
-  import json
-  import re
-
+def _compiled_token_step(chip, trunk, cfg):
+  """A token policy's whole step compiled for the described chip: the
+  trunk's loss at the cell's batch and sequence, its gradient, Adam, the
+  state donated."""
   import optax
 
-  from tensor2robot_tpu.layers import afmoe
-
-  with open(os.path.join(os.path.dirname(__file__), os.pardir,
-                         'benchmark/configs/trinity-mini-ep8.json')) as f:
-    cfg = json.load(f)
-  kinds = tuple(cfg['layer_types'][i] for i in cfg['layers_kept'])
-  trunk = afmoe.Trunk(
-      vocab_size=cfg['vocab_size'], hidden_size=cfg['hidden_size'],
-      layer_types=kinds, num_dense_layers=cfg['num_dense_layers'],
-      num_heads=cfg['num_attention_heads'],
-      num_kv_heads=cfg['num_key_value_heads'], head_dim=cfg['head_dim'],
-      sliding_window=cfg['sliding_window'],
-      rope_theta=float(cfg['rope_theta']), eps=cfg['rms_norm_eps'],
-      dense_width=cfg['intermediate_size'],
-      expert_kwargs=dict(
-          num_experts=cfg['num_experts_published'],
-          experts_held=tuple(cfg['experts_held']),
-          experts_per_token=cfg['num_experts_per_tok'],
-          expert_width=cfg['moe_intermediate_size'],
-          route_norm=cfg['route_norm'], route_scale=cfg['route_scale'],
-          load_balance_coeff=cfg['load_balance_coeff']),
-      mup_enabled=cfg['mup_enabled'], loss_chunk=cfg['loss_chunk'],
-      dtype=jnp.bfloat16, init_std=cfg['init_std'])
   tokens = jnp.zeros((cfg['batch_size'], cfg['sequence_length']), jnp.int32)
   optimizer = optax.adam(cfg['learning_rate'])
 
@@ -348,8 +314,54 @@ def _token_step_holds_what_the_layers_keep(chip, caplog):
     updates, moments = optimizer.update(grads, moments, params)
     return optax.apply_updates(params, updates), moments, state, value
 
-  program = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+  return jax.jit(step, donate_argnums=(0, 1, 2)).lower(
       params, moments, state, chip(tokens.shape, jnp.int32)).compile()
+
+
+def _cell_config(name):
+  import json
+
+  with open(os.path.join(os.path.dirname(__file__), os.pardir,
+                         f'benchmark/configs/{name}.json')) as f:
+    return json.load(f)
+
+
+def _token_step_holds_what_the_layers_keep(chip, caplog):
+  # The whole step of ``trinity-mini.train-packed-8k`` (the trunk from
+  # the cell's own configuration, loss, gradient, Adam, the state
+  # donated): the forward kernel and the one backward kernel once a layer
+  # (no ``flash_attention_dq`` / ``_dkv``) and each expert layer's
+  # two conditionals, no recomputed forward of either, and arguments +
+  # temporaries at or under 13.5 GB of the chip's 16. What the layers'
+  # remat keeps lives in the temporaries (12.85 GB in all with
+  # ``KEPT_NAMES`` as of PR 31, 11.04 with nothing kept), which the
+  # chip's ``memory_stats()`` peak does not show: this is the place a
+  # further kept name meets its budget.
+  del caplog
+  import re
+
+  from tensor2robot_tpu.layers import afmoe
+
+  cfg = _cell_config('trinity-mini-ep8')
+  kinds = tuple(cfg['layer_types'][i] for i in cfg['layers_kept'])
+  trunk = afmoe.Trunk(
+      vocab_size=cfg['vocab_size'], hidden_size=cfg['hidden_size'],
+      layer_types=kinds, num_dense_layers=cfg['num_dense_layers'],
+      num_heads=cfg['num_attention_heads'],
+      num_kv_heads=cfg['num_key_value_heads'], head_dim=cfg['head_dim'],
+      sliding_window=cfg['sliding_window'],
+      rope_theta=float(cfg['rope_theta']), eps=cfg['rms_norm_eps'],
+      dense_width=cfg['intermediate_size'],
+      expert_kwargs=dict(
+          num_experts=cfg['num_experts_published'],
+          experts_held=tuple(cfg['experts_held']),
+          experts_per_token=cfg['num_experts_per_tok'],
+          expert_width=cfg['moe_intermediate_size'],
+          route_norm=cfg['route_norm'], route_scale=cfg['route_scale'],
+          load_balance_coeff=cfg['load_balance_coeff']),
+      mup_enabled=cfg['mup_enabled'], loss_chunk=cfg['loss_chunk'],
+      dtype=jnp.bfloat16, init_std=cfg['init_std'])
+  program = _compiled_token_step(chip, trunk, cfg)
   text = program.as_text()
   sparse = len(kinds) - cfg['num_dense_layers']
   assert _attention_kernels(text) == dict(
@@ -370,15 +382,9 @@ def _zaya_step_fits_and_runs_attention_once(chip, caplog):
   # rung), and arguments + temporaries at or under 13.5 GB of the chip's
   # 16 (11.15 GB as of PR 32: 8.50 of state, 2.65 of temporaries).
   del caplog
-  import json
-
-  import optax
-
   from tensor2robot_tpu.layers import zaya
 
-  with open(os.path.join(os.path.dirname(__file__), os.pardir,
-                         'benchmark/configs/zaya1-8b-ep2.json')) as f:
-    cfg = json.load(f)
+  cfg = _cell_config('zaya1-8b-ep2')
   trunk = zaya.Trunk(
       vocab_size=cfg['vocab_size'], hidden_size=cfg['hidden_size'],
       num_layers=cfg['num_hidden_layers'],
@@ -397,36 +403,66 @@ def _zaya_step_fits_and_runs_attention_once(chip, caplog):
       branch_scale=cfg['branch_scale_init'],
       router_init_gain=cfg['router_init_gain'], loss_chunk=cfg['loss_chunk'],
       dtype=jnp.bfloat16, init_std=cfg['init_std'])
-  tokens = jnp.zeros((cfg['batch_size'], cfg['sequence_length']), jnp.int32)
-  optimizer = optax.adam(cfg['learning_rate'])
-
-  def described(tree):
-    return jax.tree_util.tree_map(
-        lambda leaf: chip(leaf.shape, leaf.dtype), tree)
-
-  state = described(jax.eval_shape(
-      lambda key: trunk.init(key, {'tokens': tokens}, False),
-      jax.random.PRNGKey(0)))
-  params = state.pop('params')
-  moments = described(jax.eval_shape(optimizer.init, params))
-
-  def step(params, moments, state, tokens):
-    def loss(p):
-      out, new = trunk.apply({'params': p, **state}, {'tokens': tokens},
-                             True, mutable=list(state))
-      return out['loss'], new
-
-    (value, state), grads = jax.value_and_grad(loss, has_aux=True)(params)
-    updates, moments = optimizer.update(grads, moments, params)
-    return optax.apply_updates(params, updates), moments, state, value
-
-  program = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
-      params, moments, state, chip(tokens.shape, jnp.int32)).compile()
+  program = _compiled_token_step(chip, trunk, cfg)
   text = program.as_text()
   assert cfg['num_hidden_layers'] == 6
   assert _attention_kernels(text) == dict(fwd=6, bwd=6, dq=0, dkv=0)
   assert ' conditional(' not in text
   memory = program.memory_analysis()
+  assert (memory.argument_size_in_bytes +
+          memory.temp_size_in_bytes) <= 13.5e9
+
+
+def _flash_attention_two_lane_tiles(chip, caplog):
+  # Latent attention's heads at the third token policy's published widths:
+  # 8,192 tokens of 20 bfloat16 heads of 256 (192 content + 64 rotary for
+  # q and k, 256 of value), full causal. Streamed at 1,024 x 1,024 blocks
+  # under the VMEM limits the two calls ask for; the way back is ONE
+  # kernel with a head's float32 dk and dv resident (16 MiB, at the
+  # budget's edge with their out blocks).
+  del caplog
+  assert flash_attention.is_supported(8192, 256, interpret=False)
+  qkv = chip((1, 8192, 20, 256), jnp.bfloat16)
+
+  def loss(q, k, v):
+    out = flash_attention.flash_attention(q, k, v, True, None, None, None)
+    return out.astype(jnp.float32).sum()
+
+  text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+      qkv, qkv, qkv).compile().as_text()
+  assert text.count('tpu_custom_call') == 2
+  assert _attention_kernels(text) == dict(fwd=1, bwd=1, dq=0, dkv=0)
+
+
+def _glm_step_fits_and_runs_attention_once(chip, caplog):
+  # The whole step of ``glm-4.7-flash.train-packed-8k`` (the trunk from
+  # the cell's own configuration at 8,192 tokens, both losses, gradient,
+  # Adam, the state donated): the attention forward kernel and the ONE
+  # backward kernel once a decoder layer at 20 heads of 256 (five layers
+  # and the MTP module's: six of each, no ``flash_attention_dq`` /
+  # ``_dkv``), each of the five expert layers' two conditionals (8 held of
+  # 64: two rungs), and arguments + temporaries at or under 13.5 GB of the
+  # chip's 16.
+  del caplog
+  import re
+
+  from tensor2robot_tpu.research.token_policy import glm_model
+
+  cfg = _cell_config('glm-4.7-flash-ep8')
+  program = cfg['program']
+  model = glm_model.GlmTokenPolicyModel(
+      **{k: cfg[k] for k in program['model_keys']},
+      **{arg: cfg[k] for arg, k in program['model_renamed'].items()},
+      **program['model_kwargs'])
+  assert model.compute_dtype == jnp.bfloat16
+  compiled = _compiled_token_step(chip, model.create_module(), cfg)
+  text = compiled.as_text()
+  layers = cfg['num_hidden_layers'] + cfg['num_nextn_predict_layers']
+  assert layers == 6
+  assert _attention_kernels(text) == dict(fwd=6, bwd=6, dq=0, dkv=0)
+  sparse = layers - cfg['first_k_dense_replace']
+  assert len(re.findall(r' conditional\(', text)) == 2 * sparse
+  memory = compiled.memory_analysis()
   assert (memory.argument_size_in_bytes +
           memory.temp_size_in_bytes) <= 13.5e9
 
@@ -438,6 +474,8 @@ def _zaya_step_fits_and_runs_attention_once(chip, caplog):
     _decoder_layer_keeps_attention_residuals,
     _token_step_holds_what_the_layers_keep,
     _zaya_step_fits_and_runs_attention_once,
+    _flash_attention_two_lane_tiles,
+    _glm_step_fits_and_runs_attention_once,
 ], ids=lambda fn: fn.__name__.lstrip('_'))
 def test_compiles_for_described_v5e(case, chip, caplog):
   case(chip, caplog)

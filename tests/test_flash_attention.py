@@ -54,7 +54,7 @@ def test_rejects_bad_shapes():
   q, k, v = _qkv((1, 100, 2, 16))
   with pytest.raises(ValueError, match='divisible'):
     flash_attention(q, k, v, False, 64, 64)
-  q, k, v = _qkv((1, 128, 2, 256))
+  q, k, v = _qkv((1, 128, 2, 384))
   with pytest.raises(ValueError, match='head dim'):
     flash_attention(q, k, v, False, 128, 128)
 
@@ -336,12 +336,67 @@ def test_fused_backward_is_the_two_kernels_and_the_reference(
     (16384, 128, 2, True),
     (65536, 64, 2, False),    # dk and dv of a head would be 64 MiB
     (32768, 128, 2, False),
+    (8192, 256, 2, True),     # latent attention's heads: at the edge
+    (16384, 256, 2, False),
 ])
 def test_backward_fuses_while_a_heads_dk_and_dv_fit(t, d, itemsize, fused):
   from tensor2robot_tpu.ops import flash_attention as fa
 
   assert fa._streams(t, d, itemsize, group=4)
   assert fa._fuses_backward(t, d, itemsize) == fused
+
+
+# ------------------------------------------- two lane tiles a head (D=256)
+
+
+@pytest.mark.parametrize('streamed', [False, True])
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16])
+def test_heads_of_256_match_plain_attention(streamed, dtype, monkeypatch):
+  """Latent attention's head (192 content + 64 rotary dimensions, 256
+  of value): forward and backward against the masked-softmax reference,
+  staged and streamed; the streamed way back is the one kernel."""
+  from tensor2robot_tpu.ops import flash_attention as fa
+
+  if streamed:
+    monkeypatch.setattr(fa, '_MAX_STAGED_KV_BYTES', 1)
+  rng = np.random.RandomState(21)
+  shape = (1, 256, 2, 256)
+  q, k, v = (jnp.asarray(rng.randn(*shape), dtype) for _ in range(3))
+  ct = jnp.asarray(rng.randn(*shape), jnp.float32)
+  assert fa._streams(256, 256, q.dtype.itemsize) == streamed
+  got, text = _streamed_grads(fa, q, k, v, ct, None, 64, 128)
+  assert text.count('name=flash_attention_bwd') == int(streamed)
+  assert 'name=flash_attention_dq' not in text
+  out = fa.flash_attention(q, k, v, True, 64, 128)
+  f32 = tuple(x.astype(jnp.float32) for x in (q, k, v))
+  want_out = _masked_softmax_attention(*f32, None)
+  want = jax.grad(lambda *a: jnp.sum(
+      _masked_softmax_attention(*a, None) * ct), (0, 1, 2))(*f32)
+  exact = dtype == jnp.float32
+  assert out.dtype == dtype
+  np.testing.assert_allclose(np.asarray(out, np.float32),
+                             np.asarray(want_out), atol=2e-5 if exact else 3e-2)
+  for g, w in zip(got, want):
+    assert g.dtype == dtype and g.shape == w.shape
+    np.testing.assert_allclose(np.asarray(g, np.float32), np.asarray(w),
+                               atol=5e-4 if exact else 0.15)
+
+
+def test_is_supported_at_two_lane_tiles_and_no_further():
+  from tensor2robot_tpu.ops import flash_attention as fa
+
+  assert fa.is_supported(8192, 256, interpret=False)
+  assert fa.is_supported(256, 256, interpret=True)
+  assert not fa.is_supported(8192, 192, interpret=False)   # no whole tiles
+  assert not fa.is_supported(8192, 384, interpret=False)
+  assert fa.is_supported(8192, 128, interpret=False)
+  assert fa.is_supported(8192, 72, interpret=False)        # as it was
+  # 8,192 tokens of bfloat16 heads of 256 stream (the staged kernels'
+  # float32 blocks double with the head, so their K/V budget halves) and
+  # fuse the way back, at the resident budget's edge.
+  assert fa._use_streamed(8192, 256, 2) and not fa._use_streamed(4096, 256, 2)
+  assert fa._resident_dkv_bytes(8192, 256, 2) == fa._MAX_RESIDENT_DKV_BYTES
+  assert fa._resolve_blocks(8192, 256, None, None) == (1024, 1024)
 
 
 def test_staged_backward_keeps_its_two_kernels():
