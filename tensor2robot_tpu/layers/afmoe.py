@@ -22,11 +22,14 @@ Parameters are float32 and flat under each module (``q``, ``gate``,
 products take operands in ``dtype``, norms, the router, softmax and the
 loss are float32. The trunk returns the mean next-token loss itself, the
 head and the log-softmax computed a chunk of positions at a time: the
-[tokens, vocabulary] logits never exist whole.
+[tokens, vocabulary] logits never exist whole, and under ``grad`` each
+chunk's logits give the loss and its gradient in one pass
+(``next_token_loss``).
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Sequence
 
 import flax.linen as nn
@@ -229,30 +232,73 @@ class Trunk(nn.Module):
     return outputs
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def next_token_loss(h, head, tokens, chunk: int, dtype, shift: int = 1):
   """Mean cross-entropy of position i's prediction of token i + ``shift``
   over each sequence's first S - ``shift`` positions (``shift`` 2: a
   multi-token-prediction module's pass), a ``chunk`` of positions at a
-  time; log-softmax in float32."""
+  time; log-softmax in float32.
+
+  Under ``jax.grad`` the gradient is computed on the way forward: a
+  chunk's logits give its loss and, from the same logits, their cotangent
+  ``(softmax - onehot) * counted / N``, hence the chunk's ``dh`` and its
+  part of ``dhead`` (summed over the chunks in float32): three
+  vocabulary-wide products a chunk and no logits computed again. Both are
+  kept, and the way back multiplies them by the loss's scalar cotangent.
+  Called outside ``grad`` it runs the loss alone, one product a chunk. A
+  ``custom_vjp`` has no forward-mode rule: nothing in the tree takes
+  ``jvp`` or a second derivative of a token trunk.
+  """
+  return _chunked_loss(h, head, tokens, chunk, dtype, shift, False)[0]
+
+
+def _chunked_loss(h, head, tokens, chunk, dtype, shift, with_grads):
+  """``(loss, (dh, dhead))``; ``(loss, None)`` unless ``with_grads``."""
   b, s, d = h.shape
   labels = jnp.roll(tokens, -shift, axis=1).reshape(b * s)
   counted = jnp.broadcast_to(jnp.arange(s) < s - shift,
                              (b, s)).reshape(b * s)
-  rows = b * s
+  rows, count = b * s, b * (s - shift)
   chunk = min(chunk, rows)
   if rows % chunk:
     raise ValueError(f'{rows} positions do not divide into chunks of {chunk}')
   weight = head.astype(dtype)
 
-  @jax.checkpoint
-  def one_chunk(args):
+  def one_chunk(dhead, args):
     hc, lc, mc = args
     logits = jnp.matmul(hc, weight, preferred_element_type=jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    picked = jnp.take_along_axis(logp, lc[:, None], axis=-1)[:, 0]
-    return -jnp.sum(jnp.where(mc, picked, 0.0))
+    top = jnp.max(logits, axis=-1)
+    log_total = jnp.log(jnp.sum(jnp.exp(logits - top[:, None]), axis=-1))
+    picked = jnp.take_along_axis(logits, lc[:, None], axis=-1)[:, 0]
+    part = -jnp.sum(jnp.where(mc, picked - top - log_total, 0.0))
+    if not with_grads:
+      return dhead, (part, None)
+    # From the logits again and not from ``logits - top``: a value shared
+    # with the sum above is written out whole, a float32 [chunk, vocabulary].
+    softmax = jnp.exp(logits - (top + log_total)[:, None])
+    onehot = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) == lc[:, None]
+    dl = (jnp.where(onehot, softmax - 1.0, softmax) *
+          jnp.where(mc, 1.0 / count, 0.0)[:, None]).astype(dtype)
+    dhc = jax.lax.dot_general(dl, weight, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    dhead += jax.lax.dot_general(hc, dl, (((0,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+    return dhead, (part, dhc.astype(h.dtype))
 
-  parts = jax.lax.map(one_chunk, (
+  dhead = jnp.zeros(head.shape, jnp.float32) if with_grads else None
+  dhead, (parts, dh) = jax.lax.scan(one_chunk, dhead, (
       h.reshape(rows // chunk, chunk, d), labels.reshape(rows // chunk, chunk),
       counted.reshape(rows // chunk, chunk)))
-  return jnp.sum(parts) / (b * (s - shift))
+  loss = jnp.sum(parts) / count
+  if not with_grads:
+    return loss, None
+  return loss, (dh.reshape(h.shape), dhead.astype(head.dtype))
+
+
+def _loss_backward(chunk, dtype, shift, kept, g):
+  del chunk, dtype, shift
+  return tuple((g * grad).astype(grad.dtype) for grad in kept) + (None,)
+
+
+next_token_loss.defvjp(functools.partial(_chunked_loss, with_grads=True),
+                       _loss_backward)

@@ -31,7 +31,9 @@ Parameters are float32 and flat under each module, by the names of
 ``benchmark/reference/glm_4_7_flash.py``; products take operands in
 ``dtype``; norms, the router, rotary, softmax and the losses are
 float32. The trunk returns its loss itself, the vocabulary losses a
-chunk of positions at a time (``afmoe.next_token_loss``).
+chunk of positions at a time (``afmoe.next_token_loss``, which computes
+each pass's gradients on the way forward and scales them on the way back
+by the pass's weight in the total: 1 and ``mtp_loss_weight``).
 """
 
 from __future__ import annotations

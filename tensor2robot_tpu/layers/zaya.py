@@ -27,7 +27,9 @@ Parameters are float32 and flat under each module, by the names of
 ``dtype``; norms, the whole router, the L2 norms, softmax and the loss
 are float32. The trunk returns the mean next-token loss itself
 (``afmoe.next_token_loss`` with the embedding transposed: one leaf, its
-gradient the sum of both uses).
+gradient the sum of both uses; the loss hands back the head's gradient
+summed over the chunks in float32, and the transpose carries it to the
+embedding's).
 """
 
 from __future__ import annotations

@@ -375,6 +375,153 @@ def test_model_loss_and_gradients_match_reference():
   assert int(out['moe/tokens']) == tokens.size * len(ref.init_state(cfg))
 
 
+# ------------------------------------------------------- the vocabulary loss
+
+LOSS_B, LOSS_S, LOSS_D, LOSS_V = 2, 64, 32, 200
+
+
+def _loss_inputs(dtype):
+  keys = jax.random.split(jax.random.PRNGKey(36), 3)
+  h = jax.random.normal(keys[0], (LOSS_B, LOSS_S, LOSS_D)).astype(dtype)
+  head = 0.3 * jax.random.normal(keys[1], (LOSS_D, LOSS_V))
+  tokens = jax.random.randint(keys[2], (LOSS_B, LOSS_S), 0, LOSS_V)
+  return h, head, tokens
+
+
+def _plain_loss(h, head, tokens, shift):
+  """The unchunked float32 log-softmax loss, differentiated by JAX."""
+  logits = jnp.einsum('bsd,dv->bsv', h.astype(jnp.float32), head,
+                      precision='highest')
+  picked = jnp.take_along_axis(
+      jax.nn.log_softmax(logits), jnp.roll(tokens, -shift, axis=1)[..., None],
+      axis=-1)[..., 0]
+  return -jnp.mean(picked[:, :LOSS_S - shift])
+
+
+def _loss_before_pr36(h, head, tokens, chunk, dtype, shift):
+  """``next_token_loss`` as it was before the gradient moved into the
+  forward pass (each chunk under ``jax.checkpoint``, differentiated by
+  JAX): what the loss's value and the bfloat16 gradients' distance from
+  the float32 ones are pinned against."""
+  b, s, d = h.shape
+  rows = b * s
+  chunk = min(chunk, rows)
+  weight = head.astype(dtype)
+
+  @jax.checkpoint
+  def one_chunk(args):
+    hc, lc, mc = args
+    logits = jnp.matmul(hc, weight, preferred_element_type=jnp.float32)
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    picked = jnp.take_along_axis(logp, lc[:, None], axis=-1)[:, 0]
+    return -jnp.sum(jnp.where(mc, picked, 0.0))
+
+  parts = jax.lax.map(one_chunk, (
+      h.reshape(rows // chunk, chunk, d),
+      jnp.roll(tokens, -shift, axis=1).reshape(rows // chunk, chunk),
+      jnp.broadcast_to(jnp.arange(s) < s - shift,
+                       (b, s)).reshape(rows // chunk, chunk)))
+  return jnp.sum(parts) / (b * (s - shift))
+
+
+def _distance(a, b):
+  a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+  return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16],
+                         ids=['float32', 'bfloat16'])
+@pytest.mark.parametrize('chunk', [LOSS_B * LOSS_S, 16],
+                         ids=['one_chunk', 'eight_chunks'])
+@pytest.mark.parametrize('shift', [1, 2])
+def test_loss_gradient_from_the_forward_pass_matches_jax_grad(shift, chunk,
+                                                              dtype):
+  h, head, tokens = _loss_inputs(dtype)
+
+  # A scalar cotangent that is not 1: the MTP pass's 0.3.
+  def now(h, head):
+    return 0.3 * afmoe.next_token_loss(h, head, tokens, chunk, dtype,
+                                       shift=shift)
+
+  def before_pr36(h, head):
+    return 0.3 * _loss_before_pr36(h, head, tokens, chunk, dtype, shift)
+
+  def plain(h, head):
+    return 0.3 * _plain_loss(h, head, tokens, shift)
+
+  steps = [jax.jit(jax.value_and_grad(fn, argnums=(0, 1)))
+           for fn in (now, before_pr36, plain)]
+  (loss, (dh, dhead)), (before, (dh_before, dhead_before)), (
+      want, (dh_want, dhead_want)) = [step(h, head) for step in steps]
+  evaluate = jax.jit(now)
+  evaluated = evaluate(h, head)
+
+  assert dh.dtype == h.dtype and dhead.dtype == head.dtype
+  assert float(loss) == float(evaluated)
+  # Positions that are not counted take no gradient at all.
+  assert not np.asarray(dh, np.float32)[:, LOSS_S - shift:].any()
+  assert np.asarray(dh, np.float32)[:, :LOSS_S - shift].all(axis=-1).all()
+  if dtype == jnp.float32:
+    assert float(loss) == float(before)       # bit for bit
+    assert abs(float(loss) - float(want)) < 1e-6 * float(want)
+    close(dh, dh_want, 1e-5)
+    close(dhead, dhead_want, 1e-5)
+    return
+  assert abs(float(loss) - float(before)) < 1e-6 * float(before)
+  # bfloat16, distance from the float32 gradients as measured here before
+  # the change (PR 36): dh 0.0027-0.0028, dhead 0.0018-0.0019 in one chunk
+  # and 0.0039-0.0040 in eight (each chunk's float32 product was cast
+  # before it was added). Now dhead 0.0016-0.0017 however chunked (the sum
+  # is float32) and dh 0.0036: the logits' cotangent enters both products
+  # rounded to bfloat16, and 0.3 x dh is rounded once more. The CPU fed the
+  # old form's products the float32 cotangent; the chip's default precision
+  # rounds it too, and there (PR 36, 8,192 x 25,024 and x 19,360) dh read
+  # 0.00287 in both forms at a cotangent of 1 and 0.00383 before, 0.00323
+  # now at 0.3; dhead 0.0025-0.0036 before, 0.0001 now.
+  assert _distance(dhead, dhead_want) <= _distance(dhead_before, dhead_want)
+  assert _distance(dhead, dhead_want) < 0.0019
+  assert _distance(dh, dh_want) < 1.4 * _distance(dh_before, dh_want)
+  assert _distance(dh, dh_want) < 0.0040
+
+
+def _vocabulary_wide(jaxpr, found):
+  """Every ``dot_general`` and ``add_any`` of ``jaxpr`` and what it
+  holds that has a dimension of ``LOSS_V``, as ``(primitive, output)``."""
+  for eqn in jaxpr.eqns:
+    shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)
+              if hasattr(v.aval, 'shape')]
+    if (eqn.primitive.name in ('dot_general', 'add_any') and
+        any(LOSS_V in shape for shape in shapes)):
+      found.append((eqn.primitive.name, eqn.outvars[0].aval))
+    for sub in jax.core.jaxprs_in_params(eqn.params):
+      _vocabulary_wide(sub, found)
+  return found
+
+
+@pytest.mark.parametrize('shift', [1, 2])
+def test_loss_step_holds_three_vocabulary_wide_products(shift):
+  # The guard against the fourth product (the logits computed again on the
+  # way back) and against a head's gradient summed over chunks in bfloat16.
+  h, head, tokens = _loss_inputs(jnp.bfloat16)
+
+  def loss(h, head):
+    return afmoe.next_token_loss(h, head, tokens, 16, jnp.bfloat16, shift)
+
+  alone = _vocabulary_wide(jax.make_jaxpr(loss)(h, head).jaxpr, [])
+  assert [name for name, _ in alone] == ['dot_general']
+  step = _vocabulary_wide(
+      jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(h, head).jaxpr, [])
+  products = [out for name, out in step if name == 'dot_general']
+  assert len(products) == 3, step
+  assert all(out.dtype == jnp.float32 for out in products)
+  assert sorted(out.shape for out in products) == [
+      (16, LOSS_D), (16, LOSS_V), (LOSS_D, LOSS_V)]
+  # The one sum over chunks there is is the loop's float32 carry.
+  assert not [out for name, out in step if name == 'add_any']
+  with pytest.raises(ValueError, match='do not divide'):
+    afmoe.next_token_loss(h, head, tokens, 48, jnp.bfloat16, shift)
+
+
 @pytest.mark.parametrize('sparse', [False, True], ids=['dense', 'sparse'])
 @pytest.mark.parametrize('kind', [afmoe.SLIDING, afmoe.FULL])
 def test_remat_keeps_named_values_and_runs_forward_kernel_once(

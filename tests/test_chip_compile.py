@@ -334,9 +334,12 @@ def _token_step_holds_what_the_layers_keep(chip, caplog):
   # two conditionals, no recomputed forward of either, and arguments +
   # temporaries at or under 13.5 GB of the chip's 16. What the layers'
   # remat keeps lives in the temporaries (12.85 GB in all with
-  # ``KEPT_NAMES`` as of PR 31, 11.04 with nothing kept), which the
-  # chip's ``memory_stats()`` peak does not show: this is the place a
-  # further kept name meets its budget.
+  # ``KEPT_NAMES`` as of PR 31, 11.04 with nothing kept; 12.99 since the
+  # loss keeps ``dh`` and a float32 ``dhead``, PR 36), which the chip's
+  # ``memory_stats()`` peak does not show: this is the place a further
+  # kept name meets its budget. The vocabulary loss is the step's one
+  # loop: its gradient comes from the forward pass's logits, so there is
+  # no second loop on the way back.
   del caplog
   import re
 
@@ -367,6 +370,7 @@ def _token_step_holds_what_the_layers_keep(chip, caplog):
   assert _attention_kernels(text) == dict(
       fwd=len(kinds), bwd=len(kinds), dq=0, dkv=0)
   assert len(re.findall(r' conditional\(', text)) == 2 * sparse
+  assert len(re.findall(r' while\(', text)) == 1
   memory = program.memory_analysis()
   assert (memory.argument_size_in_bytes +
           memory.temp_size_in_bytes) <= 13.5e9
@@ -380,7 +384,9 @@ def _zaya_step_fits_and_runs_attention_once(chip, caplog):
   # 128, no window), no
   # conditional (half the experts held: the routed-row buffer has one
   # rung), and arguments + temporaries at or under 13.5 GB of the chip's
-  # 16 (11.15 GB as of PR 32: 8.50 of state, 2.65 of temporaries).
+  # 16 (11.15 GB as of PR 32: 8.50 of state, 2.65 of temporaries; 11.63
+  # since PR 36: 3.13 of temporaries), and the tied head's loss as the
+  # step's one loop.
   del caplog
   from tensor2robot_tpu.layers import zaya
 
@@ -408,6 +414,7 @@ def _zaya_step_fits_and_runs_attention_once(chip, caplog):
   assert cfg['num_hidden_layers'] == 6
   assert _attention_kernels(text) == dict(fwd=6, bwd=6, dq=0, dkv=0)
   assert ' conditional(' not in text
+  assert text.count(' while(') == 1
   memory = program.memory_analysis()
   assert (memory.argument_size_in_bytes +
           memory.temp_size_in_bytes) <= 13.5e9
@@ -441,8 +448,9 @@ def _glm_step_fits_and_runs_attention_once(chip, caplog):
   # backward kernel once a decoder layer at 20 heads of 256 (five layers
   # and the MTP module's: six of each, no ``flash_attention_dq`` /
   # ``_dkv``), each of the five expert layers' two conditionals (8 held of
-  # 64: two rungs), and arguments + temporaries at or under 13.5 GB of the
-  # chip's 16.
+  # 64: two rungs), one loop for each of the two vocabulary passes, and
+  # arguments + temporaries at or under 13.5 GB of the chip's 16 (12.07 GB
+  # as of PR 35, 12.18 since PR 36).
   del caplog
   import re
 
@@ -462,6 +470,7 @@ def _glm_step_fits_and_runs_attention_once(chip, caplog):
   assert _attention_kernels(text) == dict(fwd=6, bwd=6, dq=0, dkv=0)
   sparse = layers - cfg['first_k_dense_replace']
   assert len(re.findall(r' conditional\(', text)) == 2 * sparse
+  assert len(re.findall(r' while\(', text)) == 2
   memory = compiled.memory_analysis()
   assert (memory.argument_size_in_bytes +
           memory.temp_size_in_bytes) <= 13.5e9
