@@ -288,8 +288,7 @@ def train_phase(run: Run) -> dict:
     # The defaults that switch on only on a TPU backend.
     _require(all(v == 1.0 for v in tpu_branches.values()),
              f'TPU-only default branches were not taken: {tpu_branches}')
-  # None where the run ended before the ledger's deferred harvest of the
-  # step (TrainerConfig.program_harvest_delay_seconds).
+  # The ledger's record of the step, taken at the first dispatch.
   program = report.get('programs', {}).get('train/step')
   dispatch_wall = metrics.get('trainer/step_wall_ms', {})
   return {
@@ -486,8 +485,6 @@ def multichip_phase(run: Run) -> dict:
 def multichip_child(rehearse: bool) -> int:
   """Runs IN THE CHILD (imports jax). Prints its result as the last
   line of its output; any failed assertion is a non-zero exit."""
-  import threading
-
   import jax
   import numpy as np
 
@@ -514,13 +511,12 @@ def multichip_child(rehearse: bool) -> int:
   batch = next(generator.create_iterator(ModeKeys.TRAIN))
 
   def config(steps):
-    # Delay 0: the ledger's harvest of the step ('train/step') starts at
-    # the first dispatch, on its own thread (run_arm joins it).
+    # The ledger records the step ('train/step') at each arm's first
+    # dispatch.
     return TrainerConfig(
         model_dir='', max_train_steps=steps, seed=SEED,
         steps_per_dispatch=STEPS_PER_DISPATCH, eval_interval_steps=0,
-        log_interval_steps=0, prefetch_batches=0,
-        program_harvest_delay_seconds=0)
+        log_interval_steps=0, prefetch_batches=0)
 
   def placement(mesh):
     """Every device of ``mesh`` holds its own shard of a placed batch
@@ -536,13 +532,9 @@ def multichip_child(rehearse: bool) -> int:
     return {'batch_shard_shape_by_device': per_device}
 
   def run_arm(mesh, steps):
-    arm = equivalence.run_arm(make_model, mesh, batch, config(steps))
-    # The ledger is the process's: with every arm's harvest joined, the
-    # 'train/step' on record is the step of the arm that ran last.
-    for thread in threading.enumerate():
-      if thread.name == 't2r-program-ledger':
-        thread.join()
-    return arm
+    # The ledger is the process's: the 'train/step' on record is the step
+    # of the arm that ran last.
+    return equivalence.run_arm(make_model, mesh, batch, config(steps))
 
   def sharded_arm(mesh, steps):
     arm = run_arm(mesh, steps)

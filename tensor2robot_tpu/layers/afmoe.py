@@ -99,10 +99,11 @@ class Attention(nn.Module):
     k_scale = self.param('k_norm', nn.initializers.ones, (self.head_dim,))
     dt = self.dtype
     x = x.astype(dt)
-    q = (x @ wq.astype(dt)).reshape(b, s, self.num_heads, self.head_dim)
-    k = (x @ wk.astype(dt)).reshape(b, s, self.num_kv_heads, self.head_dim)
-    v = (x @ wv.astype(dt)).reshape(b, s, self.num_kv_heads, self.head_dim)
-    gate = x @ wg.astype(dt)
+    with jax.named_scope('afmoe/attn/project'):
+      q = (x @ wq.astype(dt)).reshape(b, s, self.num_heads, self.head_dim)
+      k = (x @ wk.astype(dt)).reshape(b, s, self.num_kv_heads, self.head_dim)
+      v = (x @ wv.astype(dt)).reshape(b, s, self.num_kv_heads, self.head_dim)
+      gate = x @ wg.astype(dt)
     q = rms_norm(q, q_scale, self.eps, dt)
     k = rms_norm(k, k_scale, self.eps, dt)
     window = None
@@ -120,7 +121,8 @@ class Attention(nn.Module):
       o = fa.flash_attention(q, k, v, True, None, None, window)
     o = o.reshape(b, s, q_width) * jax.nn.sigmoid(
         gate.astype(jnp.float32)).astype(dt)
-    return o @ wo.astype(dt)
+    with jax.named_scope('afmoe/attn/project'):
+      return o @ wo.astype(dt)
 
 
 class DecoderLayer(nn.Module):
@@ -156,7 +158,8 @@ class DecoderLayer(nn.Module):
       y, stats = moe.ExpertLayer(dtype=dt, init_std=self.init_std,
                                  name='moe', **self.expert_kwargs)(x, train)
     else:
-      y = moe.SwiGLU(self.dense_width, dt, self.init_std, name='mlp')(x)
+      with jax.named_scope('afmoe/dense_mlp'):
+        y = moe.SwiGLU(self.dense_width, dt, self.init_std, name='mlp')(x)
     return a + rms_norm(y, norms[3], self.eps, dt), stats
 
 

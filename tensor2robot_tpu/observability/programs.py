@@ -26,13 +26,18 @@ ONCE at compile time (zero per-dispatch cost):
   many leaves the caller donated, plus any captured unused-donation
   warnings: a silently-undonated buffer doubles the program's working
   set and this is the first place it shows;
-* input/output shardings, truncated to a report-safe repr.
+* input/output shardings, truncated to a report-safe repr;
+* the compiled HLO's text, kept beside the record (not in its dict):
+  :meth:`ProgramRecord.op_scopes` maps each instruction — the name an
+  op carries in a device trace — to the named scope that issued it and
+  its direction (forward, backward, recomputed), which is how
+  ``benchmark/metrics/_scopes.py`` splits a traced step's device time
+  by layer.
 
 From a record + measured device seconds, :func:`utilization` derives
-live **MFU / HBM-bandwidth / fraction-of-roofline** gauges
-(``train/mfu``, ``train/hbm_gbps``, ``serving/model/<name>/mfu``) —
-published as train scalars, time-series and ``/metricsz`` (+prom) by
-the callers. A **steady-state recompile sentinel**
+**MFU / HBM-bandwidth / fraction-of-roofline** gauges
+(``serving/model/<name>/mfu``), published by the serving plane. A
+**steady-state recompile sentinel**
 (:class:`RecompileSentinel`) is the runtime twin of the static
 ``recompile-hazard`` rule: after warmup, any growth of a jitted
 function's executable cache — or a re-record under the same name with a
@@ -44,11 +49,10 @@ Discipline matches the rest of ``observability/``: no jax import at
 module scope (the records are duck-typed off jax's ``Compiled`` /
 ``Lowered`` objects, so the module itself stays importable on stdlib-
 only hosts), bounded memory (one small record per distinct program
-name), every shared field lock-guarded. Surfaces: ``/programz``
-(``observability/metricsz.py``), the ``programs`` section of
-``metrics.report()``, ``tools/program_report.py`` (render/diff two
-dumps), and the ``program_ledger`` line ``bench.py`` emits beside every
-headline.
+name; the step's HLO text is its largest part), every shared field
+lock-guarded. Surfaces: ``/programz`` (``observability/metricsz.py``),
+the ``programs`` section of ``metrics.report()``, and
+``tools/program_report.py`` (render/diff two dumps).
 """
 
 from __future__ import annotations
@@ -62,7 +66,8 @@ import re
 import threading
 import time
 import warnings as warnings_mod
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional,
+                    Sequence, Tuple)
 
 from tensor2robot_tpu.observability import flight
 from tensor2robot_tpu.observability import metrics as metrics_lib
@@ -70,7 +75,7 @@ from tensor2robot_tpu.observability import metrics as metrics_lib
 __all__ = [
     'ProgramRecord', 'ProgramLedger', 'RecompileSentinel', 'ledger',
     'record_compiled', 'record_jitted', 'get', 'names', 'document', 'dump',
-    'utilization', 'utilization_scalars', 'flag_recompile',
+    'utilization', 'flag_recompile', 'OpScope', 'op_scope', 'hlo_op_scopes',
     'set_recompile_escalation', 'set_device_peaks', 'set_enabled', 'enabled',
     'program_fingerprint', 'clear', 'ENV_PEAK_FLOPS', 'ENV_PEAK_HBM_GBPS',
 ]
@@ -152,16 +157,30 @@ class ProgramRecord:
   collectives: Dict[str, int] = dataclasses.field(default_factory=dict)
   # Train steps folded into ONE execution of this program (the trainer's
   # steps_per_dispatch scan). cost_analysis counts the WHOLE K-step
-  # executable; utilization() divides by this so train/mfu and
-  # train/hbm_gbps stay per-step quantities a device-feed run can't
-  # inflate by K.
+  # executable; utilization() divides by this so its gauges stay
+  # per-step quantities a device-feed run can't inflate by K.
   steps_per_execution: int = 1
+  # The compiled (optimized) HLO module's text: its instruction names
+  # are the op names of a device trace of this executable, and each
+  # instruction's ``metadata={op_name=...}`` the scope that issued it.
+  # Kept for :meth:`op_scopes`, never put in :meth:`to_dict`.
+  hlo_text: str = dataclasses.field(default='', repr=False, compare=False)
 
   def to_dict(self) -> Dict[str, Any]:
     out = dataclasses.asdict(self)
+    del out['hlo_text']
     out['donate_argnums'] = list(self.donate_argnums)
     out['donation_warnings'] = list(self.donation_warnings)
     return out
+
+  def op_scopes(self) -> Dict[str, 'OpScope']:
+    """Instruction name -> :class:`OpScope`, parsed from
+    :attr:`hlo_text` on first read and kept ({} without the text); see
+    :func:`hlo_op_scopes`."""
+    scopes = self.__dict__.get('_op_scopes')
+    if scopes is None:
+      scopes = self.__dict__['_op_scopes'] = hlo_op_scopes(self.hlo_text)
+    return scopes
 
 
 # ------------------------------------------------------ extraction helpers
@@ -234,6 +253,192 @@ def _aliased_param_numbers(text: str) -> Optional[Tuple[int, ...]]:
     return ()
   block = header[i:end + 1]
   return tuple(sorted({int(m) for m in re.findall(r'\(\s*(\d+)\s*,', block)}))
+
+
+class OpScope(NamedTuple):
+  """Where one instruction of a compiled program came from.
+
+  ``path`` is its ``op_name`` with the transformations unwrapped: a
+  ``jvp(...)``, ``transpose(...)`` or ``vmap(...)`` gives way to the
+  names it holds, a ``jit(...)`` (a function's name, not a scope) and
+  the remat's ``checkpoint`` / ``rematted_computation`` go, and a name
+  repeated next to itself is kept once. What is left are the module
+  names and named scopes that issued the instruction, the primitive
+  last, the same on the way forward and back
+  (``Trunk/layer0/attn/afmoe/attn/project/dot_general``). ``direction``
+  is :data:`FORWARD`, :data:`BACKWARD` (under a ``transpose``) or
+  :data:`RECOMPUTED` (a remat's second forward pass); both are '' for an
+  instruction with no ``op_name``.
+  """
+
+  path: str
+  direction: str
+
+
+FORWARD, BACKWARD, RECOMPUTED = 'forward', 'backward', 'recomputed'
+
+_COMPUTATION_RE = re.compile(r'^(?:ENTRY )?%?([\w.\-]+) \(.* -> .*\{\s*$')
+_INSTRUCTION_RE = re.compile(r'^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$')
+_OP_NAME_RE = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLS_RE = re.compile(r'\bcalls=%?([\w.\-]+)')
+_TO_APPLY_RE = re.compile(r'\bto_apply=%?([\w.\-]+)')
+_WRAPPED_RE = re.compile(r'^(\w+)\((.*)\)$')
+_OPERAND_RE = re.compile(r'[^%),]*%([\w.\-]+)')
+_REMAT_PARTS = frozenset(('checkpoint', 'rematted_computation'))
+# What a fusion that holds one of these does is the product's; the rest
+# of it (an optimizer's update, a bias, a cast) rides along.
+_PRODUCTS = frozenset(('dot', 'convolution', 'custom-call', 'ragged-dot'))
+
+
+def _opcode(rhs: str) -> Tuple[str, str]:
+  """'f32[8,16]{1,0} dot(%a, %b), ...' -> ('dot', 'a'): the opcode and
+  the first operand ('' for none); tuple results too."""
+  if rhs.startswith('('):
+    depth = 0
+    for i, ch in enumerate(rhs):
+      depth += (ch == '(') - (ch == ')')
+      if depth == 0:
+        rhs = rhs[i + 1:]
+        break
+  else:
+    rhs = rhs.partition(' ')[2]
+  op, _, rest = rhs.lstrip().partition('(')
+  operand = _OPERAND_RE.match(rest)
+  return op, operand.group(1) if operand else ''
+
+
+def _name_parts(name: str) -> List[str]:
+  """The components of a name stack, transformations unwrapped and
+  ``jit(...)`` dropped: 'jit(f)/transpose(jvp(a/b))/c' -> [a, b, c]."""
+  parts, depth, start = [], 0, 0
+  for i, ch in enumerate(name + '/'):
+    if ch == '(':
+      depth += 1
+    elif ch == ')':
+      depth -= 1
+    elif ch == '/' and depth == 0:
+      part = name[start:i]
+      start = i + 1
+      wrapped = _WRAPPED_RE.match(part)
+      if wrapped is None:
+        if part:
+          parts.append(part)
+      elif wrapped.group(1) not in ('jit', 'pjit'):
+        parts.extend(_name_parts(wrapped.group(2)))
+  return parts
+
+
+def op_scope(op_name: str) -> OpScope:
+  """One ``op_name`` as an :class:`OpScope` (a fused op's several names,
+  ``;``-separated, are read by the first)."""
+  op_name = op_name.split(';', 1)[0]
+  parts = _name_parts(op_name)
+  if 'rematted_computation' in parts:
+    direction = RECOMPUTED
+  elif 'transpose(' in op_name:
+    direction = BACKWARD
+  else:
+    direction = FORWARD
+  kept: List[str] = []
+  for part in parts:
+    if part not in _REMAT_PARTS and (not kept or kept[-1] != part):
+      kept.append(part)
+  return OpScope('/'.join(kept), direction)
+
+
+def hlo_op_scopes(text: str) -> Dict[str, OpScope]:
+  """Every instruction of a compiled HLO module's text -> its scope.
+
+  An instruction is read by its own ``op_name``, with two exceptions. A
+  fusion is the scope of the first product (dot, convolution, custom
+  call) it holds, nested fusions searched too, and otherwise of its
+  root's: root-only would hand a gradient's product to the optimizer
+  whose update XLA fused into it. An instruction with no ``op_name`` (a
+  copy, slice or tuple that XLA made) is read by its first operand's,
+  as far back as one has a name. Within a fusion a name with a scope
+  beats a bare primitive's (``gather``: what XLA's expansions leave in
+  the fused computation), which is kept only where nothing better is.
+  Instruction names are unique in a
+  module, so one map covers the entry, loop bodies and branches: every
+  instruction that can be an op of a device trace, and no instruction
+  inside a fusion or a reduction's region."""
+  # name -> (opcode, op_name, fused computation, first operand)
+  instrs: Dict[str, Tuple[str, str, str, str]] = {}
+  comps: Dict[str, List[str]] = {}
+  roots: Dict[str, str] = {}
+  inner = set()   # fusions' and regions' computations: never trace ops
+  current = None
+  for line in text.splitlines():
+    if current is None:
+      m = _COMPUTATION_RE.match(line)
+      if m is not None:
+        current = m.group(1)
+        comps[current] = []
+      continue
+    if line.startswith('}'):
+      current = None
+      continue
+    m = _INSTRUCTION_RE.match(line)
+    if m is None:
+      continue
+    name, rhs = m.group(1), m.group(2)
+    op, operand = _opcode(rhs)
+    named = _OP_NAME_RE.search(rhs)
+    calls = _CALLS_RE.search(rhs) if op == 'fusion' else None
+    region = _TO_APPLY_RE.search(rhs) if op != 'call' else None
+    inner.update(ref.group(1) for ref in (calls, region) if ref is not None)
+    instrs[name] = (op, named.group(1) if named else '',
+                    calls.group(1) if calls else '', operand)
+    comps[current].append(name)
+    if line.lstrip().startswith('ROOT '):
+      roots[current] = name
+
+  def first(candidates) -> str:
+    """The first name with a scope ('/'), else the first name at all:
+    XLA's rewrites leave some instructions a bare primitive's name."""
+    found = ''
+    for candidate in candidates:
+      if '/' in candidate:
+        return candidate
+      found = found or candidate
+    return found
+
+  products: Dict[str, str] = {}
+
+  def product(comp: str) -> str:
+    """The first product's ``op_name`` in ``comp``, nested fusions too."""
+    if comp not in products:
+      products[comp] = ''
+      products[comp] = first(
+          instrs[name][1] if instrs[name][0] in _PRODUCTS else
+          product(instrs[name][2]) if instrs[name][2] else ''
+          for name in comps.get(comp, ()))
+    return products[comp]
+
+  resolved: Dict[str, str] = {}
+
+  def resolve(name: str) -> str:
+    if name not in resolved:
+      resolved[name] = ''   # a cycle reads as unnamed, never loops
+      _, op_name, calls, operand = instrs.get(name, ('', '', '', ''))
+
+      def candidates():
+        if calls:
+          yield product(calls)
+          yield resolve(roots.get(calls, ''))
+        yield op_name
+
+      found = first(candidates())
+      resolved[name] = found or (resolve(operand) if operand else '')
+    return resolved[name]
+
+  out: Dict[str, OpScope] = {}
+  for comp, names in comps.items():
+    if comp not in inner:
+      for name in names:
+        op_name = resolve(name)
+        out[name] = op_scope(op_name) if op_name else OpScope('', '')
+  return out
 
 
 def _sharding_repr(value) -> str:
@@ -386,6 +591,7 @@ class ProgramLedger:
         recorded_unix=time.time(),
         custom_calls=text.count('tpu_custom_call'),
         collectives=_collective_counts(text),
+        hlo_text=text,
     )
 
   def get(self, name: str) -> Optional[ProgramRecord]:
@@ -472,14 +678,15 @@ def record_jitted(name: str, jit_fn, args: Sequence[Any],
                   donate_argnums: Sequence[int] = (),
                   donated_params: Optional[int] = None,
                   source: str = '',
-                  steps_per_execution: int = 1) -> Optional[ProgramRecord]:
-  """AOT-lowers and compiles ``jit_fn`` at ``args``' shapes and records it.
+                  steps_per_execution: int = 1,
+                  flag_steady_state: bool = True) -> Optional[ProgramRecord]:
+  """Lowers and compiles ``jit_fn`` at ``args`` and records it.
 
-  The executable cache jax builds on *call* is not shared with the AOT
-  ``lower().compile()`` path, so this pays one extra backend compile —
-  a startup-only cost, amortized to a disk read when the persistent
-  compilation cache (``utils/compilation_cache.py``) is enabled. The
-  trainer therefore runs this off-thread after its first dispatch.
+  Given the very arguments of a call ``jit_fn`` has already made (the
+  trainer's first dispatch), the trace, the lowering and the executable
+  all come from jax's caches: no backend compile, the record is of the
+  executable that runs. Avals rebuilt from those arguments
+  (``ShapeDtypeStruct``) miss the caches and pay a second compile.
   Unused-donation warnings emitted during lower/compile are captured
   into the record. Never raises.
   """
@@ -501,6 +708,7 @@ def record_jitted(name: str, jit_fn, args: Sequence[Any],
       name, compiled, lowered=lowered, compile_seconds=dt,
       donate_argnums=donate_argnums, donated_params=donated_params,
       captured_warnings=donation_warnings, source=source,
+      flag_steady_state=flag_steady_state,
       steps_per_execution=steps_per_execution)
 
 
@@ -577,7 +785,7 @@ def utilization(name: str, n_steps: int,
   (``steps_per_dispatch`` with or without device feed) records
   ``steps_per_execution=K`` and its cost_analysis covers the whole
   K-step program, so per-step FLOPs/bytes are ``record / K`` — the
-  normalization that keeps train/mfu honest when one dispatch trains K
+  normalization that keeps MFU honest when one dispatch trains K
   steps (and exact for ragged tail groups shorter than K, which a
   per-dispatch multiply would overcount). For K == 1 this is the
   historical dispatch-count math bit for bit.
@@ -611,26 +819,6 @@ def utilization(name: str, n_steps: int,
     # Fraction of the binding roof: a program at 8% MFU but 92% of HBM
     # bandwidth is bandwidth-bound, not badly scheduled.
     out['roofline_fraction'] = max(roofline)
-  return out
-
-
-def utilization_scalars(name: str, n_steps: int, device_seconds: float,
-                        scope: str = 'train') -> Dict[str, float]:
-  """:func:`utilization` published as ``<scope>/*`` gauges.
-
-  Gauge names land exactly as the ISSUE's surface contract spells them
-  (``train/mfu``, ``train/hbm_gbps``): the gauges ride ``/metricsz``
-  and the time-series ring for free, and the returned dict is merged
-  into the trainer's scalar stream at log crossings.
-  """
-  util = utilization(name, n_steps, device_seconds)
-  if not util:
-    return {}
-  scoped = metrics_lib.scope(scope)
-  out = {}
-  for key, value in util.items():
-    scoped.gauge(key).set(value)
-    out[f'{scope}/{key}'] = value
   return out
 
 

@@ -375,22 +375,14 @@ class TrainerConfig:
   step_breakdown: bool = True
   # Compiled-program ledger (observability/programs.py): record the
   # train step's executable — cost_analysis FLOPs/bytes, memory
-  # analysis, fingerprint, donation map — once at compile time, derive
-  # live train/mfu + train/hbm_gbps + train/roofline_fraction at log
-  # crossings from the breakdown's device time, and watch the jit cache
+  # analysis, fingerprint, donation map, the compiled HLO whose
+  # ``op_scopes()`` map every instruction to the scope that issued it —
+  # once, at the first dispatch, from the executable that dispatch ran
+  # (a cache hit: no second backend compile), and watch the jit cache
   # for steady-state recompiles (flagged as 'program' flight events
   # within the dispatch that paid them). Per-dispatch cost is one C++
-  # cache-size probe + an int compare; the one-off AOT harvest of the
-  # jitted step runs on a daemon thread (a disk read when the
-  # persistent compilation cache is enabled).
+  # cache-size probe + an int compare.
   program_ledger: bool = True
-  # The AOT harvest of the jitted step is a REAL second backend compile
-  # whose tracing contends (GIL) with the dispatch loop. Deferring it
-  # keeps short runs and benchmarks unpolluted — the timer is cancelled
-  # if the loop ends first (a post-run harvest serves no live gauge),
-  # and on any run longer than the delay the MFU gauges appear from the
-  # next log window on. 0 harvests immediately after the first dispatch.
-  program_harvest_delay_seconds: float = 5.0
   # Live metrics endpoint (observability/metricsz.py): serve
   # ``registry.report()`` JSON at http://127.0.0.1:<port>/metricsz from a
   # stdlib http.server daemon thread, for fleet scraping without touching
@@ -930,17 +922,12 @@ class _DispatchBreakdown:
     self._win_steps += steps
     self._win_examples += examples
 
-  def window_scalars(self, utilization_fn=None) -> MetricDict:
+  def window_scalars(self) -> MetricDict:
     """Drains the current log window into publishable scalars.
 
     ``goodput_examples_per_sec`` discounts examples whose updates the
     non-finite guard skipped on device — throughput that moved bytes
-    but trained nothing. ``utilization_fn(n_steps, device_seconds)``
-    (the program ledger's MFU/HBM derivation) is handed the window's
-    STEP count — not dispatches; the ledger normalizes the K-step
-    executable per step — and device time before the drain, and its
-    scalars ride the same merge; it publishes its own gauges, so it
-    runs after the ``trainer/``-prefixed gauge loop.
+    but trained nothing.
     """
     if not self.enabled or self._win_dispatches == 0:
       return {}
@@ -965,12 +952,6 @@ class _DispatchBreakdown:
     }
     for key, value in out.items():
       metrics_lib.gauge(f'trainer/{key}').set(value)
-    if utilization_fn is not None:
-      try:
-        out.update(
-            utilization_fn(self._win_steps, self._win['device'] / 1e3) or {})
-      except Exception:  # pylint: disable=broad-except
-        pass  # telemetry derivation must never stall a log crossing
     self._windows.inc()
     # Postmortem retention: the last K closed windows ride every
     # incident bundle (bounded ring in observability/postmortem.py).
@@ -1054,11 +1035,8 @@ class Trainer:
     self._state: Optional[TrainState] = None
     self._train_step_fn = None
     self._eval_step_fn = None
-    # Whether 'train/step' landed in the program ledger (set by the
-    # off-thread harvest of the jitted step). Plain bool,
-    # single-writer-ish: a racing reader at worst harvests a duplicate
-    # record of the SAME program, which the ledger de-duplicates by
-    # fingerprint.
+    # Whether 'train/step' landed in the program ledger (set on the loop
+    # thread at a loop's first dispatch).
     self._program_recorded = False
     # Step the current dispatch started from; callbacks use crossed() so
     # their interval semantics survive steps_per_dispatch > 1.
@@ -1247,15 +1225,19 @@ class Trainer:
         scalars = jax.tree_util.tree_map(
             lambda s: jnp.mean(jnp.asarray(s).astype(jnp.float32), axis=0),
             scalars_m)
-      updates, new_opt_state = optimizer.update(
-          grads, state.opt_state, state.params)
-      new_params = optax.apply_updates(state.params, updates)
-      new_state = state.replace(
-          step=state.step + 1,
-          params=new_params,
-          model_state=new_model_state,
-          opt_state=new_opt_state,
-          ema_params=apply_ema(state, new_params, decay))
+      # Named for the device trace's readers (``ProgramRecord.op_scopes``);
+      # where XLA fuses the update into a gradient's product, the product
+      # keeps its layer's scope.
+      with jax.named_scope('train/optimizer'):
+        updates, new_opt_state = optimizer.update(
+            grads, state.opt_state, state.params)
+        new_params = optax.apply_updates(state.params, updates)
+        new_state = state.replace(
+            step=state.step + 1,
+            params=new_params,
+            model_state=new_model_state,
+            opt_state=new_opt_state,
+            ema_params=apply_ema(state, new_params, decay))
       scalars = dict(scalars)
       scalars['loss'] = loss
       if guard_nonfinite:
@@ -1265,10 +1247,11 @@ class Trainer:
         # no host sync, no extra dispatch; the host policy reads the
         # count from the scalars one dispatch behind. Leaves the replace
         # kept by reference (rng) skip the select via identity.
-        ok = all_finite(loss, grads)
-        new_state = jax.tree_util.tree_map(
-            lambda n, o: n if n is o else jnp.where(ok, n, o),
-            new_state, state)
+        with jax.named_scope('train/optimizer'):
+          ok = all_finite(loss, grads)
+          new_state = jax.tree_util.tree_map(
+              lambda n, o: n if n is o else jnp.where(ok, n, o),
+              new_state, state)
         scalars['nonfinite_count'] = jnp.where(ok, 0, 1).astype(jnp.int32)
       return new_state, scalars
 
@@ -1326,70 +1309,34 @@ class Trainer:
         out_shardings=(state_sharding, None),
         donate_argnums=self._donate_argnums())
 
-  def _capture_program_avals(self, cell, features, labels) -> None:
-    """Fills ``cell`` with (avals, donated_leaves) for the harvest.
+  def _record_step_program(self, features, labels) -> None:
+    """'train/step' in the program ledger, from the executable that the
+    first dispatch ran.
 
-    Shape/dtype/sharding only — no batch buffers are retained. A
-    ~tree-size-microseconds cost paid once, at the first dispatch (the
-    expensive part of harvesting, the AOT compile, runs elsewhere).
+    ``lower`` at that dispatch's own arguments (the state it returned,
+    the batch it took: donated buffers lower by their avals) finds the
+    trace, the lowering and the executable in jax's caches, so no
+    backend compile is paid (``compile/backend_compiles`` stands still;
+    avals rebuilt as ``ShapeDtypeStruct`` miss them and compile again).
+    The record keeps the compiled HLO, whose instruction names are the
+    op names of a device trace of this step.
     """
-    try:
-      def to_aval(x):
-        return jax.ShapeDtypeStruct(
-            np.shape(x), getattr(x, 'dtype', None) or np.result_type(x),
-            sharding=getattr(x, 'sharding', None))
-
-      avals = jax.tree_util.tree_map(
-          to_aval, (self._state, features, labels))
-      cell.append((avals, len(jax.tree_util.tree_leaves(self._state))))
-    except Exception:  # pylint: disable=broad-except
-      pass
-
-  def _program_harvest_fn(self, cell, loop_live_fn=None):
-    """The deferred ledger record of the jitted step ('train/step').
-
-    jax's on-call executable cache is not readable from the outside, so
-    harvesting cost/memory analysis for the dispatched program means
-    one AOT ``lower().compile()`` of the same program — a real second
-    backend compile (a disk read when the persistent compilation cache
-    is on). Its tracing half holds the GIL and would contend with the
-    dispatch loop, so the loop runs this DEFERRED (a Timer created at
-    loop setup, outside any measured dispatch) by
-    ``program_harvest_delay_seconds``, or on an immediate daemon thread
-    at delay 0. Bails when the loop already ended (``loop_live_fn``),
-    when an earlier loop of this trainer recorded the program, or when
-    the first dispatch never filled ``cell``.
-    """
-    step_fn = self._train_step_fn
-
-    def harvest():
-      if loop_live_fn is not None and not loop_live_fn():
-        return  # the run already ended: no live gauge to feed
-      if self._program_recorded or not cell:
-        return
-      avals, donated_params = cell[0]
-      if programs_lib.record_jitted(
-          'train/step', step_fn, avals,
-          donate_argnums=self._donate_argnums(),
-          donated_params=donated_params, source='trainer/jit_step',
-          steps_per_execution=self._loop_k):
-        self._program_recorded = True
-
-    return harvest
-
-  def _program_utilization(self, n_steps: int,
-                           device_seconds: float) -> MetricDict:
-    """train/mfu + train/hbm_gbps + train/roofline_fraction for one
-    closed log window (empty until 'train/step' is recorded).
-
-    ``n_steps`` counts STEPS, not dispatches: the ledger records the
-    K-step executable with ``steps_per_execution=K`` and normalizes its
-    FLOPs/bytes per step, so MFU stays honest (and ragged-tail exact)
-    when one dispatch trains K steps. Identical to the historical
-    dispatch math for K == 1.
-    """
-    return programs_lib.utilization_scalars(
-        'train/step', n_steps, device_seconds, scope='train')
+    compiles = metrics_lib.counter('compile/backend_compiles')
+    before, t0 = compiles.value, time.perf_counter()
+    if programs_lib.record_jitted(
+        'train/step', self._train_step_fn, (self._state, features, labels),
+        donate_argnums=self._donate_argnums(),
+        donated_params=len(jax.tree_util.tree_leaves(self._state)),
+        source='trainer/first_dispatch', steps_per_execution=self._loop_k,
+        # A trainer's first program, not a steady state's recompile (the
+        # dispatch probe watches for those).
+        flag_steady_state=False):
+      self._program_recorded = True
+    # What the record cost the loop, and the proof that it compiled nothing.
+    metrics_lib.gauge('trainer/program_record_seconds').set(
+        time.perf_counter() - t0)
+    metrics_lib.gauge('trainer/program_record_backend_compiles').set(
+        compiles.value - before)
 
   def _build_eval_step(self):
     model = self._model
@@ -1549,26 +1496,10 @@ class Trainer:
     last_log_step = step
     breakdown = _DispatchBreakdown(config.step_breakdown)
     # Compiled-program plane (observability/programs.py): one ledger
-    # harvest after the first dispatch, a cache-size probe per dispatch
-    # (the steady-state recompile sentinel), and MFU/HBM gauges derived
-    # at log crossings from the breakdown's device time.
+    # record at the first dispatch and a cache-size probe per dispatch
+    # (the steady-state recompile sentinel).
     programs_on = config.program_ledger and programs_lib.enabled()
-    program_harvest_pending = programs_on
-    program_harvest_timer = None
-    program_aval_cell: list = []  # filled at the first dispatch
-    program_loop_live = [True]  # flipped by teardown; read at timer fire
-    program_harvest_delay = max(
-        0.0, float(config.program_harvest_delay_seconds))
-    if programs_on and program_harvest_delay > 0:
-      # Created HERE, at loop setup: Timer/thread creation costs ~1 ms,
-      # which inside the loop would land in one measured dispatch wall
-      # (visible on the zero-overhead pin for short runs).
-      program_harvest_timer = threading.Timer(
-          program_harvest_delay,
-          self._program_harvest_fn(
-              program_aval_cell, loop_live_fn=lambda: program_loop_live[0]))
-      program_harvest_timer.daemon = True
-      program_harvest_timer.start()
+    program_record_pending = programs_on and not self._program_recorded
     recompile_probe = (
         programs_lib.dispatch_probe(self._train_step_fn, 'train/step')
         if programs_on else None)
@@ -1804,16 +1735,10 @@ class Trainer:
             examples=int(np.prod(batch_leaves[0].shape[:2]))
             if self._loop_k > 1 and batch_leaves
             else (batch_leaves[0].shape[0] if batch_leaves else 0))
-        if program_harvest_pending:
-          # First dispatch done: the program (and its avals) are final.
-          program_harvest_pending = False
-          if not self._program_recorded:
-            self._capture_program_avals(
-                program_aval_cell, features, labels)
-            if program_harvest_delay <= 0:
-              threading.Thread(
-                  target=self._program_harvest_fn(program_aval_cell),
-                  name='t2r-program-ledger', daemon=True).start()
+        if program_record_pending:
+          # First dispatch done: its executable is the step that runs.
+          program_record_pending = False
+          self._record_step_program(features, labels)
         if recompile_probe is not None:
           # One C++ cache-size read + int compare per dispatch: growth
           # after warmup means steady state just paid a trace+compile.
@@ -1852,9 +1777,7 @@ class Trainer:
           # Step-time breakdown + resilience counters ride the normal
           # scalars dict, so MetricsLogger/TensorBoard publish them with
           # zero call-site changes.
-          scalars.update(breakdown.window_scalars(
-              utilization_fn=(self._program_utilization
-                              if programs_on else None)))
+          scalars.update(breakdown.window_scalars())
           # HBM gauges (peak/live bytes) ride the same scalar merge, so
           # TensorBoard shows memory beside throughput; no-op (empty) on
           # backends without allocator stats (CPU).
@@ -1884,12 +1807,6 @@ class Trainer:
     finally:
       if t_tail is not None:
         tracing.record('trainer/after_dispatch', t_tail, clock(), key - 1)
-      # A still-pending deferred harvest serves no live gauge once the
-      # loop ends — cancel it (and tell an already-fired one to bail)
-      # so short runs and benchmarks never pay the AOT compile.
-      program_loop_live[0] = False
-      if program_harvest_timer is not None:
-        program_harvest_timer.cancel()
       if prefetcher is not None:
         prefetcher.close()
       if self._heartbeat is not None:

@@ -27,9 +27,11 @@ from __future__ import annotations
 import logging
 import os
 import threading
+import time
 from typing import Dict, Optional
 
 from tensor2robot_tpu.observability import metrics as metrics_lib
+from tensor2robot_tpu.observability import tracing
 
 ENV_VAR = 'JAX_COMPILATION_CACHE_DIR'
 DEFAULT_DIR = os.path.join(
@@ -39,6 +41,10 @@ DEFAULT_DIR = os.path.join(
 _lock = threading.Lock()
 _enabled_dir: Optional[str] = None  # GUARDED_BY(_lock)
 _counters_installed = False  # GUARDED_BY(_lock)
+
+
+_COUNTERS = ('compile/cache_hits', 'compile/cache_misses',
+             'compile/backend_compiles', 'compile/compile_seconds')
 
 
 def install_compile_counters() -> None:
@@ -53,7 +59,12 @@ def install_compile_counters() -> None:
     a slow restart with misses recompiled, one with hits paid disk.
   * ``compile/backend_compiles`` / ``compile/compile_seconds`` — every
     XLA backend compile and its total wall time (the denominator
-    restart goodput is trying to erase).
+    restart goodput is trying to erase);
+  * the span ``compile/backend`` in the tracing ring
+    (``observability/tracing.py``) for each of them, on the thread that
+    compiled, ending when the event arrives: a compile that a training
+    loop pays lies between that loop's spans, so a reader sees which
+    dispatch it delayed.
 
   Idempotent.
   """
@@ -63,25 +74,28 @@ def install_compile_counters() -> None:
       return
     from jax import monitoring
 
-    hits = metrics_lib.counter('compile/cache_hits')
-    misses = metrics_lib.counter('compile/cache_misses')
-    compiles = metrics_lib.counter('compile/backend_compiles')
-    seconds = metrics_lib.counter('compile/compile_seconds')
+    counter = metrics_lib.counter
+    for name in _COUNTERS:
+      counter(name)
 
     # The callbacks run inside jax's compile path and must stay
-    # allocation-light and exception-free.
+    # allocation-light and exception-free. They look their counters up
+    # at each event: a handle held from install time would be orphaned by
+    # a registry reset, and the counts read after it would stand still.
     def on_event(name: str, **kwargs) -> None:
       del kwargs
       if name == '/jax/compilation_cache/cache_hits':
-        hits.inc()
+        counter('compile/cache_hits').inc()
       elif name == '/jax/compilation_cache/cache_misses':
-        misses.inc()
+        counter('compile/cache_misses').inc()
 
     def on_duration(name: str, duration_secs: float, **kwargs) -> None:
       del kwargs
       if name == '/jax/core/compile/backend_compile_duration':
-        compiles.inc()
-        seconds.inc(duration_secs)
+        end = time.perf_counter_ns()
+        counter('compile/backend_compiles').inc()
+        counter('compile/compile_seconds').inc(duration_secs)
+        tracing.record('compile/backend', end - int(duration_secs * 1e9), end)
 
     monitoring.register_event_listener(on_event)
     monitoring.register_event_duration_secs_listener(on_duration)
